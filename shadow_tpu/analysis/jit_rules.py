@@ -68,8 +68,7 @@ _JIT_NAMES = ("jax.jit", "jax.api.jit")
 _PARTIAL_NAMES = ("functools.partial", "partial")
 # transform wrappers a jit may trace through: jax.jit(jax.vmap(f)),
 # jax.jit(shard_map(f, ...)) — the traced fn is the wrapped one
-_TRANSFORM_NAMES = ("jax.vmap", "jax.experimental.shard_map.shard_map",
-                    "jax.experimental.shard_map", "shard_map", "jax.pmap")
+_TRANSFORM_NAMES = ("jax.vmap", "jax.shard_map", "shard_map", "jax.pmap")
 # the pad/bucket contract: a width that went through one of these is
 # drawn from a bounded class set, so it cannot churn the jit cache
 _PAD_CONTRACT_RE = re.compile(r"pad|pow2|bucket", re.IGNORECASE)

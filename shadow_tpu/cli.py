@@ -12,12 +12,13 @@ from __future__ import annotations
 import os
 import sys
 import textwrap
-from typing import List, Optional
+from typing import List, Optional, Tuple, Union
 
 from .core import configuration
+from .core.configuration import Configuration
 from .core.controller import run_simulation
 from .core.logger import SimLogger, set_logger
-from .core.options import parse_args
+from .core.options import Options, parse_args
 
 # The reference's --test serves /bin/ls (~100KB era-adjusted: we use 16KB)
 # to 1000 clients x 10 downloads via a filetransfer plugin (examples.c:10);
@@ -44,7 +45,10 @@ BUILTIN_TEST_CONFIG = textwrap.dedent("""\
 """)
 
 
-def main(argv: Optional[List[str]] = None) -> int:
+def prepare(argv: Optional[List[str]] = None
+            ) -> Union[int, Tuple[Options, Configuration]]:
+    """Parse and validate a command line: (options, config) ready for
+    run_simulation, or the exit code of a refused invocation."""
     opts = parse_args(argv)
     set_logger(SimLogger(level=opts.log_level))
     # fail fast on supervision/recovery flags that could only error after
@@ -84,6 +88,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                     os.unlink(path)
                 except OSError:
                     pass
+    from .parallel.procs import tpu_shards_refusal
+    refusal = tpu_shards_refusal(opts)
+    if refusal:
+        print(f"error: {refusal}", file=sys.stderr)
+        return 2
     if opts.test_mode:
         cfg = configuration.parse_xml(BUILTIN_TEST_CONFIG)
     elif opts.config_path:
@@ -108,7 +117,16 @@ def main(argv: Optional[List[str]] = None) -> int:
         cfg.bootstrap_end_sec = opts.bootstrap_end_sec
     opts.stop_time_sec = int(cfg.stop_time_sec)
     opts.bootstrap_end_sec = int(cfg.bootstrap_end_sec)
-    return run_simulation(opts, cfg)
+    return opts, cfg
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    prepared = prepare(argv)
+    if isinstance(prepared, int):
+        return prepared
+    from .utils.compile_cache import setup_compile_cache
+    setup_compile_cache()
+    return run_simulation(*prepared)
 
 
 if __name__ == "__main__":

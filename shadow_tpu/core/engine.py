@@ -664,7 +664,8 @@ class Engine:
             log.message("engine", self.counters.report())
         self._obs_finish()
         log.flush()
-        return 1 if self.plugin_errors else 0
+        return 1 if (self.plugin_errors or
+                     self.supervision.unrequested_dispatch_recoveries) else 0
 
     def _flush_round(self) -> bool:
         """Round-boundary hook for batching policies (tpu): LAUNCH the device

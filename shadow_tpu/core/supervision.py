@@ -11,7 +11,10 @@ the recovery tests drive.
 Recovery accounting is deliberately separate from ``engine.plugin_errors``:
 a *supervised* kill (watchdog fired, simulation continued by design) is a
 counted recovery, not a failure — the run's exit code reflects unsupervised
-faults only, and bench.py exports ``recoveries``/``watchdog_overhead_sec``
+faults only, plus any device dispatch recovery that no ``--fault-inject``
+asked for (``unrequested_dispatch_recoveries``: the numpy twin finished
+the run, so the digest stays available, but the device did not), and
+bench.py exports ``recoveries``/``watchdog_overhead_sec``
 so the steady-state cost of the supervision layer stays pinned at ~0.
 """
 
@@ -32,6 +35,7 @@ class SupervisionStats:
     """
 
     __slots__ = ("plugin_watchdog_kills", "dispatch_recoveries",
+                 "unrequested_dispatch_recoveries",
                  "shard_deaths_detected", "native_round_demotions",
                  "shard_resurrections", "reshards", "repromotions",
                  "mttr_ns", "overhead_ns", "resume_path", "resume_verified")
@@ -39,6 +43,7 @@ class SupervisionStats:
     def __init__(self) -> None:
         self.plugin_watchdog_kills = 0
         self.dispatch_recoveries = 0
+        self.unrequested_dispatch_recoveries = 0
         self.shard_deaths_detected = 0
         self.native_round_demotions = 0
         self.shard_resurrections = 0
@@ -72,9 +77,16 @@ class SupervisionStats:
             "process is marked exited — the host and round loop continue")
         self._dump_flight_recorder(f"plugin watchdog: {name}")
 
-    def count_dispatch_recovery(self, reason: str) -> None:
+    def count_dispatch_recovery(self, reason: str,
+                                injected: bool = False) -> None:
         self.dispatch_recoveries += 1
-        get_logger().warning("supervision", reason)
+        if injected:
+            get_logger().warning("supervision", reason)
+        else:
+            self.unrequested_dispatch_recoveries += 1
+            get_logger().error(
+                "supervision", f"{reason} — no --fault-inject asked for "
+                "it, so the run exits non-zero")
         self._dump_flight_recorder("device dispatch recovery")
 
     def count_native_round_demotion(self, reason: str) -> None:
@@ -134,6 +146,8 @@ class SupervisionStats:
             "recoveries": self.recoveries,
             "plugin_watchdog_kills": self.plugin_watchdog_kills,
             "dispatch_recoveries": self.dispatch_recoveries,
+            "unrequested_dispatch_recoveries":
+                self.unrequested_dispatch_recoveries,
             "shard_deaths_detected": self.shard_deaths_detected,
             "native_round_demotions": self.native_round_demotions,
             "shard_resurrections": self.shard_resurrections,

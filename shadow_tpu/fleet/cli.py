@@ -29,19 +29,17 @@ def _say(msg: str) -> None:
 
 
 def setup_fleet_env(n_dev: int = 8) -> None:
-    """In-process twin of ``fuzz.runner.child_env``: CPU-pin and force
-    the virtual device mesh BEFORE jax initializes, so phase-2 mesh
-    modes run anywhere.  A process that already imported jax (or pinned
-    an accelerator platform) is left alone."""
+    """In-process twin of ``fuzz.runner.child_env``: force the virtual
+    device mesh for a CPU backend BEFORE jax initializes, so phase-2 mesh
+    modes run anywhere.  The platform is the caller's choice; a process
+    that already imported jax is left alone."""
     if "jax" in sys.modules:
         return
-    if os.environ.get("JAX_PLATFORMS", "").strip() in ("", "cpu"):
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n_dev}"
-            ).strip()
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + f" --xla_force_host_platform_device_count={n_dev}"
+        ).strip()
 
 
 def cmd_smoke(args) -> int:

@@ -141,7 +141,7 @@ class PacketHopKernel:
     # per-dispatch tax to one ~30us jit call (CPU backend), at which point
     # always-device measured FASTER than any bypass mix on tor200 (5.57s vs
     # 5.69-5.75s).  ``--tpu-device-threshold N`` restores a bypass for
-    # environments with pathological dispatch round trips (remote tunnels).
+    # environments with pathological dispatch round trips.
     DEVICE_THRESHOLD = 0
 
     def __init__(self, topology, drop_key: int, bootstrap_end_ns: int,
@@ -282,7 +282,6 @@ def _make_matrix_sharded_hop_step(mesh, axis: str = "pkt"):
     with shard_matrix=True — padded rows are never indexed because src rows
     always reference real attached vertices).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def step(latency_ns, reliability, src_rows, dst_rows,
@@ -300,7 +299,7 @@ def _make_matrix_sharded_hop_step(mesh, axis: str = "pkt"):
             # each packet's row lives on exactly one shard -> psum assembles
             return (jax.lax.psum(lat, axis), jax.lax.psum(rel, axis))
 
-        lat, rel = shard_map(
+        lat, rel = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(axis, None), P(axis, None), P(), P()),
             out_specs=(P(), P()))(latency_ns, reliability,

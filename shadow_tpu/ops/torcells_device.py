@@ -53,6 +53,11 @@ CELL_WIRE_BYTES = 512 + defs.CONFIG_HEADER_SIZE_TCPIPETH
 # external users) keep working.
 RING_DTYPE = np.int32
 
+# Bound on the cells a plane may hold at once: segment_greedy's prefix
+# sums run in int32, exact while every node's backlog fits in it.
+# DeviceTrafficPlane refuses an injection that could exceed it.
+MAX_CELLS_IN_FLIGHT = 2 ** 31 - 1
+
 
 def build_flows(route: np.ndarray,          # int32 [C, 5] node per stage
                 latency_ticks: np.ndarray,  # int64 [H, H]
@@ -100,6 +105,27 @@ def build_flows(route: np.ndarray,          # int32 [C, 5] node per stage
 from functools import partial
 
 
+def segment_greedy(queued, cap_cells, seg_start):
+    """Exact greedy allocation in static flow order within each node
+    segment: served = clip(capacity_at_segment - cells_before_me, 0,
+    queued), the cells before a flow being a segment-relative prefix sum.
+
+    The prefix sum runs in int32.  An int64 ``cumsum`` lowers on TPU to a
+    reduce-window over a u32 pair, which the v5e compiler refuses inside
+    the tick loop's ``while_loop`` body for want of VMEM at most widths
+    (ISSUE 21: "Scoped allocation with size 19.10M and limit 16.00M" at
+    F = 1k, 25k and 50k flows).  Two's-complement wraparound keeps the
+    segment-relative difference exact whenever its true value fits in
+    int32; it is at most the cells queued at one node, which the plane
+    keeps below MAX_CELLS_IN_FLIGHT."""
+    q32 = queued.astype(jnp.int32)
+    csum = jnp.cumsum(q32)
+    seg_base = jnp.where(seg_start > 0, csum[jnp.maximum(seg_start - 1, 0)],
+                         jnp.int32(0))
+    before = (csum - q32 - seg_base).astype(jnp.int64)
+    return jnp.clip(cap_cells - before, 0, queued)
+
+
 @partial(jax.jit, static_argnames=("ring_len",))
 def torcells_run(queued0: jnp.ndarray,     # int64 [F] initial cells/flow
                  flow_node: jnp.ndarray,   # int64 [F] paced node
@@ -141,13 +167,7 @@ def torcells_run(queued0: jnp.ndarray,     # int64 [F] initial cells/flow
         # refill buckets
         tokens = jnp.minimum(capacity, tokens + refill)
         cap_cells = tokens[flow_node] // size
-        # greedy allocation in static flow order within each node segment:
-        # served = clip(capacity_at_segment - cells_before_me, 0, queued)
-        csum = jnp.cumsum(queued)
-        before = csum - queued - jnp.where(
-            seg_start > 0, csum[jnp.maximum(seg_start - 1, 0)],
-            jnp.int64(0)) * (seg_start > 0)
-        served = jnp.clip(cap_cells - before, 0, queued)
+        served = segment_greedy(queued, cap_cells, seg_start)
         queued = queued - served
         spent = jax.ops.segment_sum(served * size, flow_node,
                                     num_segments=h)
@@ -244,11 +264,7 @@ def _step_window_impl(t0: jnp.ndarray,         # int64 scalar: next tick
         queued = queued + arr
         tokens = jnp.minimum(capacity, tokens + refill)
         cap_cells = tokens[flow_node] // size
-        csum = jnp.cumsum(queued)
-        before = csum - queued - jnp.where(
-            seg_start > 0, csum[jnp.maximum(seg_start - 1, 0)],
-            jnp.int64(0)) * (seg_start > 0)
-        served = jnp.clip(cap_cells - before, 0, queued)
+        served = segment_greedy(queued, cap_cells, seg_start)
         queued = queued - served
         spent = jax.ops.segment_sum(served * size, flow_node,
                                     num_segments=h)
@@ -350,8 +366,9 @@ def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
     hh = h if cap_nodes is None else min(int(cap_nodes), h)
     length = flush_len(c, h, cap_chains, cap_nodes)
     touched = sent_delta != 0
-    pos_c = jnp.cumsum(newly.astype(jnp.int64)) - 1
-    pos_h = jnp.cumsum(touched.astype(jnp.int64)) - 1
+    # int32 cursors: a count of chains or nodes is far below 2**31
+    pos_c = jnp.cumsum(newly.astype(jnp.int32)) - 1
+    pos_h = jnp.cumsum(touched.astype(jnp.int32)) - 1
     oob = jnp.int64(length)
     sel_c = newly & (pos_c < cc)
     sel_h = touched & (pos_h < hh)
@@ -471,11 +488,7 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
         queued = queued + arr
         tokens = jnp.minimum(capacity, tokens + refill)
         cap_cells = tokens[flow_node] // size
-        csum = jnp.cumsum(queued)
-        before = csum - queued - jnp.where(
-            seg_start > 0, csum[jnp.maximum(seg_start - 1, 0)],
-            jnp.int64(0)) * (seg_start > 0)
-        served = jnp.clip(cap_cells - before, 0, queued)
+        served = segment_greedy(queued, cap_cells, seg_start)
         queued = queued - served
         spent = jax.ops.segment_sum(served * size, flow_node,
                                     num_segments=h)

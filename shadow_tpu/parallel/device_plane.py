@@ -1115,6 +1115,15 @@ class DeviceTrafficPlane:
                 inject_target[self.last_flow[circ]] += cells
                 self._cells_dispatched += cells
             self._inject_buf.clear()
+            from ..ops.torcells_device import MAX_CELLS_IN_FLIGHT
+            if (self._cells_dispatched - self._cells_delivered_seen
+                    > MAX_CELLS_IN_FLIGHT):
+                # an upper bound on every node's backlog: the kernel's
+                # int32 segment prefix sums are exact only below it
+                raise ValueError(
+                    "device plane: more than 2**31-1 cells in flight — "
+                    "the kernel's int32 segment prefix sums would not be "
+                    "exact (shorten the transfers or split the run)")
             if self._shard is not None:
                 from .mesh.partition import pad_state
                 inject = pad_state(self._shard, inject)
@@ -1282,7 +1291,8 @@ class DeviceTrafficPlane:
                 # it on the numpy twin
                 flush = self._collect_flush(engine, handle)
             except Exception as e:  # noqa: BLE001 - any dispatch failure
-                flush = self._recover_dispatch(engine, e)
+                flush = self._recover_dispatch(
+                    engine, e, injected=isinstance(handle, _PoisonedFlush))
         t1 = _wt.perf_counter_ns()
         self.device_ns += t1 - t0
         self._profiler.on_collect(self._launch_wall, t0, t1 - t0,
@@ -1453,7 +1463,7 @@ class DeviceTrafficPlane:
         """Materialize the in-flight dispatch's flush buffer, bounded by
         ``--device-watchdog-sec`` in device mode: the blocking read runs on
         a helper thread so a dispatch that never completes (wedged runtime,
-        dead device tunnel) raises TimeoutError here instead of freezing
+        lost device) raises TimeoutError here instead of freezing
         the round loop forever.  Only the guard's bookkeeping (thread spawn
         + join return) is charged to supervision overhead — the wait for
         the result is the dispatch's own cost, watchdog or not."""
@@ -1567,7 +1577,8 @@ class DeviceTrafficPlane:
         m["prof.flush_overflows"] = self.flush_overflows
         return m
 
-    def _recover_dispatch(self, engine, exc: BaseException) -> np.ndarray:
+    def _recover_dispatch(self, engine, exc: BaseException,
+                          injected: bool = False) -> np.ndarray:
         """Graceful device-plane degradation: the in-flight dispatch failed
         (exception or watchdog timeout), so rebuild the plane's state by
         replaying the FULL logged window history on the bit-identical numpy
@@ -1576,7 +1587,9 @@ class DeviceTrafficPlane:
         demote the backend to the twin.  Digest parity is preserved (the
         twin is the parity oracle the tests pin); device speed is
         forfeited.  Returns the failed window's flush buffer, which the
-        caller consumes exactly as if the device had produced it."""
+        caller consumes exactly as if the device had produced it.  A
+        failure the fault harness did not inject (``injected`` False)
+        makes the run exit non-zero (SupervisionStats)."""
         get_logger().warning(
             "device-plane",
             f"in-flight dispatch failed ({exc!r}); replaying "
@@ -1587,7 +1600,7 @@ class DeviceTrafficPlane:
         self.recoveries += 1
         engine.supervision.count_dispatch_recovery(
             f"device dispatch recovered on the numpy twin ({exc!r}); "
-            "backend demoted for the rest of the run")
+            "backend demoted for the rest of the run", injected=injected)
         self._mesh = None
         self._shard = None
         self._sharded_step = None

@@ -30,27 +30,19 @@ import numpy as np
 
 def device_mesh(n_devices: int, axis_names=("flows",), shape=None):
     """Build a 1-D (or, with ``shape``, reshaped) jax Mesh over the first
-    ``n_devices`` devices.  Prefers the default pool; when a TPU plugin
-    owns the default slot with fewer chips than requested, falls back to
-    the CPU pool (the 8-virtual-device test mesh / dryrun path).  Raises
-    RuntimeError when not enough devices exist anywhere — the ONE
-    definition of pool selection for every sharded consumer."""
+    ``n_devices`` devices of the default pool.  Raises RuntimeError when
+    the pool has fewer — it never switches pools, so a mesh asked of the
+    chips is never quietly built on the host CPU.  The ONE definition of
+    pool selection for every sharded consumer."""
     import jax
     from jax.sharding import Mesh
 
     pool = jax.devices()
     if len(pool) < n_devices:
-        try:
-            cpu_pool = jax.devices("cpu")
-        except RuntimeError:
-            cpu_pool = []
-        if len(cpu_pool) >= n_devices:
-            pool = cpu_pool
-    devices = pool[:n_devices]
-    if len(devices) < n_devices:
         raise RuntimeError(
-            f"--tpu-devices={n_devices} but only {len(pool)} present")
-    arr = np.array(devices)
+            f"--tpu-devices={n_devices} but only {len(pool)} "
+            f"{pool[0].platform} device(s) present")
+    arr = np.array(pool[:n_devices])
     if shape is not None:
         arr = arr.reshape(shape)
     return Mesh(arr, axis_names=axis_names)

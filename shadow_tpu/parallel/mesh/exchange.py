@@ -57,7 +57,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ...ops.torcells_device import CELL_WIRE_BYTES, _pack_flush_jnp, flush_len
+from ...ops.torcells_device import (CELL_WIRE_BYTES, _pack_flush_jnp,
+                                    flush_len, segment_greedy)
 
 
 class ExchangeSchedule:
@@ -267,7 +268,6 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
     exchange is one launch regardless, so only the all-False mask (which
     degrades to ``none``: zero exchange collectives, stats psum only)
     changes the launch count."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_shards = schedule.n_shards
@@ -379,12 +379,8 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
                 queued = queued + arr
                 tokens = jnp.minimum(capacity, tokens + refill)
                 cap_cells = tokens[flow_node_local] // size
-                csum = jnp.cumsum(queued)
-                before = csum - queued - jnp.where(
-                    seg_start_local > 0,
-                    csum[jnp.maximum(seg_start_local - 1, 0)],
-                    jnp.int64(0)) * (seg_start_local > 0)
-                served = jnp.clip(cap_cells - before, 0, queued)
+                served = segment_greedy(queued, cap_cells,
+                                        seg_start_local)
                 queued = queued - served
                 spent = jax.ops.segment_sum(served * size, flow_node_local,
                                             num_segments=h_local)
@@ -453,7 +449,7 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
 
         sharded = P(axis)
         repl = P()
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(repl, sharded, P(None, axis), sharded, sharded,
                       sharded, sharded, sharded, sharded, sharded, repl,
@@ -461,7 +457,7 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
                       sharded, sharded),
             out_specs=(repl, sharded, P(None, axis), sharded, sharded,
                        sharded, sharded, sharded, repl, repl),
-            check_rep=False)(
+            check_vma=False)(
             t0, queued, ring, tokens, delivered, target, done_tick,
             node_sent, inject, inject_target, targets, idle_ticks,
             flow_node_local, succ_global, seg_start_local,

@@ -314,6 +314,22 @@ def _recv_supervised(conn, proc, sid: int, watchdog_sec: float):
             raise err
 
 
+def tpu_shards_refusal(options) -> Optional[str]:
+    """Why ``--processes N`` cannot run these options, or None.  Under the
+    tpu policy every shard would open the accelerator, and a chip serves
+    one process at a time: the second shard would fail or hang on it.
+    Only an explicit ``JAX_PLATFORMS=cpu`` runs such shards, on the host."""
+    if getattr(options, "processes", 0) >= 2 \
+            and options.scheduler_policy == "tpu" \
+            and os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        return ("--processes with --scheduler-policy=tpu: each shard would "
+                "need the chip, which one process holds at a time; run the "
+                "tpu policy in one process (--tpu-devices N spreads it over "
+                "N chips), or set JAX_PLATFORMS=cpu to run the shards' hop "
+                "kernels on the host CPU")
+    return None
+
+
 class ProcsController:
     """Coordinator for ``--processes N``: spawns the shard engines, drives
     the window/exchange protocol, assembles checkpoints and the final state
@@ -324,6 +340,9 @@ class ProcsController:
         if options.processes < 2:
             raise ValueError("--processes needs N >= 2 (use the regular "
                              "engine for a single process)")
+        refusal = tpu_shards_refusal(options)
+        if refusal:
+            raise ValueError(refusal)
         self.options = options
         self.config = config
         self.n_shards = int(options.processes)

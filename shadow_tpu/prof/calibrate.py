@@ -76,7 +76,6 @@ def measure_collectives(devices, widths, iters: int,
 
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import device_mesh
@@ -112,8 +111,8 @@ def measure_collectives(devices, widths, iters: int,
                     return y + i
 
                 @jax.jit
-                @partial(shard_map, mesh=mesh, in_specs=P("x"),
-                         out_specs=P("x"), check_rep=False)
+                @partial(jax.shard_map, mesh=mesh, in_specs=P("x"),
+                         out_specs=P("x"), check_vma=False)
                 def run(x, body=body, iters=iters):
                     return jax.lax.fori_loop(0, iters, body, x)
 

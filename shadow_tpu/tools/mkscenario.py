@@ -100,10 +100,10 @@ def summarize(cfg: Configuration) -> dict:
     }
 
 
-def run_scenario(cfg: Configuration, argv: List[str]) -> int:
-    """Execute a generated scenario with scale defaults: host table on,
-    heartbeats off (quiet rows stay rows), pure-Python control plane."""
-    from ..core.controller import run_simulation
+def scenario_options(cfg: Configuration, argv: List[str]):
+    """The Options a generated scenario runs with (scale defaults: host
+    table on, heartbeats off) from CLI-style ``argv``; sets the logger
+    and applies ``--stop-time`` to ``cfg``."""
     from ..core.logger import SimLogger, set_logger
     from ..core.options import build_parser, Options
     import dataclasses
@@ -121,6 +121,16 @@ def run_scenario(cfg: Configuration, argv: List[str]) -> int:
     opts.host_table = "on"
     if "--heartbeat-frequency" not in argv:
         opts.heartbeat_interval_sec = 0
+    return opts
+
+
+def run_scenario(cfg: Configuration, argv: List[str]) -> int:
+    """Execute a generated scenario with scale defaults: host table on,
+    heartbeats off (quiet rows stay rows), pure-Python control plane."""
+    from ..core.controller import run_simulation
+    from ..utils.compile_cache import setup_compile_cache
+    opts = scenario_options(cfg, argv)
+    setup_compile_cache()
     return run_simulation(opts, cfg)
 
 

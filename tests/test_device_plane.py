@@ -355,3 +355,37 @@ def test_activate_zero_cells_rejected():
     client = plane.specs[0].client_name
     with pytest.raises(ValueError, match="at least 1 cell"):
         plane.activate(client, cells=0)
+
+
+def test_segment_greedy_int32_prefix_is_exact_past_int32_totals():
+    """The kernel's prefix sum runs in int32 (v5e refuses the int64 scan
+    inside the tick loop): with per-flow backlogs whose running total
+    passes 2**31 but whose per-node backlog fits, the wrapped int32
+    differences equal the int64 greedy exactly."""
+    import jax.numpy as jnp
+    from shadow_tpu.ops.torcells_device import segment_greedy
+
+    rng = np.random.default_rng(21)
+    sizes = [7, 1, 30, 12, 50, 45, 50, 40]
+    seg_start = np.concatenate(
+        [np.full(n, s) for n, s in zip(sizes, np.r_[0, np.cumsum(sizes)])])
+    queued = rng.integers(0, 2 ** 31 // 60, size=len(seg_start))
+    queued[[3, 40, 90]] = 2 ** 31 // 55        # totals wrap several times
+    cap_cells = rng.integers(0, 2 ** 31 // 2, size=len(seg_start))
+    csum = np.cumsum(queued)
+    before = csum - queued - np.where(seg_start > 0, csum[seg_start - 1], 0)
+    want = np.clip(cap_cells - before, 0, queued)
+    assert csum[-1] > 2 ** 31
+    got = segment_greedy(jnp.asarray(queued), jnp.asarray(cap_cells),
+                         jnp.asarray(seg_start))
+    assert np.array_equal(np.asarray(got), want)
+
+
+def test_plane_refuses_injections_past_the_int32_bound(monkeypatch):
+    """More cells in flight than the int32 prefix sums can hold is refused
+    loudly at dispatch, identically on the device and the numpy twin."""
+    import shadow_tpu.ops.torcells_device as td
+    monkeypatch.setattr(td, "MAX_CELLS_IN_FLIGHT", 10)
+    for mode in ("device", "numpy"):
+        with pytest.raises(ValueError, match="cells in flight"):
+            _run(mode=mode)

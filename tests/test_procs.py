@@ -113,6 +113,23 @@ def test_tpu_policy_shards_match_serial():
     assert sharded.events_executed == serial.engine.events_executed
 
 
+def test_tpu_policy_shards_refused_off_the_host_cpu(monkeypatch, tmp_path):
+    """--processes N under the tpu policy would have every shard open the
+    chip, which serves one process at a time: refused at startup with a
+    message — by the coordinator and by the CLI (rc 2) — unless the shards
+    are explicitly put on the host CPU (JAX_PLATFORMS=cpu, as here)."""
+    from shadow_tpu.cli import main
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(ValueError, match="one process holds at a time"):
+        ProcsController(Options(scheduler_policy="tpu", processes=2),
+                        _cfg())
+    cfg_path = tmp_path / "cfg.xml"
+    cfg_path.write_text(XML)
+    assert main([str(cfg_path), "--processes", "2",
+                 "--scheduler-policy", "tpu"]) == 2
+
+
 def test_procs_requires_two():
     with pytest.raises(ValueError):
         ProcsController(Options(processes=1), _cfg())

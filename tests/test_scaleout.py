@@ -51,14 +51,32 @@ def _example(n_rows=16, n_pkts=2048):
 
 def test_device_mesh_is_the_one_pool_definition():
     """parallel/mesh.device_mesh: the shared pool-selection rule — honors
-    the virtual CPU mesh, errors past the pool size, reshapes on demand."""
+    the virtual CPU mesh, raises past the pool size, reshapes on demand."""
     from shadow_tpu.parallel.mesh import device_mesh
     mesh = device_mesh(8, axis_names=("pkt",))
     assert mesh.devices.shape == (8,)
     mesh2 = device_mesh(8, axis_names=("a", "b"), shape=(4, 2))
     assert mesh2.devices.shape == (4, 2)
-    with pytest.raises(RuntimeError):
+    with pytest.raises(RuntimeError, match="only 8 cpu device"):
         device_mesh(10_000)
+
+
+def test_device_mesh_never_switches_pools(monkeypatch):
+    """A default pool with too few devices (one chip, say) raises: the mesh
+    is never rebuilt on another pool such as the host CPU's."""
+    import jax
+    from shadow_tpu.parallel.mesh import device_mesh
+    cpu = jax.devices()
+    asked = []
+
+    def devices(backend=None):
+        asked.append(backend)
+        return cpu if backend is not None else cpu[:1]
+
+    monkeypatch.setattr(jax, "devices", devices)
+    with pytest.raises(RuntimeError, match="only 1 cpu device"):
+        device_mesh(4)
+    assert asked == [None]
 
 
 def test_batch_sharded_matches_single_device():

@@ -145,7 +145,7 @@ def test_sigstopped_plugin_killed_host_survives(native_bin):
 # seam 2: dispatch guard (poisoned / hung device dispatch)
 # ---------------------------------------------------------------------------
 
-def _device_run(mode="device", **opt_kw):
+def _device_run(mode="device", expect_rc=0, **opt_kw):
     cfg = configuration.parse_xml(workloads.tor_network(
         8, n_clients=3, n_servers=2, stoptime=60,
         stream_spec="512:20200", device_data=True))
@@ -153,7 +153,7 @@ def _device_run(mode="device", **opt_kw):
     ctrl = Controller(Options(scheduler_policy="global", workers=0, seed=3,
                               stop_time_sec=60, log_level="warning",
                               device_plane=mode, **opt_kw), cfg)
-    assert ctrl.run() == 0
+    assert ctrl.run() == expect_rc
     return ctrl
 
 
@@ -172,7 +172,41 @@ def test_poisoned_dispatch_numpy_fallback_digest_parity():
     assert plane.demoted and plane.mode == "numpy"
     assert plane.recoveries == 1
     assert faulted.engine.supervision.dispatch_recoveries == 1
+    assert faulted.engine.supervision.unrequested_dispatch_recoveries == 0
     assert state_digest(faulted.engine) == d_clean
+
+
+def test_unrequested_dispatch_recovery_exits_nonzero(monkeypatch):
+    """A dispatch failure that no --fault-inject asked for is still
+    recovered on the numpy twin (the digest stays available), but the run
+    exits non-zero: a run that quietly left the device is not a pass."""
+    clean = _device_run(mode="numpy", tpu_devices=1)
+    import shadow_tpu.ops.torcells_device as td
+    real = td.step_window_flush_for_backend
+    launches = []
+
+    class _Broken:
+        def __array__(self, dtype=None, copy=None):
+            raise RuntimeError("device lost mid-dispatch")
+
+    def failing_second_launch():
+        step = real()
+
+        def run(*args, **kw):
+            out = step(*args, **kw)
+            launches.append(1)
+            return (*out[:9], _Broken()) if len(launches) == 2 else out
+        return run
+
+    monkeypatch.setattr(td, "step_window_flush_for_backend",
+                        failing_second_launch)
+    faulted = _device_run(mode="device", expect_rc=1, tpu_devices=1)
+    plane = faulted.engine.device_plane
+    sup = faulted.engine.supervision
+    assert plane.demoted and plane.recoveries == 1
+    assert sup.unrequested_dispatch_recoveries == 1
+    assert sup.summary()["unrequested_dispatch_recoveries"] == 1
+    assert state_digest(faulted.engine) == state_digest(clean.engine)
 
 
 def test_hung_dispatch_watchdog_recovers_digest_parity():
