@@ -1,0 +1,7 @@
+"""Device traffic plane dispatches (``plane.dispatches``) in the window per
+simulated second."""
+
+
+def read(run):
+    d = run.delta("plane.dispatches")
+    return d / run.sim_s if d is not None and run.sim_s > 0 else None
