@@ -8,6 +8,7 @@ Engine, computes the lookahead, runs the simulation, reports results
 from __future__ import annotations
 
 import os
+import time as _walltime
 from typing import Dict, List, Optional
 
 from ..apps import registry as app_registry
@@ -184,14 +185,21 @@ class Controller:
         proc.app_path = path    # device-plane scan matches on resolved app
 
     def run(self) -> int:
-        self.setup()
+        engine = self.engine
+        t0 = _walltime.perf_counter()
+        with engine.tracer.annotate("setup.hosts"):
+            self.setup()
         # device-mode clients in the workload promote their bulk traffic to
         # the device-resident plane (parallel/device_plane.py); None when
         # the workload has none — the engine hooks are then inert
         from ..parallel.device_plane import build_plane_from_engine
-        self.engine.device_plane = build_plane_from_engine(
-            self.engine, mode=getattr(self.options, "device_plane", "device"))
-        return self.engine.run()
+        with engine.tracer.annotate("setup.plane"):
+            engine.device_plane = build_plane_from_engine(
+                engine, mode=getattr(self.options, "device_plane", "device"))
+        # hosts, table, topology finalize and plane layout, in wall seconds
+        engine.metrics.gauge("setup.build_sec").set(
+            round(_walltime.perf_counter() - t0, 6))
+        return engine.run()
 
 
 def run_simulation(options: Options, config: Configuration) -> int:

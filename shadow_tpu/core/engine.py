@@ -195,6 +195,7 @@ class Engine:
             lambda: round(_walltime.monotonic() - self.sim_start_wall, 3))
         self._checkpoint_counter = self.metrics.counter(
             "engine.checkpoints_written")
+        self._boundary_hooks: List = []
 
     # -- registry ----------------------------------------------------------
     def add_host(self, host, requested_ip: Optional[int] = None) -> None:
@@ -334,6 +335,26 @@ class Engine:
 
     def count_packet_drop(self, packet) -> None:
         self.counters.count_new("packet_drop")
+
+    # -- round-boundary hooks ----------------------------------------------
+    def on_boundary(self, fn) -> None:
+        """Call ``fn(window_end)`` at every round boundary, before the next
+        window is computed: the previous round's host work is done and its
+        device dispatch collected, and ``window_end`` is the simulated time
+        reached.  A hook returning False ends the run there, as a stop time
+        at that boundary would."""
+        self._boundary_hooks.append(fn)
+
+    def _boundary(self) -> bool:
+        """Run the boundary hooks; False when one ends the run."""
+        if not self._boundary_hooks:
+            return True
+        boundary = self.scheduler.window_end
+        for fn in self._boundary_hooks:
+            if fn(boundary) is False:
+                self.end_time = min(self.end_time, boundary)
+                return False
+        return True
 
     # -- misc --------------------------------------------------------------
     def is_running(self) -> bool:
@@ -705,7 +726,8 @@ class Engine:
             # here would forfeit the async launch/consume overlap for the
             # whole run)
             self._consume_flush()
-            with self.tracer.span("checkpoint.write", "engine", sim_ns=ws):
+            with self.tracer.span("checkpoint.write", "engine", sim_ns=ws,
+                                  prof="engine.checkpoint"):
                 path = self._checkpointer.maybe_write(self)
             did = True
             if path:
@@ -890,15 +912,17 @@ class Engine:
                 # materialized here) or a launch is flush-phase work
                 plane_active = plane is not None and plane._inflight
                 with tracer.span("collect", "engine",
-                                 sim_ns=self.scheduler.window_start):
+                                 sim_ns=self.scheduler.window_start,
+                                 prof="engine.collect"):
                     self._consume_flush()
                 self.flush_ns += perf() - tc
-                if not self._advance_window(lookahead):
+                if not (self._boundary() and self._advance_window(lookahead)):
                     break
                 ws = self.scheduler.window_start
                 tl = perf()
                 dispatches0 = plane.dispatches if plane is not None else 0
-                with tracer.span("dispatch.launch", "engine", sim_ns=ws):
+                with tracer.span("dispatch.launch", "engine", sim_ns=ws,
+                                 prof="engine.launch"):
                     self._launch_plane()
                 self.flush_ns += perf() - tl
                 plane_active = plane_active or (
@@ -906,10 +930,12 @@ class Engine:
                 worker.round_end = self.scheduler.window_end
                 t0 = perf()
                 with tracer.span("round", "engine", sim_ns=ws,
-                                 args={"round": self.rounds_executed}):
+                                 args={"round": self.rounds_executed},
+                                 prof="engine.round"):
                     worker.run_round()
                 t1 = perf()
-                with tracer.span("flush", "engine", sim_ns=ws):
+                with tracer.span("flush", "engine", sim_ns=ws,
+                                 prof="engine.flush"):
                     did_flush = self._flush_round()
                 t2 = perf()
                 self.flush_ns += t2 - t1
@@ -920,7 +946,8 @@ class Engine:
                 # compacted flush (ISSUE 10): one pending() read skips the
                 # whole sort-and-emit leg (and its span) on quiet rounds
                 if log.pending():
-                    with tracer.span("log.flush", "engine", sim_ns=ws):
+                    with tracer.span("log.flush", "engine", sim_ns=ws,
+                                     prof="engine.log_flush"):
                         log.flush()
                 elif not (did_flush or plane_active):
                     self.flush_quiet_skips += 1
@@ -969,15 +996,17 @@ class Engine:
                 tc = perf()
                 plane_active = plane is not None and plane._inflight
                 with tracer.span("collect", "engine",
-                                 sim_ns=self.scheduler.window_start):
+                                 sim_ns=self.scheduler.window_start,
+                                 prof="engine.collect"):
                     self._consume_flush()
                 self.flush_ns += perf() - tc
-                if not self._advance_window(lookahead):
+                if not (self._boundary() and self._advance_window(lookahead)):
                     break
                 ws = self.scheduler.window_start
                 tl = perf()
                 dispatches0 = plane.dispatches if plane is not None else 0
-                with tracer.span("dispatch.launch", "engine", sim_ns=ws):
+                with tracer.span("dispatch.launch", "engine", sim_ns=ws,
+                                 prof="engine.launch"):
                     self._launch_plane()
                 self.flush_ns += perf() - tl
                 plane_active = plane_active or (
@@ -985,7 +1014,8 @@ class Engine:
                 t0 = perf()
                 with tracer.span("round", "engine", sim_ns=ws,
                                  args={"round": self.rounds_executed,
-                                       "workers": n}):
+                                       "workers": n},
+                                 prof="engine.round"):
                     start_latch.count_down_await()
                     start_latch.reset()
                     done_latch.count_down_await()
@@ -993,7 +1023,8 @@ class Engine:
                 t1 = perf()
                 if errors:
                     raise errors[0]
-                with tracer.span("flush", "engine", sim_ns=ws):
+                with tracer.span("flush", "engine", sim_ns=ws,
+                                 prof="engine.flush"):
                     did_flush = self._flush_round()
                 t2 = perf()
                 self.flush_ns += t2 - t1
@@ -1002,7 +1033,8 @@ class Engine:
                 self._heartbeat()
                 self._obs_round_end()
                 if log.pending():
-                    with tracer.span("log.flush", "engine", sim_ns=ws):
+                    with tracer.span("log.flush", "engine", sim_ns=ws,
+                                     prof="engine.log_flush"):
                         log.flush()
                 elif not (did_flush or plane_active):
                     self.flush_quiet_skips += 1

@@ -16,7 +16,9 @@ gives all of them one structured home with three cooperating layers:
   SupervisionStats, tracker heartbeats, and device-plane stats as sources
   instead of leaving each its own ad-hoc format;
 * :mod:`obs.profiler` — device-plane hooks (dispatch/collect latency
-  histograms, bytes per flush, pipeline-overlap efficiency) feeding both.
+  histograms, bytes per flush, pipeline-overlap efficiency) feeding both;
+* :mod:`obs.jit` — the process's XLA compile count and seconds
+  (``jit.*``), from JAX's own compile events.
 
 Everything is OFF by default and the disabled path is a handful of
 attribute checks per round (pinned by bench.py's ``obs_overhead_sec``
@@ -52,6 +54,9 @@ def configure_observability(options, shard_id=None, label=None):
     metrics_path = getattr(options, "metrics_path", None)
     registry = MetricsRegistry(enabled=bool(metrics_path))
     set_metrics(registry)
+    from . import jit
+    jit.compile_clock()
+    registry.source("jit", jit.scrape)
     writer = None
     # shard engines record but never write files: their rings/scrapes ride
     # the procs final message and the parent owns the merged outputs (N

@@ -82,14 +82,10 @@ class DeviceProfiler:
                                   "blocked_us": round(blocked_ns / 1e3, 1)})
 
     def on_window(self, launch_ns: int, end_ns: int, blocked_ns: int,
-                  steps: int, granule_ms: int,
-                  predicted_us, band: float, sim_base_ns: int,
-                  exchange_mode: str) -> None:
+                  predicted_us, band: float) -> None:
         """Per-launch attribution (ISSUE 15): pair the model's predicted
-        device cost with the measured launch->collect-end wall, count
-        band violations in ``prof.model_stale``, and emit the
-        sim-correlated ``device.window`` span onto the dedicated
-        ``device-sim`` Chrome-trace track.
+        device cost with the measured launch->collect-end wall and count
+        band violations in ``prof.model_stale``.
 
         The measured span UPPER-bounds the kernel wall (the pipeline
         overlaps host work inside it), so the band check is one-sided
@@ -111,14 +107,3 @@ class DeviceProfiler:
             under = blocked_dominated and measured_us > predicted_us * band
             if over or under:
                 self.model_stale.inc()
-        if self.tracer.enabled:
-            self.tracer.complete(
-                "device.window", "device-sim", launch_ns / 1e9,
-                end_ns / 1e9, sim_base_ns,
-                {"steps": steps,
-                 "sim_span_ms": steps * granule_ms,
-                 "exchange_mode": exchange_mode,
-                 "measured_us": round(measured_us, 1),
-                 "predicted_us": round(predicted_us, 1)
-                 if predicted_us is not None else None},
-                tid="device-sim")

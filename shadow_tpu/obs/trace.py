@@ -21,11 +21,24 @@ shard's ring into the parent, which merges them onto per-shard tracks
 The disabled path returns a shared null span: one attribute check + one
 no-op context manager per call site, pinned ~0 by bench.py's
 ``obs_overhead_sec`` column.
+
+A second sink, the profiler's: a span that names a ``prof`` (the layer
+boundaries: the round loop, the device plane, set-up, ``native.round``,
+the shard exchange) also opens a ``TraceMe`` when a ``jax.profiler``
+session is recording, so the program's phases land on the same host
+timeline as the device's operations in the session's xplane.  Per-event
+spans (``plugin.continue``, ``plugin.rpc``, ``native.run``) name none and
+stay in the ring.  ``annotate`` is the profiler-only form, for phases the
+ring does not record.  With no session the cost is one
+``TraceMe.is_enabled()`` check, and a process that never imported JAX
+never imports jaxlib for it.  ``complete`` records an interval after the
+fact, which a ``TraceMe`` cannot back-date, so it stays in the ring.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time as _walltime
 from collections import deque
@@ -48,20 +61,50 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+_TRACE_ME = None     # jaxlib's TraceMe, once jaxlib has been imported
+
+
+def _profiling() -> bool:
+    """True while a ``jax.profiler`` session records.  jaxlib is looked up
+    among the loaded modules, never imported: a process that has not
+    imported JAX has no session to record into."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        mod = sys.modules.get("jaxlib._profiler")
+        if mod is None:
+            return False
+        _TRACE_ME = mod.TraceMe
+    return _TRACE_ME.is_enabled()
+
+
+def _trace_me(name: str, sim_ns: Optional[int]):
+    """A profiler-sink span (call only when :func:`_profiling`)."""
+    if sim_ns is None:
+        return _TRACE_ME(name)
+    return _TRACE_ME(name, sim_ns=int(sim_ns))
+
 
 class _Span:
-    """One live span: records a Chrome 'X' (complete) event on exit."""
+    """One live span: records a Chrome 'X' (complete) event on exit, and
+    spans the same interval in the profiler sink when it names a
+    ``prof`` and a session records."""
 
-    __slots__ = ("_tracer", "name", "cat", "sim_ns", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "sim_ns", "args", "prof",
+                 "_t0", "_me")
 
-    def __init__(self, tracer, name, cat, sim_ns, args):
+    def __init__(self, tracer, name, cat, sim_ns, args, prof):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.sim_ns = sim_ns
         self.args = args
+        self.prof = prof
 
     def __enter__(self):
+        self._me = None
+        if self.prof is not None and _profiling():
+            self._me = _trace_me(self.prof, self.sim_ns)
+            self._me.__enter__()
         self._t0 = _walltime.perf_counter()
         return self
 
@@ -69,6 +112,8 @@ class _Span:
         self._tracer.complete(self.name, self.cat, self._t0,
                               _walltime.perf_counter(), self.sim_ns,
                               self.args)
+        if self._me is not None:
+            self._me.__exit__(*exc)
         return False
 
 
@@ -114,29 +159,37 @@ class Tracer:
             ring.append(ev)
 
     def complete(self, name: str, cat: str, t0: float, t1: float,
-                 sim_ns: Optional[int], args: Optional[dict],
-                 tid: Optional[str] = None) -> None:
-        """Record a finished span [t0, t1] (perf_counter seconds).
-        ``tid`` overrides the track — the device plane's sim-correlated
-        ``device-sim`` track (obs/profiler.py) gets its own lane in the
-        merged Chrome trace instead of interleaving with the engine
-        thread's round spans."""
+                 sim_ns: Optional[int], args: Optional[dict]) -> None:
+        """Record a finished span [t0, t1] (perf_counter seconds) in the
+        ring."""
         if sim_ns is None:
             sim_ns = self._sim_now()
         self._record({"name": name, "cat": cat, "ph": "X",
                       "ts": round((t0 - self._t0) * 1e6, 3),
                       "dur": round((t1 - t0) * 1e6, 3),
                       "pid": self.shard_id,
-                      "tid": tid or threading.current_thread().name,
+                      "tid": threading.current_thread().name,
                       "args": dict(args, sim_ns=sim_ns) if args
                       else {"sim_ns": sim_ns}})
 
     def span(self, name: str, cat: str = "sim",
-             sim_ns: Optional[int] = None, args: Optional[dict] = None):
-        """Context manager timing a span; a shared no-op when disabled."""
+             sim_ns: Optional[int] = None, args: Optional[dict] = None,
+             prof: Optional[str] = None):
+        """Context manager timing a span into the ring (when enabled) and,
+        where ``prof`` names it, into a recording profiler session; a
+        shared no-op when neither records."""
         if not self.enabled:
+            if prof is not None and _profiling():
+                return _trace_me(prof, sim_ns)
             return NULL_SPAN
-        return _Span(self, name, cat, sim_ns, args)
+        return _Span(self, name, cat, sim_ns, args, prof)
+
+    def annotate(self, prof: str, sim_ns: Optional[int] = None):
+        """A span in the profiler sink only, for a phase the ring does not
+        record: a ``TraceMe`` while a session records, else a no-op."""
+        if _profiling():
+            return _trace_me(prof, sim_ns)
+        return NULL_SPAN
 
     def instant(self, name: str, cat: str = "sim",
                 sim_ns: Optional[int] = None,

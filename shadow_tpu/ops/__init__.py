@@ -9,4 +9,8 @@ non-time quantities so the MXU/VPU paths stay fast.
 
 import jax
 
+from ..obs.jit import compile_clock
+
 jax.config.update("jax_enable_x64", True)
+# count this process's XLA compiles (obs/jit.py) from its first kernel on
+compile_clock()

@@ -317,7 +317,7 @@ def torcells_step_window(t0, queued, ring, tokens, delivered, target,
 # device-side cursor: only chains that completed THIS window and only nodes
 # whose sent-byte counter moved occupy slots; the header carries the counts.
 #
-# Layout ([5 + 2C + 2H] int64, C = chains, H = nodes):
+# Layout ([6 + 2C + 2H] int64, C = chains, H = nodes):
 #   [0] forwards this window
 #   [1] cumulative delivered cells summed over chain-exit flows
 #   [2] n_done   — chains newly completed this window
@@ -328,13 +328,16 @@ def torcells_step_window(t0, queued, ring, tokens, delivered, target,
 #                  _step_span_impl); carried in the flush so the host never
 #                  pays a second device read to learn where a multi-round
 #                  dispatch stopped
-#   [5        : 5+n_done]        newly-done chain indices (ascending)
-#   [5+C      : 5+C+n_done]      their completion steps
-#   [5+2C     : 5+2C+n_nodes]    touched node indices (ascending)
-#   [5+2C+H   : 5+2C+H+n_nodes]  their sent-byte deltas
+#   [5] moved    — (flow, tick) pairs this window in which a flow moved at
+#                  least one cell: the kernel's useful work, counted the
+#                  same way whatever implements the tick (flush_moved)
+#   [6        : 6+n_done]        newly-done chain indices (ascending)
+#   [6+C      : 6+C+n_done]      their completion steps
+#   [6+2C     : 6+2C+n_nodes]    touched node indices (ascending)
+#   [6+2C+H   : 6+2C+H+n_nodes]  their sent-byte deltas
 # ---------------------------------------------------------------------------
 
-FLUSH_HEADER = 5
+FLUSH_HEADER = 6
 
 
 def flush_len(n_chains: int, n_nodes: int,
@@ -352,7 +355,7 @@ def flush_len(n_chains: int, n_nodes: int,
 
 def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
                     sent_delta, cap_chains: Optional[int] = None,
-                    cap_nodes: Optional[int] = None):
+                    cap_nodes: Optional[int] = None, moved=0):
     """newly bool [C], done_last int64 [C], sent_delta int64 [H] -> packed
     buffer.  Compaction is a cumsum-cursor scatter; out-of-range slots (the
     unselected lanes) are dropped on device.  With caps the buffer is the
@@ -378,6 +381,7 @@ def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
     buf = buf.at[2].set(jnp.sum(newly.astype(jnp.int64)))
     buf = buf.at[3].set(jnp.sum(touched.astype(jnp.int64)))
     buf = buf.at[4].set(t_stop)
+    buf = buf.at[5].set(moved)
     base = jnp.int64(FLUSH_HEADER)
     buf = buf.at[jnp.where(sel_c, base + pos_c, oob)].set(
         jnp.arange(c, dtype=jnp.int64), mode="drop")
@@ -391,7 +395,7 @@ def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
 
 
 def pack_flush_np(forwards, delivered_sum, t_stop, newly, done_last,
-                  sent_delta):
+                  sent_delta, moved=0):
     """Bit-identical host twin of _pack_flush_jnp."""
     c = len(newly)
     h = len(sent_delta)
@@ -403,6 +407,7 @@ def pack_flush_np(forwards, delivered_sum, t_stop, newly, done_last,
     buf[2] = len(ci)
     buf[3] = len(ni)
     buf[4] = t_stop
+    buf[5] = moved
     base = FLUSH_HEADER
     buf[base:base + len(ci)] = ci
     buf[base + c:base + c + len(ci)] = np.asarray(done_last)[ci]
@@ -410,6 +415,11 @@ def pack_flush_np(forwards, delivered_sum, t_stop, newly, done_last,
     buf[base + 2 * c + h:base + 2 * c + h + len(ni)] = \
         np.asarray(sent_delta)[ni]
     return buf
+
+
+def flush_moved(buf: np.ndarray) -> int:
+    """The flush header's count of (flow, tick) pairs that moved a cell."""
+    return int(buf[5])
 
 
 def flush_overflowed(buf: np.ndarray, cap_chains: int,
@@ -464,7 +474,8 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
 
     Per-tick math is byte-for-byte the _step_window_impl body (pinned by
     tests/test_superwindow.py's span-vs-sequential-windows parity case).
-    Returns the same 9-tuple, with [0] = the boundary actually reached."""
+    Returns the same 9-tuple, with [0] = the boundary actually reached,
+    plus [9] = the (flow, tick) pairs in which a flow served a cell."""
     f = queued.shape[0]
     h = refill.shape[0]
     p = targets.shape[0]
@@ -483,7 +494,7 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
 
     def body(state):
         (t, idx, halt, span_done, queued, hist, tokens, delivered, target,
-         done_tick, node_sent, forwards) = state
+         done_tick, node_sent, forwards, moved) = state
         arr = hist[jnp.mod(t - arr_lat, ring_len), cols]
         queued = queued + arr
         tokens = jnp.minimum(capacity, tokens + refill)
@@ -502,6 +513,7 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
             jnp.where(is_last, jnp.int64(0), served))
         hist = hist.at[jnp.mod(t, ring_len)].set(v.astype(hist.dtype))
         forwards = forwards + jnp.sum(served)
+        moved = moved + jnp.sum((served > 0).astype(jnp.int64))
         # sub-window bookkeeping: at a boundary, halt iff this span saw a
         # completion; otherwise roll into the next span with a clean flag
         span_done = span_done | jnp.any(newly_done)
@@ -510,14 +522,14 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
         idx = jnp.where(boundary, idx + 1, idx)
         span_done = span_done & ~boundary
         return (t + 1, idx, halt, span_done, queued, hist, tokens,
-                delivered, target, done_tick, node_sent, forwards)
+                delivered, target, done_tick, node_sent, forwards, moved)
 
     def cond(state):
         return (state[0] < end) & ~state[2]
 
     state = (t0, jnp.int64(0), jnp.bool_(False), jnp.bool_(False),
              queued, ring, tokens, delivered, target, done_tick,
-             node_sent, jnp.int64(0))
+             node_sent, jnp.int64(0), jnp.int64(0))
     out = jax.lax.while_loop(cond, body, state)
     return (out[0], *out[4:])
 
@@ -530,7 +542,8 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
                           cap_chains: Optional[int] = None,
                           cap_nodes: Optional[int] = None):
     """Superwindow step + packed flush in ONE dispatch: the 9-tuple of
-    _step_span_impl with the packed flush buffer appended as [9].
+    _step_span_impl with the packed flush buffer appended as [9] (its
+    moved count rides in the flush header).
     ``last_flow`` [C] maps each chain to its exit flow row.  With caps
     the flush is the capped (delta-compacted) buffer — see
     _pack_flush_jnp."""
@@ -545,8 +558,8 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
     newly = (done_last >= 0) & (done_in_last < 0)
     flush = _pack_flush_jnp(out[8], jnp.sum(out[4][last_flow]), out[0],
                             newly, done_last, out[7] - node_sent_in,
-                            cap_chains, cap_nodes)
-    return (*out, flush)
+                            cap_chains, cap_nodes, moved=out[9])
+    return (*out[:9], flush)
 
 
 # Two jit wrappers over the SAME flush program, picked by backend
@@ -637,8 +650,8 @@ def torcells_step_span_numpy(t0, queued, ring, tokens, delivered, target,
                              flow_succ, seg_start, refill, capacity,
                              ring_len: int):
     """Bit-identical host twin of _step_span_impl (same boundary/halt
-    rule) — the parity oracle and the --device-plane=numpy execution
-    mode's superwindow step."""
+    rule, same 10-tuple) — the parity oracle and the --device-plane=numpy
+    execution mode's superwindow step."""
     f = len(queued)
     h = len(refill)
     size = CELL_WIRE_BYTES
@@ -655,6 +668,7 @@ def torcells_step_span_numpy(t0, queued, ring, tokens, delivered, target,
     bounds = [int(x) for x in np.asarray(targets)]
     end = bounds[-1]
     forwards = 0
+    moved = 0
     t = int(t0)
     idx = 0
     span_done = False
@@ -681,6 +695,7 @@ def torcells_step_span_numpy(t0, queued, ring, tokens, delivered, target,
         np.add.at(v, np.maximum(flow_succ, 0), np.where(is_last, 0, served))
         ring[t % ring_len] = v
         forwards += int(served.sum())
+        moved += int(np.count_nonzero(served))
         span_done = span_done or bool(newly_done.any())
         t += 1
         if t == bounds[min(idx, len(bounds) - 1)]:
@@ -689,7 +704,7 @@ def torcells_step_span_numpy(t0, queued, ring, tokens, delivered, target,
                 break
             span_done = False
     return (np.int64(t), queued, ring, tokens, delivered, target, done_tick,
-            node_sent, np.int64(forwards))
+            node_sent, np.int64(forwards), np.int64(moved))
 
 
 def torcells_step_window_numpy_flush(t0, queued, ring, tokens, delivered,
@@ -711,8 +726,8 @@ def torcells_step_window_numpy_flush(t0, queued, ring, tokens, delivered,
     newly = (done_last >= 0) & (done_in_last < 0)
     flush = pack_flush_np(int(out[8]), int(out[4][last_flow].sum()),
                           int(out[0]), newly, done_last,
-                          out[7] - node_sent_in)
-    return (*out, flush)
+                          out[7] - node_sent_in, moved=int(out[9]))
+    return (*out[:9], flush)
 
 
 def torcells_step_window_numpy(t0, queued, ring, tokens, delivered, target,

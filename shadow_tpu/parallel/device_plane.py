@@ -401,8 +401,23 @@ class DeviceTrafficPlane:
         self.total_forwards = 0
         self.total_injected_cells = 0
         self.dispatches = 0
-        self.device_ns = 0
-        self.host_ns = 0
+        # the plane's wall, split where the work happens: host = launch_ns
+        # (dispatch prep up to the jit call's return) + fold_ns (the fold
+        # of a collected flush); device = wait_ns (blocked on the in-flight
+        # dispatch) + readback_ns (the device->host copy of its flush).
+        # idle_ns: wall with no dispatch in flight, from a collect's
+        # readback to the next launch's return
+        self.launch_ns = 0
+        self.fold_ns = 0
+        self.wait_ns = 0
+        self.readback_ns = 0
+        self.idle_ns = 0
+        self._idle_from: Optional[int] = None
+        # kernel ticks executed (t_stop - launch base; banked idle ticks
+        # are a re-base jump, not ticks) and the (flow, tick) pairs in
+        # which a flow moved a cell (the flush header's count)
+        self.ticks_stepped = 0
+        self.flow_ticks_moved = 0
         # pipeline introspection: actual host<->device interactions (kernel
         # dispatch + inject upload + flush read) and the wall the in-flight
         # dispatch had to compute behind host round work
@@ -912,6 +927,10 @@ class DeviceTrafficPlane:
         """Pre-compile the windowed kernel for this plane's exact shapes
         using throwaway state (XLA compiles are 20-40s on a real TPU; the
         bench excludes them from timed walls).  No plane state is touched."""
+        with self._profiler.tracer.annotate("plane.warmup"):
+            self._warmup()
+
+    def _warmup(self) -> None:
         if self.mode != "device":
             return
         if self._lane is not None:
@@ -1105,6 +1124,16 @@ class DeviceTrafficPlane:
             # (the kernel may halt at an earlier boundary on a completion)
             targets = plan.targets
             n = targets[-1] - self._ticks_synced
+        with self._profiler.tracer.annotate(
+                "plane.launch", sim_ns=engine.scheduler.window_start):
+            self._launch(engine, plan, targets, n, t0)
+
+    def _launch(self, engine, plan: Optional[_SuperPlan], targets: List[int],
+                n: int, t0: int) -> None:
+        """advance()'s dispatch: fold the staged injections in at the base
+        step and launch the kernel through ``targets`` (``n`` ticks past
+        the synced step); ``t0`` is advance()'s entry stamp."""
+        import time as _wt
         inject_pairs = list(self._inject_buf)
         if self._inject_buf:
             f = self.n_flows
@@ -1259,7 +1288,10 @@ class DeviceTrafficPlane:
                     + max(ex_us, 0.0),
                     self._costmodel.transfer_us())
         self._launch_wall = _wt.perf_counter_ns()
-        self.host_ns += self._launch_wall - t0
+        self.launch_ns += self._launch_wall - t0
+        if self._idle_from is not None:
+            self.idle_ns += self._launch_wall - self._idle_from
+            self._idle_from = None
         self._profiler.on_dispatch(t0, self._launch_wall, int(n),
                                    len(inject_pairs), self.dispatches,
                                    engine.scheduler.window_end)
@@ -1280,6 +1312,7 @@ class DeviceTrafficPlane:
         # the collect succeeds, raises, or is recovered
         handle, self._flush_handle = self._flush_handle, None
         self._inflight = False
+        t_read = None
         with self._profiler.tracer.span(
                 "device.collect", "device",
                 sim_ns=engine.scheduler.window_start,
@@ -1289,20 +1322,35 @@ class DeviceTrafficPlane:
                 # in-flight dispatch RAISES here (guarded by
                 # --device-watchdog-sec), and the dispatch guard recovers
                 # it on the numpy twin
-                flush = self._collect_flush(engine, handle)
+                flush, t_read = self._collect_flush(engine, handle)
             except Exception as e:  # noqa: BLE001 - any dispatch failure
                 flush = self._recover_dispatch(
                     engine, e, injected=isinstance(handle, _PoisonedFlush))
         t1 = _wt.perf_counter_ns()
-        self.device_ns += t1 - t0
+        # wait up to the readback's start; a recovered dispatch read
+        # nothing from the device, so all of it counts as wait
+        t_read = t1 if t_read is None else min(max(t_read, t0), t1)
+        self.wait_ns += t_read - t0
+        self.readback_ns += t1 - t_read
         self._profiler.on_collect(self._launch_wall, t0, t1 - t0,
                                   int(getattr(flush, "nbytes", 0)),
                                   self.dispatches,
                                   engine.scheduler.window_start)
+        with self._profiler.tracer.annotate(
+                "plane.fold", sim_ns=engine.scheduler.window_start):
+            self._fold(engine, flush, t0, t1)
+        self.fold_ns += _wt.perf_counter_ns() - t1
+        self._idle_from = t1
+
+    def _fold(self, engine, flush: np.ndarray, t0: int, t1: int) -> None:
+        """consume()'s host fold of one collected flush buffer (``t0``,
+        ``t1``: the collect's start and end stamps): parse it, advance the
+        window bookkeeping, fold node byte deltas and wake completed
+        flows."""
         if self.mode == "device":
             self.device_calls += 1              # the flush read
-        from ..ops.torcells_device import (flush_len, flush_overflowed,
-                                           parse_flush)
+        from ..ops.torcells_device import (flush_len, flush_moved,
+                                           flush_overflowed, parse_flush)
         caps, self._inflight_caps = self._inflight_caps, None
         args, self._inflight_args = self._inflight_args, None
         if caps is not None and self.mode != "device":
@@ -1328,29 +1376,26 @@ class DeviceTrafficPlane:
         (forwards, delivered_sum, t_stop, done_chains, done_steps, node_idx,
          node_delta) = parse_flush(flush, self.n_chains, self.n_nodes,
                                    *(caps or (None, None)))
+        steps_done = max(int(t_stop) - self._launch_base, 0)
+        self.ticks_stepped += steps_done
+        self.flow_ticks_moved += flush_moved(flush)
         # launch attribution (ISSUE 15): predicted-vs-measured per-launch
-        # gauges, the model-stale band check, and the sim-correlated
-        # device track span — one call per collect, ~free when no model
-        # is loaded and observability is off.  Placed AFTER parse_flush
+        # gauges and the model-stale band check — one call per collect,
+        # ~free when no model is loaded and observability is off.  Placed AFTER parse_flush
         # so the prediction covers the steps the kernel actually REACHED
         # (t_stop): a superwindow halting early on a completion is
         # judged on its real span, never flagged stale for not running
         # the merged rounds it skipped.  Device mode only — the numpy
         # twin's host-side walls must not pollute the launch gauges.
         if self.mode == "device":
-            steps_done = max(int(t_stop) - self._launch_base, 0)
             pred_us = None
             if self._launch_pred is not None:
                 per_step, fixed = self._launch_pred
                 pred_us = steps_done * per_step + fixed
             self._profiler.on_window(
-                self._launch_wall, t1, t1 - t0, steps_done,
-                self.granule, pred_us,
+                self._launch_wall, t1, t1 - t0, pred_us,
                 self._costmodel.band if self._costmodel is not None
-                else 0.0,
-                engine.scheduler.window_start,
-                self._meshinfo.exchange_mode if self._meshinfo is not None
-                else "single")
+                else 0.0)
         if self._meshinfo is not None:
             # mesh flush: ONE trailing slot carries the window's
             # cross-shard cell count (zero extra device reads; a
@@ -1457,18 +1502,33 @@ class DeviceTrafficPlane:
             self._probation_clean += 1
             if self._probation_clean >= self._repromote_after:
                 self._repromote(engine)
-        self.host_ns += _wt.perf_counter_ns() - t1
 
-    def _collect_flush(self, engine, handle) -> np.ndarray:
-        """Materialize the in-flight dispatch's flush buffer, bounded by
-        ``--device-watchdog-sec`` in device mode: the blocking read runs on
+    def _read_flush(self, handle) -> Tuple[np.ndarray, int]:
+        """Block until the dispatch behind ``handle`` is done, then copy
+        its flush buffer to the host: (buffer, perf_counter_ns stamp
+        between the two).  A handle that is already host memory (the numpy
+        twin, a fleet lane's row) has nothing to wait for."""
+        import time as _wt
+        tracer = self._profiler.tracer
+        block = getattr(handle, "block_until_ready", None)
+        if block is not None:
+            with tracer.annotate("plane.wait"):
+                block()
+        t_read = _wt.perf_counter_ns()
+        with tracer.annotate("plane.readback"):
+            return np.asarray(handle), t_read
+
+    def _collect_flush(self, engine, handle) -> Tuple[np.ndarray, int]:
+        """Materialize the in-flight dispatch's flush buffer (see
+        _read_flush), bounded by ``--device-watchdog-sec`` in device mode:
+        the blocking read runs on
         a helper thread so a dispatch that never completes (wedged runtime,
         lost device) raises TimeoutError here instead of freezing
         the round loop forever.  Only the guard's bookkeeping (thread spawn
         + join return) is charged to supervision overhead — the wait for
         the result is the dispatch's own cost, watchdog or not."""
         if self.mode != "device" or self._watchdog_sec <= 0:
-            return np.asarray(handle)
+            return self._read_flush(handle)
         import threading
         import time as _wt
         t_g = _wt.perf_counter_ns()
@@ -1482,7 +1542,7 @@ class DeviceTrafficPlane:
 
         def _work() -> None:
             try:
-                out = np.asarray(handle)
+                out = self._read_flush(handle)
             except BaseException as e:  # noqa: BLE001 - forwarded below
                 with box_lock:
                     box["err"] = e
@@ -1790,6 +1850,15 @@ class DeviceTrafficPlane:
         # mesh introspection is NOT mirrored here: the mesh.* registry
         # source (mesh/meshplane.py) is the one spelling of those
         # counters — readers scrape the registry like every other source
+        import time as _wt
+        host_ns = self.launch_ns + self.fold_ns
+        device_ns = self.wait_ns + self.readback_ns
+        idle_ns = self.idle_ns
+        if self._idle_from is not None:
+            # the idle interval still open: a scrape inside it (a window
+            # opened between a collect and the next launch) reads the
+            # idle wall up to now, so differences of scrapes are exact
+            idle_ns += _wt.perf_counter_ns() - self._idle_from
         return {
             "circuits": len(self.specs),
             "injected_cells": self.total_injected_cells,
@@ -1824,8 +1893,20 @@ class DeviceTrafficPlane:
             # tracked but never exported, hiding ~half the flagship wall):
             # host_sec = advance() dispatch prep + wake bookkeeping;
             # device_sec = blocking materialization of dispatch summaries
-            "plane_host_sec": round(self.host_ns / 1e9, 3),
-            "plane_device_sec": round(self.device_ns / 1e9, 3),
+            "plane_host_sec": round(host_ns / 1e9, 3),
+            "plane_device_sec": round(device_ns / 1e9, 3),
+            # ... and split where the work happens, plus the wall with no
+            # dispatch in flight
+            "launch_sec": round(self.launch_ns / 1e9, 6),
+            "fold_sec": round(self.fold_ns / 1e9, 6),
+            "wait_sec": round(self.wait_ns / 1e9, 6),
+            "readback_sec": round(self.readback_ns / 1e9, 6),
+            "idle_sec": round(idle_ns / 1e9, 6),
+            # the kernel's work: ticks executed, and (flow, tick) pairs in
+            # which a flow moved a cell — a denominator that does not
+            # depend on how the tick is implemented
+            "ticks_stepped": self.ticks_stepped,
+            "flow_ticks_moved": self.flow_ticks_moved,
             # pipeline introspection: host<->device interactions (dispatch +
             # inject upload + flush read; <= 3 per dispatch) and the wall
             # the in-flight dispatch computed behind host round work
@@ -1836,7 +1917,7 @@ class DeviceTrafficPlane:
             # never blocked (obs/profiler.py reads the same definition)
             "overlap_efficiency": round(
                 self.pipeline_overlap_ns
-                / max(self.pipeline_overlap_ns + self.device_ns, 1), 4),
+                / max(self.pipeline_overlap_ns + device_ns, 1), 4),
         }
 
 

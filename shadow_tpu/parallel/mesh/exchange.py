@@ -254,7 +254,8 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
     the flush packing; the arrival ring and arr_lat are SHARD-LOCAL
     (sharded in_specs), unlike the PR-7 kernel's replicated ring.  Returns
     the usual 9-tuple plus [9] = cross-shard cells exchanged this window
-    (psum'd, replicated).
+    (psum'd, replicated) and [10] = the (flow, tick) pairs in which a flow
+    served a cell, over every shard.
 
     ``leg_mask`` (ISSUE 16 quiet-tick fusion) is a STATIC per-leg bool
     tuple: a False leg issues NO collective this variant.  Safe whenever
@@ -372,7 +373,8 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
 
             def body(state):
                 (t, idx, halt, span_done, queued, ring, tokens, delivered,
-                 target, done_tick, node_sent, forwards, cross) = state
+                 target, done_tick, node_sent, forwards, cross,
+                 moved) = state
                 # arrivals: my rows' sends from arr_lat steps ago, out of
                 # MY ring slice (columns with no predecessor gather zeros)
                 arr = ring[jnp.mod(t - arr_lat, ring_len), cols]
@@ -420,13 +422,17 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
                             jnp.where(l_dst >= 0, got, jnp.int64(0)))
                 ring = ring.at[jnp.mod(t, ring_len)].set(
                     v.astype(ring.dtype))
-                # fused stats reduction: forwards + the global completion
-                # flag (any shard's newly-done chain halts every shard at
-                # the same sub-window boundary) ride ONE psum per tick
+                # fused stats reduction: forwards, the moved flow count
+                # and the global completion flag (any shard's newly-done
+                # chain halts every shard at the same sub-window
+                # boundary) ride ONE psum per tick
                 stats = jax.lax.psum(
                     jnp.stack([jnp.sum(served),
-                               jnp.sum(newly.astype(jnp.int64))]), axis)
+                               jnp.sum(newly.astype(jnp.int64)),
+                               jnp.sum((served > 0).astype(jnp.int64))]),
+                    axis)
                 forwards = forwards + stats[0]
+                moved = moved + stats[2]
                 span_done = span_done | (stats[1] > 0)
                 boundary = (t + 1) == targets[jnp.minimum(idx, p - 1)]
                 halt = boundary & span_done
@@ -434,18 +440,19 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
                 span_done = span_done & ~boundary
                 return (t + 1, idx, halt, span_done, queued, ring, tokens,
                         delivered, target, done_tick, node_sent, forwards,
-                        cross)
+                        cross, moved)
 
             def cond(state):
                 return (state[0] < end) & ~state[2]
 
             state = (t0, jnp.int64(0), jnp.bool_(False), jnp.bool_(False),
                      queued, ring, tokens, delivered, target,
-                     done_tick, node_sent, jnp.int64(0), jnp.int64(0))
+                     done_tick, node_sent, jnp.int64(0), jnp.int64(0),
+                     jnp.int64(0))
             out = jax.lax.while_loop(cond, body, state)
             # every exchanged cell was counted once, at its receiver
             cross_total = jax.lax.psum(out[12], axis)
-            return (out[0], *out[4:12], cross_total)
+            return (out[0], *out[4:12], cross_total, out[13])
 
         sharded = P(axis)
         repl = P()
@@ -456,7 +463,7 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
                       repl, sharded, sharded, sharded, sharded, sharded,
                       sharded, sharded),
             out_specs=(repl, sharded, P(None, axis), sharded, sharded,
-                       sharded, sharded, sharded, repl, repl),
+                       sharded, sharded, sharded, repl, repl, repl),
             check_vma=False)(
             t0, queued, ring, tokens, delivered, target, done_tick,
             node_sent, inject, inject_target, targets, idle_ticks,
@@ -509,7 +516,7 @@ def make_mesh_span_flush(mesh, axis: str, ring_len: int, layout: dict,
         newly = (done_last >= 0) & (done_in_last < 0)
         flush = _pack_flush_jnp(out[8], jnp.sum(out[4][lf]), out[0], newly,
                                 done_last, global_sent(out[7]) - sent_in,
-                                cap_chains, cap_nodes)
+                                cap_chains, cap_nodes, moved=out[10])
         flush = jnp.concatenate([flush, out[9][None]])
         return (*out[:9], flush)
 

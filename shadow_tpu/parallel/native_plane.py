@@ -557,12 +557,8 @@ class NativeGlobalPolicy(GlobalSinglePolicy):
                 if self._fault_countdown == 0:
                     raise RuntimeError(
                         "fault injection: native round executor")
-            if self._tracer.enabled:
-                with self._tracer.span("native.round", "native",
-                                       sim_ns=we):
-                    self._plane.c.run_window(we, q.peek_key(), py_exec,
-                                             batch)
-            else:
+            with self._tracer.span("native.round", "native", sim_ns=we,
+                                   prof="native.round"):
                 self._plane.c.run_window(we, q.peek_key(), py_exec, batch)
         except BaseException as e:
             if e is self._py_exc or e is self._plane.sim_exc \

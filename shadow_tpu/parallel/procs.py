@@ -155,12 +155,15 @@ def _shard_body(conn, options, config) -> None:
                 engine.host_table.promote_due(we)
             with tracer.span("round", "engine", sim_ns=ws,
                              args={"round": engine.rounds_executed,
-                                   "shard": engine.shard_id}):
+                                   "shard": engine.shard_id},
+                             prof="engine.round"):
                 worker.run_round()
-            with tracer.span("flush", "engine", sim_ns=ws):
+            with tracer.span("flush", "engine", sim_ns=ws,
+                             prof="engine.flush"):
                 engine._flush_round()
             conn.send(("out", engine.drain_outboxes()))
-            with tracer.span("exchange", "engine", sim_ns=ws):
+            with tracer.span("exchange", "engine", sim_ns=ws,
+                             prof="procs.exchange"):
                 inbox = conn.recv()[1]
             for t, dst_id, src_id, seq, wire in inbox:
                 if engine.native_plane is not None:
@@ -592,7 +595,8 @@ class ProcsController:
                     for s in range(n):
                         for d in range(n):
                             inboxes[d].extend(outs[s][d])
-                with self.tracer.span("exchange", "procs", sim_ns=ws):
+                with self.tracer.span("exchange", "procs", sim_ns=ws,
+                                      prof="procs.exchange"):
                     for sid in range(n):
                         if not in_sent[sid]:
                             self._send(sid, ("in", inboxes[sid]))
@@ -681,7 +685,8 @@ class ProcsController:
                     break
                 ws, we = nxt, min(nxt + lookahead, end_time)
                 with self.tracer.span("round", "procs", sim_ns=ws,
-                                      args={"round": self.rounds_executed}):
+                                      args={"round": self.rounds_executed},
+                                      prof="procs.round"):
                     mins = self._drive_round(ws, we)
                 last_ws = ws
                 if resume_snap is not None \

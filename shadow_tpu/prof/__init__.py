@@ -26,8 +26,7 @@ measured-schedule framing of *FAST* (arXiv 2505.09764):
 
 Live attribution rides the existing observability plane: the device
 plane publishes per-launch predicted-vs-measured histograms under
-``prof.*`` and a sim-time-correlated ``device-sim`` track into the
-Chrome trace; a drifting model (measured/predicted outside the band)
+``prof.*``; a drifting model (measured/predicted outside the band)
 raises the loud ``prof.model_stale`` counter instead of silently
 mis-scheduling.
 """

@@ -12,8 +12,7 @@
    ever change WHICH identical-result kernel runs.
 4. Live attribution: per-launch predicted-vs-measured gauges land in the
    prof.* scrape, an absurd model raises prof.model_stale, out-of-range
-   tables are NOT judged (no extrapolation false-positives), and the
-   sim-correlated device.window track merges into the Chrome trace.
+   tables are NOT judged (no extrapolation false-positives).
 5. Histogram percentile schema (p50/p95/p99) + trace_report --metrics.
 6. The trend ledger: append/load, trace_report --trend rendering with
    regression flags, and the --trend CLI.
@@ -302,28 +301,6 @@ def test_attribution_gauges_and_stale_counter(tmp_path):
     scrape = ctrl.engine.metrics.scrape()
     assert scrape["prof.launches_checked"] == 0
     assert scrape["prof.model_stale"] == 0
-
-
-def test_device_window_track_in_chrome_trace(tmp_path):
-    """The sim-correlated device track: one device.window span per
-    collect on the dedicated device-sim track, carrying sim_ns and the
-    measured/predicted pair, merged into the same Chrome trace file the
-    flight recorder already writes."""
-    trace = str(tmp_path / "trace.json")
-    _run(STAR_XML, cost_model=_write_model(tmp_path), trace_path=trace)
-    from shadow_tpu.tools.trace_report import load_events, summarize
-    events = load_events(trace)
-    wins = [e for e in events if e["name"] == "device.window"]
-    assert wins, "no device.window spans in the trace"
-    assert all(e["tid"] == "device-sim" for e in wins)
-    for e in wins:
-        assert e["args"]["sim_ns"] >= 0
-        assert e["args"]["measured_us"] > 0
-        assert e["args"]["exchange_mode"] in ("fused", "ppermute",
-                                              "none", "single")
-    # the report folds the new track like any other (one tracks entry)
-    rep = summarize(events)
-    assert any(t.endswith(":device-sim") for t in rep["tracks"])
 
 
 # -- 5. percentile schema --------------------------------------------------
