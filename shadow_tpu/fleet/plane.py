@@ -46,6 +46,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+# torcells_step_span_flush_batched's array operands, ring_len aside
+_N_OPERANDS = 21
+
 
 def _pow2(n: int) -> int:
     return 1 if n <= 1 else 1 << int(n - 1).bit_length()
@@ -128,12 +131,14 @@ class _ShapeClass:
                 np.zeros(h2, i64),                        # refill
                 np.zeros(h2, i64),                        # capacity
                 np.full(c2, f2 - 1, i64),                 # last_flow
+                np.full(f2, -1, i64),                     # flow_pred
+                np.zeros((2, h2), i64),                   # node_seg
             )
         return self._filler
 
 
 class _Submit:
-    """One lane's staged dispatch: the 19 padded kernel operands, filled
+    """One lane's staged dispatch: the 21 padded kernel operands, filled
     in with its batch row (or an error) by the launching thread."""
 
     __slots__ = ("lane", "args", "result", "error")
@@ -192,6 +197,10 @@ class FleetLane:
             _pad_vec(np.asarray(dev_plane.capacity_step, i64), h2, 0),
             # padded chains exit through a guaranteed-zero padding flow
             _pad_vec(np.asarray(dev_plane.last_flow, i64), c2, f2 - 1),
+            # padding flows have no predecessor; padding nodes no segment
+            _pad_vec(np.asarray(dev_plane.flow_pred, i64), f2, -1),
+            np.pad(np.asarray(dev_plane.node_seg, i64),
+                   ((0, 0), (0, h2 - h))),
         )
 
     def dispatch(self, state: tuple, inject, inject_target, tvec,
@@ -364,11 +373,12 @@ class FleetPlane:
             np.asarray([r[i] for r in rows])
             if np.ndim(rows[0][i]) == 0
             else np.stack([r[i] for r in rows])
-            for i in range(19))
+            for i in range(_N_OPERANDS))
         if self._use_numpy:
+            # the twin takes every operand but the two gather tables
             from ..ops.torcells_device import torcells_step_span_batched_numpy
             out = torcells_step_span_batched_numpy(
-                *stacked, ring_len=cls.ring_len)
+                *stacked[:-2], ring_len=cls.ring_len)
         else:
             from ..ops.torcells_device import torcells_step_span_flush_batched
             out = torcells_step_span_flush_batched(

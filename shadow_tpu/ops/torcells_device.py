@@ -95,11 +95,45 @@ def build_flows(route: np.ndarray,          # int32 [C, 5] node per stage
     seg_id = np.cumsum(np.r_[0, (flow_node[1:] != flow_node[:-1])
                              .astype(np.int64)])
     seg_start_of_flow = starts[seg_id]
+    flow_pred, node_seg = gather_tables(flow_node, succ,
+                                        latency_ticks.shape[0])
     return {
         "flow_circ": flow_circ, "flow_stage": flow_stage,
         "flow_node": flow_node, "flow_lat": lat, "flow_succ": succ,
-        "seg_start": seg_start_of_flow,
+        "seg_start": seg_start_of_flow, "flow_pred": flow_pred,
+        "node_seg": node_seg,
     }
+
+
+def gather_tables(flow_node: np.ndarray, flow_succ: np.ndarray,
+                  n_nodes: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The static tables the span-flush tick reads by gather where it
+    would otherwise scatter, built once on the host from the flow layout:
+
+    * ``flow_pred`` int64 [F]: the flow whose successor is this one, or -1
+      (the inverse of the injective ``flow_succ``);
+    * ``node_seg`` int64 [2, H]: each node's flow segment as [first, end)
+      positions, [0, 0) for a node that paces no flow.
+
+    Refuses a layout in which a node's flows are not one contiguous
+    segment or two flows share a successor: the kernel's node totals and
+    successor sends would then be wrong, not slow."""
+    flow_node = np.asarray(flow_node, dtype=np.int64)
+    flow_succ = np.asarray(flow_succ, dtype=np.int64)
+    f = len(flow_node)
+    has_succ = np.flatnonzero(flow_succ >= 0)
+    flow_pred = np.full(f, -1, dtype=np.int64)
+    flow_pred[flow_succ[has_succ]] = has_succ
+    if np.count_nonzero(flow_pred >= 0) != len(has_succ):
+        raise ValueError("flow_succ is not injective")
+    starts = np.flatnonzero(np.r_[True, flow_node[1:] != flow_node[:-1]])
+    nodes = flow_node[starts]
+    if len(np.unique(nodes)) != len(nodes):
+        raise ValueError("a node's flows are not one contiguous segment")
+    node_seg = np.zeros((2, n_nodes), dtype=np.int64)
+    node_seg[0, nodes] = starts
+    node_seg[1, nodes] = np.r_[starts[1:], f]
+    return flow_pred, node_seg
 
 
 from functools import partial
@@ -118,12 +152,37 @@ def segment_greedy(queued, cap_cells, seg_start):
     segment-relative difference exact whenever its true value fits in
     int32; it is at most the cells queued at one node, which the plane
     keeps below MAX_CELLS_IN_FLIGHT."""
+    return _segment_greedy(queued, cap_cells, seg_start)[0]
+
+
+def _prefix_before(csum, pos):
+    """The int32 prefix total of the flows before position ``pos``."""
+    return jnp.where(pos > 0, csum[jnp.maximum(pos - 1, 0)], jnp.int32(0))
+
+
+def _segment_greedy(queued, cap_cells, seg_start):
+    """segment_greedy's (served, int32 prefix sum)."""
     q32 = queued.astype(jnp.int32)
     csum = jnp.cumsum(q32)
-    seg_base = jnp.where(seg_start > 0, csum[jnp.maximum(seg_start - 1, 0)],
-                         jnp.int32(0))
-    before = (csum - q32 - seg_base).astype(jnp.int64)
-    return jnp.clip(cap_cells - before, 0, queued)
+    before = (csum - q32 - _prefix_before(csum, seg_start)).astype(jnp.int64)
+    return jnp.clip(cap_cells - before, 0, queued), csum
+
+
+def segment_greedy_totals(queued, node_cap, flow_node, seg_start, node_seg):
+    """segment_greedy with ``cap_cells = node_cap[flow_node]``, plus the
+    cells each node served, without a scatter.
+
+    The greedy serves a node's segment in order until its capacity runs
+    out, so for ``node_cap >= 0`` and ``queued >= 0`` the node's total is
+    ``min(node_cap, segment's queued total)``, and that total is the
+    difference of the same int32 prefix sum at the segment's two ends
+    (``node_seg``, see gather_tables), exact under the same bound as the
+    per-flow greedy.  A node with no flows has an empty segment and
+    totals 0.  Returns (served [F], node_cells [H])."""
+    served, csum = _segment_greedy(queued, node_cap[flow_node], seg_start)
+    seg_total = (_prefix_before(csum, node_seg[1])
+                 - _prefix_before(csum, node_seg[0])).astype(jnp.int64)
+    return served, jnp.minimum(jnp.maximum(node_cap, 0), seg_total)
 
 
 @partial(jax.jit, static_argnames=("ring_len",))
@@ -456,7 +515,7 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
                     done_tick, node_sent, inject, inject_target,
                     targets, idle_ticks, flow_node, flow_lat,
                     flow_succ, seg_start, refill, capacity,
-                    ring_len: int):
+                    flow_pred, node_seg, ring_len: int):
     """The SUPERWINDOW step: advance the cell model from ``t0`` through the
     ascending absolute step boundaries in ``targets`` (padded by repeating
     the final boundary, so the array shape stays static), HALTING at the
@@ -472,23 +531,28 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
     exactly to that round — so the kernel refuses to run past it.  The
     reached boundary comes back in the flush header (t_stop), one transfer.
 
-    Per-tick math is byte-for-byte the _step_window_impl body (pinned by
-    tests/test_superwindow.py's span-vs-sequential-windows parity case).
+    Per-tick math is the _step_window_impl body's (pinned by
+    tests/test_superwindow.py's span-vs-sequential-windows parity case),
+    formed without a scatter: ``flow_pred`` and ``node_seg``
+    (gather_tables) turn the successor send and the per-node byte total
+    into gathers.  A scatter-add's updates run one after another on TPU
+    (~114 ms a tick for 890k flows on v5e); a gather of the same length
+    takes a few ms.
     Returns the same 9-tuple, with [0] = the boundary actually reached,
     plus [9] = the (flow, tick) pairs in which a flow served a cell."""
     f = queued.shape[0]
-    h = refill.shape[0]
     p = targets.shape[0]
     size = jnp.int64(CELL_WIRE_BYTES)
     is_last = flow_succ < 0
+    has_pred = flow_pred >= 0
+    pred = jnp.maximum(flow_pred, 0)
     queued = queued + inject
     target = target + inject_target
     tokens = jnp.minimum(capacity, tokens + refill * idle_ticks)
     ring = jax.lax.cond(idle_ticks > 0,
                         lambda hh: jnp.zeros_like(hh),
                         lambda hh: hh, ring)
-    arr_lat = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-        jnp.where(is_last, jnp.int64(0), flow_lat))
+    arr_lat = jnp.where(has_pred, flow_lat[pred], jnp.int64(0))
     cols = jnp.arange(f)
     end = targets[p - 1]
 
@@ -498,20 +562,21 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
         arr = hist[jnp.mod(t - arr_lat, ring_len), cols]
         queued = queued + arr
         tokens = jnp.minimum(capacity, tokens + refill)
-        cap_cells = tokens[flow_node] // size
-        served = segment_greedy(queued, cap_cells, seg_start)
+        served, cells = segment_greedy_totals(queued, tokens // size,
+                                              flow_node, seg_start, node_seg)
         queued = queued - served
-        spent = jax.ops.segment_sum(served * size, flow_node,
-                                    num_segments=h)
+        spent = cells * size
         tokens = tokens - spent
         node_sent = node_sent + spent
         delivered = delivered + jnp.where(is_last, served, 0)
         newly_done = (is_last & (target > 0) & (done_tick < 0)
                       & (delivered >= target))
         done_tick = jnp.where(newly_done, t, done_tick)
-        v = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-            jnp.where(is_last, jnp.int64(0), served))
-        hist = hist.at[jnp.mod(t, ring_len)].set(v.astype(hist.dtype))
+        # cast before the gather: one gather in the ring dtype
+        fwd = jnp.where(is_last, 0, served).astype(hist.dtype)
+        v = jnp.where(has_pred, fwd[pred], jnp.zeros((), hist.dtype))
+        hist = jax.lax.dynamic_update_slice(
+            hist, v[None], (jnp.mod(t, ring_len), jnp.int64(0)))
         forwards = forwards + jnp.sum(served)
         moved = moved + jnp.sum((served > 0).astype(jnp.int64))
         # sub-window bookkeeping: at a boundary, halt iff this span saw a
@@ -538,13 +603,14 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
                           done_tick, node_sent, inject, inject_target,
                           targets, idle_ticks, flow_node, flow_lat,
                           flow_succ, seg_start, refill, capacity,
-                          last_flow, ring_len: int,
+                          last_flow, flow_pred, node_seg, ring_len: int,
                           cap_chains: Optional[int] = None,
                           cap_nodes: Optional[int] = None):
     """Superwindow step + packed flush in ONE dispatch: the 9-tuple of
     _step_span_impl with the packed flush buffer appended as [9] (its
     moved count rides in the flush header).
-    ``last_flow`` [C] maps each chain to its exit flow row.  With caps
+    ``last_flow`` [C] maps each chain to its exit flow row;
+    ``flow_pred`` and ``node_seg`` come from gather_tables.  With caps
     the flush is the capped (delta-compacted) buffer — see
     _pack_flush_jnp."""
     done_in_last = done_tick[last_flow]
@@ -553,7 +619,7 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
                           done_tick, node_sent, inject, inject_target,
                           targets, idle_ticks, flow_node, flow_lat,
                           flow_succ, seg_start, refill, capacity,
-                          ring_len)
+                          flow_pred, node_seg, ring_len)
     done_last = out[6][last_flow]
     newly = (done_last >= 0) & (done_in_last < 0)
     flush = _pack_flush_jnp(out[8], jnp.sum(out[4][last_flow]), out[0],
@@ -614,7 +680,7 @@ def torcells_step_span_flush_batched(t0, queued, ring, tokens, delivered,
                                      inject_target, targets, idle_ticks,
                                      flow_node, flow_lat, flow_succ,
                                      seg_start, refill, capacity, last_flow,
-                                     ring_len: int):
+                                     flow_pred, node_seg, ring_len: int):
     """[W]-leading-axis twin of torcells_step_window_flush: 10-tuple with
     every element batched ([W] t_stop/forwards scalars, [W, F] columns,
     [W, L, F] rings, [W, flush_len] flush buffers)."""
@@ -622,7 +688,8 @@ def torcells_step_span_flush_batched(t0, queued, ring, tokens, delivered,
     return jax.vmap(fn)(t0, queued, ring, tokens, delivered, target,
                         done_tick, node_sent, inject, inject_target,
                         targets, idle_ticks, flow_node, flow_lat,
-                        flow_succ, seg_start, refill, capacity, last_flow)
+                        flow_succ, seg_start, refill, capacity, last_flow,
+                        flow_pred, node_seg)
 
 
 def torcells_step_span_batched_numpy(t0, queued, ring, tokens, delivered,

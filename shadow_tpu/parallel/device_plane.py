@@ -62,6 +62,7 @@ executed here as dense tensor ticks).
 from __future__ import annotations
 
 import math
+import threading
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -72,6 +73,10 @@ from ..core.task import Task
 from ..core.logger import get_logger
 
 TICK_NS = 1_000_000          # 1 ms, = the interface refill interval
+
+# The numpy twin's share of _flow_args(): every table but the device
+# kernel's two gather tables (ops/torcells_device.gather_tables).
+_TWIN_TABLES = 7
 
 
 class _PoisonedFlush:
@@ -90,6 +95,50 @@ class _PoisonedFlush:
             # simlint: disable=SIM005 -- fault harness: a deliberate stall
             _wt.sleep(30.0)
         raise RuntimeError("fault injection: poisoned device dispatch")
+
+
+class _CollectThread:
+    """The process's flush-collect thread: runs each blocking flush read so
+    that ``--device-watchdog-sec`` can bound it.  One thread serves every
+    plane's collects instead of a new one per collect: on v5e each new
+    thread's flush readback (~6 MB at 890k flows) landed in a fresh malloc
+    arena, and host RSS grew by ~6 MB a dispatch.  A read that outlives
+    the watchdog abandons the thread (``abandon``); it exits once the
+    stuck read returns, and the next collect starts a fresh one."""
+
+    _lock = threading.Lock()
+    _live: Optional["_CollectThread"] = None
+
+    def __init__(self):
+        import queue
+        self._jobs = queue.SimpleQueue()
+        self.thread = threading.Thread(target=self._serve, daemon=True,
+                                       name="device-dispatch-collect")
+        self.thread.start()
+
+    @classmethod
+    def get(cls) -> "_CollectThread":
+        with cls._lock:
+            if cls._live is None:
+                cls._live = cls()
+            return cls._live
+
+    def submit(self, job) -> None:
+        self._jobs.put(job)
+
+    def abandon(self) -> None:
+        with self._lock:
+            if _CollectThread._live is self:
+                _CollectThread._live = None
+        self._jobs.put(None)
+
+    def _serve(self) -> None:
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            job()
+            del job
 
 
 class _SuperPlan:
@@ -599,6 +648,9 @@ class DeviceTrafficPlane:
         self.flow_lat = lat.astype(np.int64)
         self.flow_succ = succ
         self.seg_start = starts[seg_id]
+        from ..ops.torcells_device import gather_tables
+        self.flow_pred, self.node_seg = gather_tables(flow_node, succ,
+                                                      len(names))
         self.flow_circ = flow_chain[order]
         self.flow_stage = flow_stage[order]
         # per-chain entry (stage 0) and exit (last stage) flow positions
@@ -829,11 +881,12 @@ class DeviceTrafficPlane:
         """The static flow tables, resident where the kernel runs: committed
         device buffers in device mode (uploaded ONCE — re-sending ~2 MB of
         int64 tables per dispatch at 10k circuits would waste host link
-        bandwidth every round), plain numpy for the twin."""
+        bandwidth every round), plain numpy for the twin, which takes the
+        first ``_TWIN_TABLES``."""
         if self._flow_args_cached is None:
             args = (self.flow_node, self.flow_lat_steps, self.flow_succ,
                     self.seg_start, self.refill_step, self.capacity_step,
-                    self.last_flow)
+                    self.last_flow, self.flow_pred, self.node_seg)
             if self.mode == "device":
                 import jax.numpy as jnp
                 args = tuple(jnp.asarray(a) for a in args)
@@ -971,7 +1024,8 @@ class DeviceTrafficPlane:
             *state, z, z, self._pad_targets([1]), np.int64(0),
             self.flow_node, self.flow_lat_steps, self.flow_succ,
             self.seg_start, self.refill_step, self.capacity_step,
-            self.last_flow, ring_len=self.ring_len)
+            self.last_flow, self.flow_pred, self.node_seg,
+            ring_len=self.ring_len)
         jax.block_until_ready(out)
         if self._flush_caps is not None:
             # the tuned dispatch runs the CAPPED flush kernel — compile
@@ -982,7 +1036,8 @@ class DeviceTrafficPlane:
                 *state, z, z, self._pad_targets([1]), np.int64(0),
                 self.flow_node, self.flow_lat_steps, self.flow_succ,
                 self.seg_start, self.refill_step, self.capacity_step,
-                self.last_flow, ring_len=self.ring_len,
+                self.last_flow, self.flow_pred, self.node_seg,
+                ring_len=self.ring_len,
                 cap_chains=cc, cap_nodes=hh)
             jax.block_until_ready(out)
 
@@ -1229,7 +1284,8 @@ class DeviceTrafficPlane:
             from ..ops.torcells_device import torcells_step_window_numpy_flush
             out = torcells_step_window_numpy_flush(*state, inject,
                                                    inject_target, tvec, idle,
-                                                   *self._flow_args(),
+                                                   *self._flow_args()[
+                                                       :_TWIN_TABLES],
                                                    self.ring_len)
         self._state = out[:8]
         self._flush_handle = out[9]
@@ -1521,24 +1577,24 @@ class DeviceTrafficPlane:
     def _collect_flush(self, engine, handle) -> Tuple[np.ndarray, int]:
         """Materialize the in-flight dispatch's flush buffer (see
         _read_flush), bounded by ``--device-watchdog-sec`` in device mode:
-        the blocking read runs on
-        a helper thread so a dispatch that never completes (wedged runtime,
-        lost device) raises TimeoutError here instead of freezing
-        the round loop forever.  Only the guard's bookkeeping (thread spawn
-        + join return) is charged to supervision overhead — the wait for
+        the blocking read runs on the collect thread (_CollectThread) so
+        a dispatch that never completes (wedged runtime, lost device)
+        raises TimeoutError here instead of freezing the round loop
+        forever.  Only the guard's bookkeeping (the job's hand-off and the
+        wait's return) is charged to supervision overhead — the wait for
         the result is the dispatch's own cost, watchdog or not."""
         if self.mode != "device" or self._watchdog_sec <= 0:
             return self._read_flush(handle)
-        import threading
         import time as _wt
         t_g = _wt.perf_counter_ns()
-        # the result box is written by the helper thread and read by the
+        # the result box is written by the collect thread and read by the
         # dispatcher: one lock covers both sides (simrace SIM102 — a
-        # timed-out join() returning does NOT order the abandoned
-        # helper's late write against the dispatcher's read, so the
+        # timed-out wait returning does NOT order the abandoned
+        # thread's late write against the dispatcher's read, so the
         # dict-sharing idiom was a real, if narrow, race window)
         box: Dict[str, object] = {}
         box_lock = threading.Lock()
+        done = threading.Event()
 
         def _work() -> None:
             try:
@@ -1549,15 +1605,15 @@ class DeviceTrafficPlane:
             else:
                 with box_lock:
                     box["out"] = out
+            done.set()
 
-        th = threading.Thread(target=_work, daemon=True,
-                              name="device-dispatch-collect")
-        th.start()
+        collector = _CollectThread.get()
+        collector.submit(_work)
         engine.supervision.overhead_ns += _wt.perf_counter_ns() - t_g
-        th.join(self._watchdog_sec)
-        if th.is_alive():
-            # the helper thread is abandoned with the handle (it cannot be
-            # interrupted mid-XLA-call); the numpy replay takes over
+        if not done.wait(self._watchdog_sec):
+            # the collect thread is abandoned with the handle (it cannot
+            # be interrupted mid-XLA-call); the numpy replay takes over
+            collector.abandon()
             raise TimeoutError(
                 f"device dispatch did not complete within "
                 f"{self._watchdog_sec:.0f}s (--device-watchdog-sec)")
@@ -1695,7 +1751,8 @@ class DeviceTrafficPlane:
                      np.zeros(f, dtype=np.int64), np.zeros(f, dtype=np.int64),
                      np.full(f, -1, dtype=np.int64),
                      np.zeros(h, dtype=np.int64))
-        args = self._flow_args()        # plain numpy now that mode flipped
+        # plain numpy now that mode flipped
+        args = self._flow_args()[:_TWIN_TABLES]
         flush = None
         for base, pairs, targets, idle in self._dispatch_log:
             inject = np.zeros(f, dtype=np.int64)

@@ -164,7 +164,8 @@ def measure_step_kernel(flow_points, steps: int,
         args = (jnp.asarray(fl["flow_node"]), jnp.asarray(fl["flow_lat"]),
                 jnp.asarray(fl["flow_succ"]), jnp.asarray(fl["seg_start"]),
                 jnp.asarray(inst.refill), jnp.asarray(inst.capacity),
-                jnp.asarray(last_flow))
+                jnp.asarray(last_flow), jnp.asarray(fl["flow_pred"]),
+                jnp.asarray(fl["node_seg"]))
         targets = np.array([pt_steps], dtype=np.int64)
         out = torcells_step_window_flush_nodonate(
             *state, queued0, target0, targets, np.int64(0), *args,
@@ -216,7 +217,8 @@ def measure_batched_step_kernel(widths=(1, 2, 4, 8), n_circ: int = 1000,
     tables = (np.asarray(fl["flow_node"]), np.asarray(fl["flow_lat"]),
               np.asarray(fl["flow_succ"]), np.asarray(fl["seg_start"]),
               np.asarray(inst.refill), np.asarray(inst.capacity),
-              np.asarray(last_flow))
+              np.asarray(last_flow), np.asarray(fl["flow_pred"]),
+              np.asarray(fl["node_seg"]))
     points: List[Dict] = []
     truncated = False
     base_us = None

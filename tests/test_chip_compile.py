@@ -51,7 +51,8 @@ def one_chip(topo):
 
 def _flush_shapes(one_chip, n_chains, ring_len=66, rounds=8):
     """The span-flush argument shapes of a plane with ``n_chains`` 5-hop
-    chains (tor circuits) and two nodes per chain plus 500 servers."""
+    chains (tor circuits) and two nodes per chain plus 500 servers,
+    through the gather tables (flow_pred, node_seg)."""
     f, h = 5 * n_chains, 2 * n_chains + 500
 
     def s(shape, dtype=jnp.int64):
@@ -61,7 +62,7 @@ def _flush_shapes(one_chip, n_chains, ring_len=66, rounds=8):
             s((f,)), s((f,)), s((f,)), s((h,)),          # carried state
             s((f,)), s((f,)), s((rounds,)), s(()),       # inject, targets
             s((f,)), s((f,)), s((f,)), s((f,)), s((h,)), s((h,)),
-            s((n_chains,)))
+            s((n_chains,)), s((f,)), s((2, h)))
 
 
 @pytest.mark.parametrize("n_chains", [200, 2_000, 5_000, 10_000])

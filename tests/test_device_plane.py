@@ -389,3 +389,42 @@ def test_plane_refuses_injections_past_the_int32_bound(monkeypatch):
     for mode in ("device", "numpy"):
         with pytest.raises(ValueError, match="cells in flight"):
             _run(mode=mode)
+
+
+def test_flow_pred_inverts_flow_succ():
+    """The plane's gather tables: flow_pred is the inverse of flow_succ,
+    and node_seg bounds exactly each node's flows."""
+    plane = _run(mode="numpy", stop=5).engine.device_plane
+    succ, pred = plane.flow_succ, plane.flow_pred
+    has = np.flatnonzero(succ >= 0)
+    assert np.array_equal(pred[succ[has]], has)
+    assert np.count_nonzero(pred >= 0) == len(has)
+    lo, hi = plane.node_seg
+    assert np.array_equal(hi - lo, np.bincount(plane.flow_node,
+                                               minlength=plane.n_nodes))
+    for node in np.flatnonzero(hi > lo):
+        assert (plane.flow_node[lo[node]:hi[node]] == node).all()
+
+
+def test_collects_share_one_thread(monkeypatch):
+    """Every watchdog-bounded flush read runs on one collect thread, across
+    dispatches and planes (a thread per collect grew host RSS by a malloc
+    arena's worth each dispatch on v5e)."""
+    import threading
+
+    from shadow_tpu.parallel.device_plane import DeviceTrafficPlane
+    seen = []
+    real = DeviceTrafficPlane._read_flush
+
+    def read(self, handle):
+        seen.append(threading.current_thread())
+        return real(self, handle)
+
+    monkeypatch.setattr(DeviceTrafficPlane, "_read_flush", read)
+    planes = [_run(mode="device", stop=20).engine.device_plane
+              for _ in range(2)]
+    assert all(p._watchdog_sec > 0 for p in planes)
+    assert len(seen) == sum(p.dispatches for p in planes) >= 4
+    assert len(set(seen)) == 1
+    assert seen[0].name == "device-dispatch-collect"
+    assert seen[0].is_alive()

@@ -117,7 +117,8 @@ def test_vmapped_kernel_matches_unbatched():
     f, h = inst.n_flows, len(inst.refill)
     last_flow = np.flatnonzero(fl["flow_succ"] < 0)
     tables = (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
-              fl["seg_start"], inst.refill, inst.capacity, last_flow)
+              fl["seg_start"], inst.refill, inst.capacity, last_flow,
+              fl["flow_pred"], fl["node_seg"])
     lanes = []
     for k in (1, 3):          # different injections AND different spans
         inject = (fl["flow_stage"] == 0).astype("int64") * 40 * k
@@ -131,7 +132,7 @@ def test_vmapped_kernel_matches_unbatched():
     singles = [torcells_step_window_flush_nodonate(
         *lane, ring_len=inst.ring_len) for lane in lanes]
     batch = tuple(np.stack([np.asarray(lane[i]) for lane in lanes])
-                  for i in range(19))
+                  for i in range(21))
     batched = torcells_step_span_flush_batched(*batch,
                                                ring_len=inst.ring_len)
     for i in range(10):

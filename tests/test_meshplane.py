@@ -195,7 +195,8 @@ def test_mesh_kernel_bit_parity(n_dev):
     args = (jnp.asarray(fl["flow_node"]), jnp.asarray(fl["flow_lat"]),
             jnp.asarray(fl["flow_succ"]), jnp.asarray(fl["seg_start"]),
             jnp.asarray(inst.refill), jnp.asarray(inst.capacity),
-            jnp.asarray(last_flow))
+            jnp.asarray(last_flow), jnp.asarray(fl["flow_pred"]),
+            jnp.asarray(fl["node_seg"]))
     ref = torcells_step_window_flush_nodonate(
         *sstate, queued0, target0, targets1, np.int64(0), *args,
         ring_len=inst.ring_len)

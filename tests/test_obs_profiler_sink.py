@@ -233,7 +233,8 @@ def test_span_flush_module_names(wrapper, kw):
             np.zeros(f, np.int64), np.zeros(f, np.int64),
             np.array([2, 2]), np.int64(0), np.array([0, 1, 1]),
             np.array([1, 1, 0]), np.array([1, 2, -1]), np.array([0, 1, 1]),
-            np.array([5, 5]), np.array([9, 9]), np.array([2]))
+            np.array([5, 5]), np.array([9, 9]), np.array([2]),
+            np.array([-1, 0, 1]), np.array([[0, 1], [1, 3]]))
     text = getattr(td, wrapper).lower(*args, ring_len=4, **kw).as_text()
     module = next(ln for ln in text.splitlines() if ln.startswith("module"))
     assert "_step_span_flush_impl" in module, module

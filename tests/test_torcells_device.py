@@ -1,6 +1,7 @@
 """Device-resident onion-relay cell model (ops/torcells_device.py)."""
 
 import numpy as np
+import pytest
 
 from shadow_tpu.ops.torcells_device import (CELL_WIRE_BYTES, DeviceTorCells,
                                             bucket_params)
@@ -74,6 +75,13 @@ def _chain(cells, refill_cells, cap_cells):
     return state, inject, inject_target, tables
 
 
+def _gather(tables):
+    """The kernel's gather tables for a twin-ordered table tuple (flow_node,
+    flow_lat, flow_succ, seg_start, refill, capacity, last_flow)."""
+    from shadow_tpu.ops.torcells_device import gather_tables
+    return gather_tables(tables[0], tables[2], len(tables[4]))
+
+
 def _moved_three_ways(cells, refill_cells, cap_cells, ticks=20):
     """flush_moved from the jitted span-flush, its numpy twin and the
     vmapped fleet program, on the same one-chain table."""
@@ -84,15 +92,17 @@ def _moved_three_ways(cells, refill_cells, cap_cells, ticks=20):
         torcells_step_window_flush_nodonate,
         torcells_step_window_numpy_flush)
     state, inj, inj_t, tables = _chain(cells, refill_cells, cap_cells)
+    gather = _gather(tables)
     targets = np.array([ticks, ticks], np.int64)
     dev = torcells_step_window_flush_nodonate(
-        *state, inj, inj_t, targets, np.int64(0), *tables, ring_len=4)
+        *state, inj, inj_t, targets, np.int64(0), *tables, *gather,
+        ring_len=4)
     twin = torcells_step_window_numpy_flush(
         *(np.array(a).copy() for a in state), inj, inj_t, targets,
         np.int64(0), *tables, 4)
     batched = torcells_step_span_flush_batched(
         *(jnp.asarray(np.asarray(a))[None] for a in
-          (*state, inj, inj_t, targets, np.int64(0), *tables)),
+          (*state, inj, inj_t, targets, np.int64(0), *tables, *gather)),
         ring_len=4)
     out = [flush_moved(np.asarray(dev[9])), flush_moved(twin[9]),
            flush_moved(np.asarray(batched[9])[0])]
@@ -112,3 +122,211 @@ def test_flow_ticks_moved_contended_hand_count():
     them two ticks later and serves 6, 3, 3, 3, 3, 2 on ticks 2-7.  So
     4 + 6 = 10 moved (flow, tick) pairs."""
     assert _moved_three_ways(20, [4, 3], [8, 6]) == [10, 10, 10]
+
+
+# -- the scatter-free span-flush tick against the unchanged numpy twin -------
+
+def _tor_table(route, n_nodes, refill_cells, cap_cells, lat=2):
+    """build_flows tables for 5-hop ``route`` rows over ``n_nodes`` nodes
+    (a node no route names paces no flow), every onward hop ``lat`` ticks,
+    buckets given in whole cells per node."""
+    from shadow_tpu.ops.torcells_device import build_flows
+    route = np.asarray(route, np.int64)
+    fl = build_flows(route, np.full((n_nodes, n_nodes), lat, np.int64))
+    last_flow = np.array([np.flatnonzero((fl["flow_circ"] == c)
+                                         & (fl["flow_stage"] == 4))[0]
+                          for c in range(len(route))], np.int64)
+    c = CELL_WIRE_BYTES
+    tables = (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
+              fl["seg_start"], np.asarray(refill_cells, np.int64) * c,
+              np.asarray(cap_cells, np.int64) * c, last_flow)
+    return fl, tables
+
+
+def _zero_state(tables, ring_len=4):
+    from shadow_tpu.ops.torcells_device import RING_DTYPE
+    f, h = len(tables[0]), len(tables[4])
+    return [np.int64(0), np.zeros(f, np.int64),
+            np.zeros((ring_len, f), RING_DTYPE), tables[5].copy(),
+            np.zeros(f, np.int64), np.zeros(f, np.int64),
+            np.full(f, -1, np.int64), np.zeros(h, np.int64)]
+
+
+def _span_parity(tables, state, inject, inject_target, targets, idle=0,
+                 ring_len=4):
+    """The jitted span-flush, through its gather tables, against the numpy
+    twin from the same state: all ten outputs equal bit for bit.  Returns
+    the twin's outputs."""
+    from shadow_tpu.ops.torcells_device import (
+        torcells_step_window_flush_nodonate, torcells_step_window_numpy_flush)
+    args = (np.asarray(inject, np.int64), np.asarray(inject_target, np.int64),
+            np.asarray(targets, np.int64), np.int64(idle))
+    dev = torcells_step_window_flush_nodonate(
+        *state, *args, *tables, *_gather(tables), ring_len=ring_len)
+    twin = torcells_step_window_numpy_flush(
+        *(np.array(a).copy() for a in state), *args, *tables, ring_len)
+    for i in range(10):
+        np.testing.assert_array_equal(np.asarray(dev[i]),
+                                      np.asarray(twin[i]),
+                                      err_msg=f"output {i}")
+    return twin
+
+
+# six circuits over odd nodes 1..25: nodes 0, the evens and 26 pace no flow;
+# node 1 paces every circuit's stage 0 and node 25 every stage 4
+_SHARED_ROUTE = [[1, 3 + 2 * (k % 2), 7 + 2 * (k % 3), 13 + 2 * k, 25]
+                 for k in range(6)]
+_EIGHT_SPANS = np.arange(1, 9, dtype=np.int64) * 3
+
+
+def test_span_parity_empty_nodes_and_a_partial_serve():
+    """Node 1 holds 6 x 5 queued cells in one segment and a 7-cell bucket
+    refilled by 3: its tokens run out inside the segment (5 cells to the
+    first flow, 2 to the second, none after) on the first tick and every
+    tick after, so the node total is the bucket, not the backlog.  Half
+    the nodes pace no flow at all."""
+    n = 27
+    refill = np.full(n, 50)
+    cap = np.full(n, 100)
+    refill[1], cap[1] = 3, 7
+    fl, tables = _tor_table(_SHARED_ROUTE, n, refill, cap)
+    assert (fl["node_seg"][0] == fl["node_seg"][1]).sum() == 14   # empty
+    inject = np.where(fl["flow_stage"] == 0, 5, 0)
+    target = np.where(fl["flow_succ"] < 0, 10 ** 9, 0)
+    one = _span_parity(tables, _zero_state(tables), inject, target, [1])
+    seg = slice(*fl["node_seg"][:, 1])
+    assert list(one[1][seg]) == [0, 3, 5, 5, 5, 5]
+    assert int(one[7][1]) == 7 * CELL_WIRE_BYTES
+    out = _span_parity(tables, _zero_state(tables), inject, target,
+                       _EIGHT_SPANS)
+    assert int(out[0]) == _EIGHT_SPANS[-1]
+    assert int(out[7][1]) == 30 * CELL_WIRE_BYTES
+
+
+def test_span_parity_prefix_sum_wraps_int32():
+    """Backlogs near 2**29 cells on each of six disjoint circuits: the
+    int32 prefix sum over all flows wraps past 2**31 (twice over after the
+    first hop) while each node's segment total fits, and buckets of 2**28
+    cells serve part of each."""
+    n = 32
+    route = np.arange(30).reshape(6, 5)
+    fl, tables = _tor_table(route, n, np.full(n, 2 ** 27),
+                            np.full(n, 2 ** 28))
+    inject = np.where(fl["flow_stage"] == 0,
+                      2 ** 29 + fl["flow_circ"] * 977, 0)
+    assert inject.sum() > 2 ** 31
+    target = np.where(fl["flow_succ"] < 0, 2 ** 40, 0)
+    out = _span_parity(tables, _zero_state(tables), inject, target,
+                       np.arange(1, 9, dtype=np.int64) * 2)
+    assert int(out[8]) > 2 ** 31                     # cells forwarded
+
+
+def test_span_parity_idle_ticks_clear_a_stale_ring():
+    """idle_ticks > 0 folds refill into half-empty buckets and clears a
+    ring full of stale sends before the first tick."""
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 4), np.full(n, 9))
+    state = _zero_state(tables)
+    rng = np.random.default_rng(4)
+    state[2] = rng.integers(1, 50, size=state[2].shape).astype(
+        state[2].dtype)
+    state[3] = tables[5] // 3
+    inject = np.where(fl["flow_stage"] == 0, 6, 0)
+    target = np.where(fl["flow_succ"] < 0, 10 ** 9, 0)
+    out = _span_parity(tables, state, inject, target, _EIGHT_SPANS, idle=5)
+    assert int(out[8]) > 0
+
+
+def test_span_parity_eight_spans_halt_at_a_completion():
+    """A K=8 span whose chains complete mid-plan halts at the boundary
+    after the first completion, identically in both."""
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 40),
+                            np.full(n, 80))
+    inject = np.where(fl["flow_stage"] == 0, 4, 0)
+    target = np.where(fl["flow_succ"] < 0, 4, 0)
+    out = _span_parity(tables, _zero_state(tables), inject, target,
+                       _EIGHT_SPANS)
+    assert int(out[0]) in _EIGHT_SPANS[:-1]
+    assert (np.asarray(out[6]) >= 0).any()
+
+
+def test_fleet_kernel_padded_lanes_match_twin():
+    """The vmapped fleet program through a lane's padding: padded flows
+    (pred -1, their own empty segments), padded nodes (no segment) and
+    three filler lanes, against the twin on the real shapes."""
+    from types import SimpleNamespace
+
+    from shadow_tpu.fleet.plane import FleetPlane
+    from shadow_tpu.ops.torcells_device import torcells_step_window_numpy_flush
+    n = 27
+    refill = np.full(n, 6)
+    refill[1] = 2
+    fl, tables = _tor_table(_SHARED_ROUTE, n, refill, np.full(n, 12))
+    plane = SimpleNamespace(
+        n_flows=len(tables[0]), n_nodes=n, n_chains=6, superwindow_rounds=8,
+        ring_len=4, flow_node=tables[0], flow_lat_steps=tables[1],
+        flow_succ=tables[2], seg_start=tables[3], refill_step=tables[4],
+        capacity_step=tables[5], last_flow=tables[6],
+        flow_pred=fl["flow_pred"], node_seg=fl["node_seg"])
+    lane = FleetPlane().lane("padded")
+    lane.attach_plane(plane)
+    assert lane.cls.f2 > plane.n_flows and lane.cls.h2 > n
+    assert (lane._tables[7][plane.n_flows:] == -1).all()
+    lane.cls.width = 4              # a class that once ran four lanes
+    state = _zero_state(tables)
+    inject = np.where(fl["flow_stage"] == 0, 7, 0)
+    target = np.where(fl["flow_succ"] < 0, 7, 0)
+    got = lane.dispatch(state, inject, target, _EIGHT_SPANS, 0)
+    want = torcells_step_window_numpy_flush(
+        *(np.array(a).copy() for a in state), inject, target, _EIGHT_SPANS,
+        np.int64(0), *tables, 4)
+    for i in range(10):
+        np.testing.assert_array_equal(np.asarray(got[i]),
+                                      np.asarray(want[i]),
+                                      err_msg=f"output {i}")
+    assert int(want[8]) > 0
+
+
+def _while_body(text: str) -> str:
+    """The ``do`` region of the one ``stablehlo.while`` in ``text``."""
+    assert text.count("stablehlo.while") == 1
+    start = text.index("} do {", text.index("stablehlo.while")) + 5
+    depth = 0
+    for j in range(start, len(text)):
+        depth += {"{": 1, "}": -1}.get(text[j], 0)
+        if depth == 0:
+            return text[start:j + 1]
+    raise AssertionError("unterminated while body")
+
+
+def test_span_flush_tick_loop_has_no_scatter():
+    """No scatter inside the tick loop: on TPU a scatter-add's updates run
+    one after another (the successor send and the per-node byte total
+    took 188 ms of a 225 ms tick at 890k flows on v5e).  The flush
+    pack's scatters run once per dispatch, after the loop."""
+    import jax
+
+    from shadow_tpu.ops.torcells_device import _step_span_flush_impl
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 4), np.full(n, 9))
+    state = _zero_state(tables)
+    z = np.zeros(len(tables[0]), np.int64)
+    text = jax.jit(_step_span_flush_impl, static_argnames=("ring_len",)) \
+        .lower(*state, z, z, _EIGHT_SPANS, np.int64(0), *tables,
+               *_gather(tables), ring_len=4).as_text()
+    body = _while_body(text)
+    assert "dynamic_update_slice" in body            # the ring's row write
+    assert "scatter" not in body
+    assert "scatter" in text.replace(body, "")       # the flush pack's
+
+
+@pytest.mark.parametrize("flow_node,flow_succ,why", [
+    ([0, 0, 1], [2, 2, -1], "not injective"),
+    ([0, 1, 0], [1, 2, -1], "contiguous"),
+])
+def test_gather_tables_refuse_a_layout_the_kernel_cannot_read(
+        flow_node, flow_succ, why):
+    from shadow_tpu.ops.torcells_device import gather_tables
+    with pytest.raises(ValueError, match=why):
+        gather_tables(np.array(flow_node), np.array(flow_succ), 2)
