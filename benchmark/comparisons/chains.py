@@ -1,8 +1,9 @@
-"""How ``correct`` is decided: the device traffic plane's state at the
+"""How ``correct`` is decided for process-less chain configurations
+(``"comparison": "chains"``): the device traffic plane's state at the
 tick it had reached when the window closed, against the plain reference
 (``lib/plane_ref.py``), which computes that state from the flows the
 benchmark's own generator offered and the configuration's stated
-bandwidths, hop latency, granule and cell size.
+bandwidths, hop latency, granule and cell size (``config["plane"]``).
 
 Every number compared is a count of differences, held to 0, except the
 plane's lag behind the closing boundary:
@@ -33,10 +34,10 @@ from typing import Dict, List
 
 import numpy as np
 
-from . import plane_ref
+from benchmark.lib import plane_ref
 
 
-def snapshot(engine) -> dict:
+def snapshot(engine, scenario: dict, config: dict) -> dict:
     """What the comparison reads from the timed run, once it is over."""
     plane = engine.device_plane
     scrape = engine.metrics.scrape()
@@ -68,10 +69,11 @@ def snapshot(engine) -> dict:
     return snap
 
 
-def compare(snap: dict, scenario: dict, boundary_ns: int,
-            granule_ms: int, cell_wire_bytes: int):
+def compare(snap: dict, scenario: dict, boundary_ns: int, config: dict):
     """(name -> {"value", "limit"}, circuits started by the closing
     boundary, circuits whose state differs)."""
+    granule_ms = int(config["plane"]["granule_ms"])
+    cell_wire_bytes = int(config["plane"]["cell_wire_bytes"])
     checks = {"hop_host_calls": snap["hop_host_calls"],
               "recoveries": snap["recoveries"]}
     p = snap["plane"]

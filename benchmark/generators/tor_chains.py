@@ -106,6 +106,7 @@ def build(sizes: dict, traffic: dict, seed: int) -> dict:
         """(up, down) KiB/s of a host, from its name's group."""
         return rates[host.rstrip("0123456789")]
     return {"kind": "config", "config": cfg, "flows": flows,
+            "offered": (len(flows), "circuits"),
             "bandwidth": bandwidth, "processes": False,
             "hop_latency_ms": 2 * float(sizes["edge_latency_ms"]),
             "stop_s": float(sizes["stoptime_s"]),

@@ -1,1 +1,2 @@
-"""The benchmark's harness: window, trace reduction, comparison."""
+"""The benchmark's harness: window, trace reduction, the chain plane's
+plain reference."""

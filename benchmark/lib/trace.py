@@ -12,14 +12,18 @@ dur_ns)``, so the reduction can be checked on a small recorded trace
   whose name contains a given program name (a jitted function's HLO
   module is ``jit_<function name>``).
 * idle gaps: the device's idle intervals inside the window, each named
-  by the host annotation (``bench.*``, opened by the harness around the
-  engine's calls) that overlaps it most, else ``host.round``.
+  by the program span (``engine.*``, ``plane.*``, ``native.*``,
+  ``procs.*``, ``setup.*``: the program's layer spans, on whichever host
+  thread ran them) that is innermost over most of the gap, else
+  ``host.round``.  At each instant the innermost span is the one, of
+  those open then, that opened last (so a span nested inside another
+  wins over it, on one thread or across two).
 """
 
 from __future__ import annotations
 
-import bisect
 import glob
+import heapq
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -28,10 +32,16 @@ Event = Tuple[str, str, str, float, float]   # plane, line, name, start, dur
 WINDOW_ANNOTATION = "bench.window"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+SPAN_PREFIXES = ("engine.", "plane.", "native.", "procs.", "setup.")
+NO_SPAN = "host.round"
 
 
 def is_device_plane(name: str) -> bool:
     return name.startswith("/device:TPU:") and "NON_CORE" not in name
+
+
+def is_program_span(name: str) -> bool:
+    return name.startswith(SPAN_PREFIXES)
 
 
 def read_xplane(log_dir: str) -> List[Event]:
@@ -48,7 +58,8 @@ def read_xplane(log_dir: str) -> List[Event]:
         for line in plane.lines:
             for ev in line.events:
                 name = ev.name
-                if keep_all or name.startswith("bench."):
+                if keep_all or name == WINDOW_ANNOTATION \
+                        or is_program_span(name):
                     out.append((plane.name, line.name, name,
                                 float(ev.start_ns), float(ev.duration_ns)))
     return out
@@ -97,26 +108,25 @@ def reduce(events: Sequence[Event], kernels: Dict[str, str]) -> Optional[dict]:
                 for key, prog in kernels.items():
                     if prog in name:
                         kernel_ns[key] = kernel_ns.get(key, 0.0) + (e - s)
-        elif name.startswith("bench.") and name != WINDOW_ANNOTATION \
-                and e > s:
+        elif is_program_span(name) and e > s:
             host.append((s, e, name))
     if not per_device:
         return None
     busy = {p: _union(iv) for p, iv in per_device.items()}
     busy_ns = sum(sum(e - s for s, e in iv) for iv in busy.values()) \
         / len(busy)
-    # idle gaps of the first device (one chip per cell today), named by
-    # the host annotation that overlaps each most
+    # idle gaps of the first device (one chip per cell today), each named
+    # by the span innermost over most of it
     first = busy[sorted(busy)[0]]
-    host.sort()
-    starts = [h[0] for h in host]
-    gaps: Dict[str, float] = {}
+    idle: List[Tuple[float, float]] = []
     cursor = w0
     for s, e in first + [[w1, w1]]:
         if s > cursor:
-            who = _who(host, starts, cursor, s)
-            gaps[who] = gaps.get(who, 0.0) + (s - cursor)
+            idle.append((cursor, s))
         cursor = max(cursor, e)
+    gaps: Dict[str, float] = {}
+    for (s, e), who in zip(idle, _name_gaps(idle, _innermost(host))):
+        gaps[who] = gaps.get(who, 0.0) + (e - s)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:10]
     return {
@@ -129,20 +139,48 @@ def reduce(events: Sequence[Event], kernels: Dict[str, str]) -> Optional[dict]:
     }
 
 
-def _who(host: Sequence[Tuple[float, float, str]], starts: List[float],
-         s: float, e: float) -> str:
-    """The host annotation that overlaps [s, e) most.  The harness's
-    annotations are sequential calls on one thread, so the scan walks back
-    from the last one that starts before ``e`` until one ends before
-    ``s``."""
-    best, best_ns = "host.round", 0.0
-    j = bisect.bisect_left(starts, e) - 1
-    while j >= 0:
-        hs, he, name = host[j]
-        ov = min(he, e) - max(hs, s)
-        if ov > best_ns:
-            best, best_ns = name, ov
-        if he <= s:
-            break
-        j -= 1
-    return best
+def _innermost(spans: Sequence[Tuple[float, float, str]]
+               ) -> List[Tuple[float, float, str]]:
+    """The time line cut where any span opens or closes, each piece under
+    the innermost span open over it: of those open, the one that opened
+    last, then the one that closes first.  Pieces under no span are left
+    out.  Sorted, disjoint ``(start, end, name)``."""
+    marks = sorted({t for s, e, _n in spans for t in (s, e)})
+    order = sorted(range(len(spans)), key=lambda i: spans[i][0])
+    heap: List[Tuple[float, float, int]] = []
+    out: List[Tuple[float, float, str]] = []
+    j = 0
+    for a, b in zip(marks, marks[1:]):
+        while j < len(order) and spans[order[j]][0] <= a:
+            i = order[j]
+            heapq.heappush(heap, (-spans[i][0], spans[i][1], i))
+            j += 1
+        while heap and heap[0][1] <= a:
+            heapq.heappop(heap)
+        if heap:
+            # a closed span deeper in the heap is dropped when it surfaces
+            name = spans[heap[0][2]][2]
+            if out and out[-1][1] == a and out[-1][2] == name:
+                out[-1] = (out[-1][0], b, name)
+            else:
+                out.append((a, b, name))
+    return out
+
+
+def _name_gaps(gaps: Sequence[Tuple[float, float]],
+               pieces: Sequence[Tuple[float, float, str]]) -> List[str]:
+    """For each of the sorted, disjoint ``gaps``, the name that holds most
+    of it among ``pieces`` (``_innermost``), or ``NO_SPAN``."""
+    names: List[str] = []
+    k = 0
+    for s, e in gaps:
+        while k < len(pieces) and pieces[k][1] <= s:
+            k += 1
+        held: Dict[str, float] = {}
+        m = k
+        while m < len(pieces) and pieces[m][0] < e:
+            ps, pe, name = pieces[m]
+            held[name] = held.get(name, 0.0) + min(pe, e) - max(ps, s)
+            m += 1
+        names.append(max(held, key=held.get) if held else NO_SPAN)
+    return names

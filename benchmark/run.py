@@ -9,8 +9,14 @@ configuration and a traffic mix.  Everything else is found by name:
 
 * ``benchmark/configs/<config>.json``: the deployment's sizes, the
   device plane's stated granule and cell size, the program options it
-  runs with, and the generator that renders it
-  (``benchmark/generators/<generator>.py``, the benchmark's own copy);
+  runs with, the generator that renders it
+  (``benchmark/generators/<generator>.py``, the benchmark's own copy;
+  its ``build`` returns the scenario, with ``"offered"``, a count and
+  its unit, and ``"last_arrival_s"``), and the comparison that decides
+  ``correct`` (``benchmark/comparisons/<comparison>.py``: ``snapshot(
+  engine, scenario, config)`` after the window, ``compare(snap,
+  scenario, boundary_ns, config)`` returning ``(checks, attempted,
+  failed)``, each check ``{"value", "limit"}``);
 * ``benchmark/traffic/<traffic>.json``: the traffic mix's parameters,
   read by that generator, and ``warm_sim_s``;
 * ``benchmark/metrics/<metric>.py``: one reader per metric, ``read(run)``
@@ -24,9 +30,9 @@ generated ``Configuration``; then ``Controller``) with
 ``--scheduler-policy=tpu --tpu-devices <chips> --device-plane device``,
 warm every kernel shape the scenario can use, run to ``warm_sim_s``,
 then measure the window (``lib/window.py``).  ``--trace 1`` records a
-``jax.profiler`` trace of the window.  After the window: the comparison
-with the plain reference (``lib/correct.py``, ``lib/plane_ref.py``),
-then the result, the last line of standard output.
+``jax.profiler`` trace of the window (``lib/trace.py``).  After the
+window: the configuration's comparison with its plain reference, then
+the result, the last line of standard output.
 """
 
 from __future__ import annotations
@@ -94,6 +100,13 @@ class Cell:
             os.path.join(HERE, "generators",
                          self.config["generator"] + ".py"),
             "bench_gen_" + self.config["generator"])
+        if "comparison" not in self.config:
+            raise RunFailed(f"configuration {self.spec['config']!r} names "
+                            "no \"comparison\": nothing decides correct")
+        self.comparison = load_module(
+            os.path.join(HERE, "comparisons",
+                         self.config["comparison"] + ".py"),
+            "bench_cmp_" + self.config["comparison"])
         self.end_to_end = [m for m in bench["end_to_end"]
                            if name in m.get("workloads", [name])]
         self.per_layer = [m for m in bench["per_layer"]
@@ -211,29 +224,11 @@ class Run:
         return float(self.after[key]) - float(self.before[key])
 
 
-def traced_calls(engine) -> None:
-    """Host annotations around the engine's calls into each layer, so the
-    trace can name what the host did in each idle gap of the device."""
-    import jax.profiler as prof
-
-    def wrap(attr: str, name: str) -> None:
-        inner = getattr(engine, attr)
-
-        def call(*a, **kw):
-            with prof.TraceAnnotation(name):
-                return inner(*a, **kw)
-        setattr(engine, attr, call)
-    wrap("_consume_flush", "bench.collect")
-    wrap("_launch_plane", "bench.launch")
-    wrap("_flush_round", "bench.flush")
-
-
 def measure(cell: Cell, args, scenario: dict, clock: CompileClock,
             tmpdir: str):
     """Set-up, warm-up and the window; returns the run, the state snapshot,
     the window facts and the closing boundary (sim ns)."""
     from benchmark.lib.window import Window
-    from benchmark.lib import correct
     flags = [*TIMED_FLAGS, "--tpu-devices", str(cell.chips)]
     ctrl = build_controller(cell, scenario, flags, tmpdir)
     engine = ctrl.engine
@@ -258,7 +253,6 @@ def measure(cell: Cell, args, scenario: dict, clock: CompileClock,
     def opened(_boundary: int) -> None:
         if args.trace:
             import jax.profiler as prof
-            traced_calls(engine)
             prof.start_trace(trace_dir)
             state["ann"] = prof.TraceAnnotation("bench.window")
             state["ann"].__enter__()
@@ -314,7 +308,7 @@ def measure(cell: Cell, args, scenario: dict, clock: CompileClock,
         red = tr.reduce(tr.read_xplane(trace_dir), kernels)
         run.trace = red or {}
         say(f"trace read in {time.perf_counter() - t0:.3f} s")
-    snap = correct.snapshot(engine)
+    snap = cell.comparison.snapshot(engine, scenario, cell.config)
     return run, snap, facts, win.sim1_ns
 
 
@@ -333,6 +327,9 @@ def main(argv=None) -> int:
 
 
 def _main(args) -> int:
+    # the benchmark's own modules (a comparison imports benchmark.lib)
+    # and the program under test, both from the checkout's root
+    sys.path.insert(0, ROOT)
     cell = Cell(args.workload)
     if not os.path.isfile(os.path.join(ROOT, "native", "Makefile")):
         raise RunFailed("no shadow-tpu checkout around the benchmark")
@@ -342,7 +339,6 @@ def _main(args) -> int:
     # own default, shadow_tpu/utils/compile_cache.py)
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
                           os.path.join(ROOT, ".jax_cache"))
-    sys.path.insert(0, ROOT)
     devices = find_devices(cell.chips)
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -351,9 +347,10 @@ def _main(args) -> int:
         f"after the runtime's start "
         f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024:.1f} MB")
     scenario = cell.scenario(args.seed)
+    count, unit = scenario["offered"]
     say(f"cell {cell.name}: seed {args.seed}, scenario "
-        f"{scenario_digest(scenario)}, {len(scenario['flows'])} circuits "
-        f"offered up to sim {scenario['last_arrival_s']:.3f} s")
+        f"{scenario_digest(scenario)}, {count} {unit} offered up to sim "
+        f"{scenario['last_arrival_s']:.3f} s")
     with tempfile.TemporaryDirectory(prefix="bench-") as tmpdir:
         run, timed, facts, end_ns = measure(cell, args, scenario, clock,
                                             tmpdir)
@@ -367,14 +364,11 @@ def _main(args) -> int:
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     gc.collect()
-    from benchmark.lib import correct
     t0 = time.perf_counter()
-    plane = cell.config["plane"]
-    checks, attempted, failed = correct.compare(
-        timed, scenario, end_ns, int(plane["granule_ms"]),
-        int(plane["cell_wire_bytes"]))
-    say(f"plain reference to the plane's tick and comparison: "
-        f"{time.perf_counter() - t0:.3f} s")
+    checks, attempted, failed = cell.comparison.compare(
+        timed, scenario, end_ns, cell.config)
+    say(f"plain reference and comparison "
+        f"({cell.config['comparison']}): {time.perf_counter() - t0:.3f} s")
     ok = all(c["value"] <= c["limit"] for c in checks.values())
     if facts["compiles_in_window"]:
         say(f"WARNING: {facts['compiles_in_window']} compiles inside the "

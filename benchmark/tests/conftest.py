@@ -4,6 +4,7 @@ harness's look for a chip skipped (``run.find_devices``)."""
 import importlib.util
 import json
 import os
+import shutil
 import sys
 
 import pytest
@@ -38,12 +39,44 @@ def bench(monkeypatch):
 
     def init(self, name):
         base(self, name)
-        self.config["sizes"].update(SMALL[self.spec["config"]])
+        self.config["sizes"].update(SMALL.get(self.spec["config"], {}))
         self.config["sizes"].update(mod.sizes)
         self.traffic.update(SMALL_TRAFFIC.get(self.spec["config"], {}))
         self.traffic.update(mod.traffic)
     monkeypatch.setattr(mod.Cell, "__init__", init)
     return mod
+
+
+CELLS = os.path.join(HERE, "cells")
+FOUND_BY_NAME = ("configs", "traffic", "generators", "comparisons", "metrics")
+
+
+@pytest.fixture
+def inject_cell(bench, monkeypatch, tmp_path):
+    """``inject_cell(cell)`` adds a test-only cell to what the harness
+    reads, never to ``BENCHMARK.json``: the harness's directories are
+    copied under ``tmp_path`` with ``benchmark/tests/cells`` laid over
+    them, and the cell joins the workloads it reads."""
+    for d in FOUND_BY_NAME:
+        shutil.copytree(os.path.join(BENCH, d), tmp_path / d)
+        extra = os.path.join(CELLS, d)
+        if os.path.isdir(extra):
+            shutil.copytree(extra, tmp_path / d, dirs_exist_ok=True)
+    monkeypatch.setattr(bench, "HERE", str(tmp_path))
+    added = []
+    real_load_json = bench.load_json
+
+    def load_json(path):
+        out = real_load_json(path)
+        if os.path.basename(path) == "BENCHMARK.json":
+            out["workloads"] = out["workloads"] + added
+        return out
+    monkeypatch.setattr(bench, "load_json", load_json)
+
+    def inject(cell: dict) -> str:
+        added.append(cell)
+        return cell["name"]
+    return inject
 
 
 def result_of(out: str):

@@ -1,4 +1,5 @@
-"""The comparison that decides ``correct`` fails when the timed path is
+"""The comparison that decides ``correct`` for the chains configuration
+(``benchmark/comparisons/chains.py``) fails when the timed path is
 wrong: the control (the plane stepped at twice the configured granule,
 the approximation a later PR could be tempted by) and faults planted in
 the timed path once the window has opened.  Each run goes through the
@@ -12,6 +13,9 @@ from benchmark.lib.window import Window
 from conftest import result_of
 
 CHAINS = "tor-chains-100k.waves"
+# numbers only comparisons/chains.py compares
+CHAINS_CHECKS = {"plane_lag_ticks", "flows_differing", "nodes_differing",
+                 "completions_differing"}
 
 
 def _args(cell, seed=4294967311, seconds=1.0):
@@ -94,6 +98,7 @@ def test_fault_makes_correct_false(bench, monkeypatch, capsys, fault):
     assert bench.main(_args(CHAINS)) == 0
     res = result_of(capsys.readouterr().out)
     assert res is not None and res["correct"] is False
+    assert CHAINS_CHECKS <= set(res["checks"])
     assert res["failed"] > 0
     assert any(c["value"] > c["limit"] for c in res["checks"].values())
 
@@ -113,6 +118,7 @@ def test_coarser_granule_control_is_not_correct(bench, monkeypatch, capsys):
     assert bench.main(_args(CHAINS, seconds=0.5)) == 0
     res = result_of(capsys.readouterr().out)
     assert res is not None and res["correct"] is False
+    assert CHAINS_CHECKS <= set(res["checks"])
 
 
 @pytest.mark.parametrize("seed", [4294967311, 2**31 + 5])
