@@ -168,6 +168,30 @@ def _segment_greedy(queued, cap_cells, seg_start):
     return jnp.clip(cap_cells - before, 0, queued), csum
 
 
+def _floor_div_small(x, m: int):
+    """``x // m`` for int64 ``x`` and a static ``0 < m < 2**16``, exact, in
+    32-bit divisions: base-2**16 long division of ``|x|``'s 32-bit halves.
+    XLA lowers an int64 division or remainder on TPU to a bitwise long
+    division, ~500 KB of HLO each, and the runtime keeps every loaded
+    program's HLO in host memory."""
+    neg = x < 0
+    ax = jnp.where(neg, -x - 1, x)        # x // m == -((-x - 1) // m) - 1
+    hi = (ax >> 32).astype(jnp.uint32)
+    lo = (ax & 0xFFFFFFFF).astype(jnp.uint32)
+    m32 = jnp.uint32(m)
+    z1 = (hi % m32 << 16) | (lo >> 16)
+    z0 = (z1 % m32 << 16) | (lo & 0xFFFF)
+    q = ((hi // m32).astype(jnp.int64) << 32
+         | (z1 // m32).astype(jnp.int64) << 16
+         | (z0 // m32).astype(jnp.int64))
+    return jnp.where(neg, -q - 1, q)
+
+
+def _rem_small(x, m: int):
+    """``x mod m`` (floor) as int32, for int64 ``x`` and m as above."""
+    return (x - m * _floor_div_small(x, m)).astype(jnp.int32)
+
+
 def segment_greedy_totals(queued, node_cap, flow_node, seg_start, node_seg):
     """segment_greedy with ``cap_cells = node_cap[flow_node]``, plus the
     cells each node served, without a scatter.
@@ -476,6 +500,21 @@ def pack_flush_np(forwards, delivered_sum, t_stop, newly, done_last,
     return buf
 
 
+@jax.jit
+def flush_halves(flush):
+    """The packed flush as its int32 halves, for the copy to the host
+    (flush_from_halves undoes it there).  The runtime copies an int64
+    array out through a host-side conversion (X64FromTuple), which took
+    17-29 ms of each ~30 ms readback of an 890k-flow plane's 5.9 MB flush
+    on v5e; an int32 array is copied as it is."""
+    return jax.lax.bitcast_convert_type(flush, jnp.int32)
+
+
+def flush_from_halves(halves: np.ndarray) -> np.ndarray:
+    """The int64 flush from flush_halves' int32 pairs, on the host."""
+    return np.ascontiguousarray(halves).view(np.int64).reshape(-1)
+
+
 def flush_moved(buf: np.ndarray) -> int:
     """The flush header's count of (flow, tick) pairs that moved a cell."""
     return int(buf[5])
@@ -511,6 +550,80 @@ def parse_flush(buf: np.ndarray, n_chains: int, n_nodes: int,
                 base + 2 * cc + hh + n_touch])
 
 
+def _span_loop(t0, targets, state, tables, ring_len: int):
+    """The superwindow tick loop over one flow table, shared by the
+    full-width program (_step_span_impl) and the compacted one
+    (_compact_step_span_impl): advance ``state`` = (queued, ring, tokens,
+    delivered, target, done_tick, node_sent) from ``t0`` through the
+    ascending absolute step boundaries in ``targets``, halting at the end
+    of the first sub-window in which any chain newly completed.
+
+    ``tables`` = (flow_node, seg_start, node_seg, refill, capacity,
+    flow_pred, arr_lat, is_last): each flow's node as an index into the
+    node columns, its node segment's first flow, each node's [first, end)
+    flow segment, the nodes' bucket refill and capacity, each flow's
+    predecessor (-1 where none), the latency of the hop into it, and
+    whether it is a chain's last stage.  Every operation in the loop
+    takes the length of these tables.  Returns (t_stop, *state,
+    forwards, moved)."""
+    (flow_node, seg_start, node_seg, refill, capacity, flow_pred, arr_lat,
+     is_last) = tables
+    p = targets.shape[0]
+    size = jnp.int64(CELL_WIRE_BYTES)
+    has_pred = flow_pred >= 0
+    pred = jnp.maximum(flow_pred, 0)
+    cols = jnp.arange(flow_node.shape[0])
+    end = targets[p - 1]
+    # ring rows in int32: tick t's row, t mod ring_len, rides beside t in
+    # the loop state (an int64 remainder is a long division on TPU, see
+    # _floor_div_small)
+    lat = arr_lat.astype(jnp.int32)
+
+    def body(state):
+        (t, row, idx, halt, span_done, queued, hist, tokens, delivered,
+         target, done_tick, node_sent, forwards, moved) = state
+        arr = hist[jnp.mod(row - lat, ring_len), cols]
+        queued = queued + arr
+        tokens = jnp.minimum(capacity, tokens + refill)
+        node_cap = _floor_div_small(tokens, CELL_WIRE_BYTES)
+        served, cells = segment_greedy_totals(queued, node_cap, flow_node,
+                                              seg_start, node_seg)
+        queued = queued - served
+        spent = cells * size
+        tokens = tokens - spent
+        node_sent = node_sent + spent
+        delivered = delivered + jnp.where(is_last, served, 0)
+        newly_done = (is_last & (target > 0) & (done_tick < 0)
+                      & (delivered >= target))
+        done_tick = jnp.where(newly_done, t, done_tick)
+        # cast before the gather: one gather in the ring dtype
+        fwd = jnp.where(is_last, 0, served).astype(hist.dtype)
+        v = jnp.where(has_pred, fwd[pred], jnp.zeros((), hist.dtype))
+        hist = jax.lax.dynamic_update_slice(hist, v[None],
+                                            (row, jnp.int32(0)))
+        forwards = forwards + jnp.sum(served)
+        moved = moved + jnp.sum((served > 0).astype(jnp.int64))
+        # sub-window bookkeeping: at a boundary, halt iff this span saw a
+        # completion; otherwise roll into the next span with a clean flag
+        span_done = span_done | jnp.any(newly_done)
+        boundary = (t + 1) == targets[jnp.minimum(idx, p - 1)]
+        halt = boundary & span_done
+        idx = jnp.where(boundary, idx + 1, idx)
+        span_done = span_done & ~boundary
+        row = jnp.where(row + 1 == ring_len, 0, row + 1)
+        return (t + 1, row, idx, halt, span_done, queued, hist, tokens,
+                delivered, target, done_tick, node_sent, forwards, moved)
+
+    def cond(state):
+        return (state[0] < end) & ~state[3]
+
+    init = (t0, _rem_small(t0, ring_len), jnp.int64(0),
+            jnp.bool_(False), jnp.bool_(False), *state, jnp.int64(0),
+            jnp.int64(0))
+    out = jax.lax.while_loop(cond, body, init)
+    return (out[0], *out[5:])
+
+
 def _step_span_impl(t0, queued, ring, tokens, delivered, target,
                     done_tick, node_sent, inject, inject_target,
                     targets, idle_ticks, flow_node, flow_lat,
@@ -540,63 +653,127 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
     takes a few ms.
     Returns the same 9-tuple, with [0] = the boundary actually reached,
     plus [9] = the (flow, tick) pairs in which a flow served a cell."""
-    f = queued.shape[0]
-    p = targets.shape[0]
-    size = jnp.int64(CELL_WIRE_BYTES)
-    is_last = flow_succ < 0
-    has_pred = flow_pred >= 0
-    pred = jnp.maximum(flow_pred, 0)
     queued = queued + inject
     target = target + inject_target
     tokens = jnp.minimum(capacity, tokens + refill * idle_ticks)
     ring = jax.lax.cond(idle_ticks > 0,
                         lambda hh: jnp.zeros_like(hh),
                         lambda hh: hh, ring)
-    arr_lat = jnp.where(has_pred, flow_lat[pred], jnp.int64(0))
-    cols = jnp.arange(f)
-    end = targets[p - 1]
+    arr_lat = jnp.where(flow_pred >= 0, flow_lat[jnp.maximum(flow_pred, 0)],
+                        jnp.int64(0))
+    return _span_loop(
+        t0, targets,
+        (queued, ring, tokens, delivered, target, done_tick, node_sent),
+        (flow_node, seg_start, node_seg, refill, capacity, flow_pred,
+         arr_lat, flow_succ < 0), ring_len)
 
-    def body(state):
-        (t, idx, halt, span_done, queued, hist, tokens, delivered, target,
-         done_tick, node_sent, forwards, moved) = state
-        arr = hist[jnp.mod(t - arr_lat, ring_len), cols]
-        queued = queued + arr
-        tokens = jnp.minimum(capacity, tokens + refill)
-        served, cells = segment_greedy_totals(queued, tokens // size,
-                                              flow_node, seg_start, node_seg)
-        queued = queued - served
-        spent = cells * size
-        tokens = tokens - spent
-        node_sent = node_sent + spent
-        delivered = delivered + jnp.where(is_last, served, 0)
-        newly_done = (is_last & (target > 0) & (done_tick < 0)
-                      & (delivered >= target))
-        done_tick = jnp.where(newly_done, t, done_tick)
-        # cast before the gather: one gather in the ring dtype
-        fwd = jnp.where(is_last, 0, served).astype(hist.dtype)
-        v = jnp.where(has_pred, fwd[pred], jnp.zeros((), hist.dtype))
-        hist = jax.lax.dynamic_update_slice(
-            hist, v[None], (jnp.mod(t, ring_len), jnp.int64(0)))
-        forwards = forwards + jnp.sum(served)
-        moved = moved + jnp.sum((served > 0).astype(jnp.int64))
-        # sub-window bookkeeping: at a boundary, halt iff this span saw a
-        # completion; otherwise roll into the next span with a clean flag
-        span_done = span_done | jnp.any(newly_done)
-        boundary = (t + 1) == targets[jnp.minimum(idx, p - 1)]
-        halt = boundary & span_done
-        idx = jnp.where(boundary, idx + 1, idx)
-        span_done = span_done & ~boundary
-        return (t + 1, idx, halt, span_done, queued, hist, tokens,
-                delivered, target, done_tick, node_sent, forwards, moved)
 
-    def cond(state):
-        return (state[0] < end) & ~state[2]
+def _compact_step_span_impl(t0, queued, ring, tokens, delivered, target,
+                            done_tick, node_sent, live, targets, idle_ticks,
+                            flow_node, flow_lat, flow_succ, seg_start,
+                            refill, capacity, flow_pred, ring_len: int):
+    """_step_span_impl over the live flows alone: the same 10-tuple, bit
+    for bit, from a tick loop whose every operation is K long.
 
-    state = (t0, jnp.int64(0), jnp.bool_(False), jnp.bool_(False),
-             queued, ring, tokens, delivered, target, done_tick,
-             node_sent, jnp.int64(0), jnp.int64(0))
-    out = jax.lax.while_loop(cond, body, state)
-    return (out[0], *out[4:])
+    ``live`` int64 [3, K]: the ascending table positions of every flow
+    that holds or receives a cell before the dispatch ends (whole chains;
+    padded with F), and the cells injected and the target added at each.
+    Every other flow has nothing queued or in flight and receives
+    nothing, so it serves nothing and its columns stay as they are.  The
+    kernel gathers the live columns, derives their tables (predecessor
+    and segment starts by ``searchsorted`` of the positions; the live
+    flows' nodes and each one's segment from the node-sorted order), runs
+    the shared tick loop, then writes back:
+
+    * the live columns and the live nodes' buckets and bytes sent;
+    * every other node's bucket refilled in closed form over the ticks
+      actually run, ``min(capacity, tokens + refill * n)`` (a capped
+      refill composes);
+    * the ring rows of those ticks zeroed in every other column, as the
+      full program writes a quiet flow's empty sends, so the ring equals
+      the full program's and a flow that turns live later reads no stale
+      send."""
+    f = queued.shape[0]
+    h = refill.shape[0]
+    k = live.shape[1]
+    pos = live[0]
+    valid = pos < f
+    at = jnp.minimum(pos, f - 1)
+    tokens = jnp.minimum(capacity, tokens + refill * idle_ticks)
+    ring = jax.lax.cond(idle_ticks > 0,
+                        lambda hh: jnp.zeros_like(hh),
+                        lambda hh: hh, ring)
+
+    def take(col, fill):
+        return jnp.where(valid, col[at], jnp.asarray(fill, col.dtype))
+
+    def find(p):
+        return jnp.searchsorted(pos, p, method="scan")
+
+    # the live flows' tables; padding is a last stage of node h, no cells
+    node = take(flow_node, h)
+    pred_at = take(flow_pred, -1)
+    pred = jnp.where(pred_at >= 0, find(pred_at), -1)
+    arr_lat = jnp.where(pred_at >= 0, flow_lat[jnp.maximum(pred_at, 0)],
+                        jnp.int64(0))
+    seg_start_k = find(take(seg_start, f))
+    # the live nodes: node-sorted flows, numbered by segment
+    first = jnp.concatenate([jnp.ones(1, bool), node[1:] != node[:-1]])
+    flow_u = jnp.cumsum(first.astype(jnp.int32)) - 1
+    u = jnp.arange(k, dtype=jnp.int32)
+    useg = jnp.stack([jnp.searchsorted(flow_u, u, side="left",
+                                       method="scan"),
+                      jnp.searchsorted(flow_u, u, side="right",
+                                       method="scan")])
+    unode = jnp.where(useg[0] < useg[1],
+                      node[jnp.minimum(useg[0], k - 1)], h)
+    real = unode < h
+    un = jnp.minimum(unode, h - 1)
+
+    def take_node(col):
+        return jnp.where(real, col[un], jnp.zeros((), col.dtype))
+
+    hist = jnp.where(valid[None, :], ring[:, at], jnp.zeros((), ring.dtype))
+    out = _span_loop(
+        t0, targets,
+        (take(queued, 0) + live[1], hist, take_node(tokens),
+         take(delivered, 0), take(target, 0) + live[2],
+         take(done_tick, -1), take_node(node_sent)),
+        (flow_u, seg_start_k, useg, take_node(refill), take_node(capacity),
+         pred, arr_lat, take(flow_succ, -1) < 0), ring_len)
+    (t_stop, queued_k, hist, tokens_u, delivered_k, target_k, done_k,
+     sent_u, forwards, moved) = out
+    ran = t_stop - t0
+    wrote = jnp.mod(jnp.arange(ring_len, dtype=jnp.int32)
+                    - _rem_small(t0, ring_len), ring_len) < ran
+
+    def put(col, vals):
+        return col.at[pos].set(vals, mode="drop")
+
+    tokens = jnp.minimum(capacity, tokens + refill * ran) \
+        .at[unode].set(tokens_u, mode="drop")
+    ring = jnp.where(wrote[:, None], jnp.zeros((), ring.dtype), ring) \
+        .at[:, pos].set(hist, mode="drop")
+    return (t_stop, put(queued, queued_k), ring, tokens,
+            put(delivered, delivered_k), put(target, target_k),
+            put(done_tick, done_k),
+            node_sent.at[unode].set(sent_u, mode="drop"), forwards, moved)
+
+
+def _with_flush(out, done_in_last, node_sent_in, last_flow,
+                cap_chains: Optional[int] = None,
+                cap_nodes: Optional[int] = None):
+    """A span step's 10-tuple as the flush programs return it: the 9-tuple
+    with the packed flush buffer appended as [9] (its moved count rides in
+    the flush header).  ``done_in_last`` and ``node_sent_in`` are the
+    chains' exit-flow done ticks and the nodes' bytes sent before the
+    step."""
+    done_last = out[6][last_flow]
+    newly = (done_last >= 0) & (done_in_last < 0)
+    flush = _pack_flush_jnp(out[8], jnp.sum(out[4][last_flow]), out[0],
+                            newly, done_last, out[7] - node_sent_in,
+                            cap_chains, cap_nodes, moved=out[9])
+    return (*out[:9], flush)
 
 
 def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
@@ -607,25 +784,34 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
                           cap_chains: Optional[int] = None,
                           cap_nodes: Optional[int] = None):
     """Superwindow step + packed flush in ONE dispatch: the 9-tuple of
-    _step_span_impl with the packed flush buffer appended as [9] (its
-    moved count rides in the flush header).
+    _step_span_impl with the packed flush buffer appended as [9].
     ``last_flow`` [C] maps each chain to its exit flow row;
     ``flow_pred`` and ``node_seg`` come from gather_tables.  With caps
     the flush is the capped (delta-compacted) buffer — see
     _pack_flush_jnp."""
-    done_in_last = done_tick[last_flow]
-    node_sent_in = node_sent
     out = _step_span_impl(t0, queued, ring, tokens, delivered, target,
                           done_tick, node_sent, inject, inject_target,
                           targets, idle_ticks, flow_node, flow_lat,
                           flow_succ, seg_start, refill, capacity,
                           flow_pred, node_seg, ring_len)
-    done_last = out[6][last_flow]
-    newly = (done_last >= 0) & (done_in_last < 0)
-    flush = _pack_flush_jnp(out[8], jnp.sum(out[4][last_flow]), out[0],
-                            newly, done_last, out[7] - node_sent_in,
-                            cap_chains, cap_nodes, moved=out[9])
-    return (*out[:9], flush)
+    return _with_flush(out, done_tick[last_flow], node_sent, last_flow,
+                       cap_chains, cap_nodes)
+
+
+def _compact_step_span_flush_impl(t0, queued, ring, tokens, delivered,
+                                  target, done_tick, node_sent, live,
+                                  targets, idle_ticks, flow_node, flow_lat,
+                                  flow_succ, seg_start, refill, capacity,
+                                  last_flow, flow_pred, ring_len: int):
+    """_step_span_flush_impl through _compact_step_span_impl: the same
+    10-tuple and the same full-length flush, from a tick loop over the
+    ``live`` flows alone."""
+    out = _compact_step_span_impl(t0, queued, ring, tokens, delivered,
+                                  target, done_tick, node_sent, live,
+                                  targets, idle_ticks, flow_node, flow_lat,
+                                  flow_succ, seg_start, refill, capacity,
+                                  flow_pred, ring_len)
+    return _with_flush(out, done_tick[last_flow], node_sent, last_flow)
 
 
 # Two jit wrappers over the SAME flush program, picked by backend
@@ -653,12 +839,46 @@ torcells_step_window_flush_capped = partial(
         _step_span_flush_impl)
 
 
+# The compacted flush program over a dispatch's live flows, in the same
+# two jit wrappers; each width of ``live`` is a program of its own.
+torcells_step_compact_flush = partial(
+    jax.jit, static_argnames=("ring_len",),
+    donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))(_compact_step_span_flush_impl)
+
+torcells_step_compact_flush_nodonate = partial(
+    jax.jit, static_argnames=("ring_len",))(_compact_step_span_flush_impl)
+
+
 def step_window_flush_for_backend():
     """The flush-step jit appropriate for the default backend (see note
     above): donating on accelerators, non-donating on CPU."""
     if jax.default_backend() == "cpu":
         return torcells_step_window_flush_nodonate
     return torcells_step_window_flush
+
+
+def compact_flush_for_backend():
+    """The compacted flush program for the default backend, as above."""
+    if jax.default_backend() == "cpu":
+        return torcells_step_compact_flush_nodonate
+    return torcells_step_compact_flush
+
+
+# The compacted widths: powers of two at or above F/256 and F/16, never
+# below COMPACT_FLOOR.  A dispatch runs the smallest that holds its live
+# flows, and the full-width program beyond the larger.
+COMPACT_FLOOR = 1024
+
+
+def compact_widths(n_flows: int) -> Tuple[int, ...]:
+    """The compacted program widths for an ``n_flows`` table, ascending;
+    a width that is not below ``n_flows`` would save nothing and is left
+    out."""
+    widths = set()
+    for share in (256, 16):
+        need = max(-(-n_flows // share), 1)          # ceil(F / share)
+        widths.add(max(COMPACT_FLOOR, 1 << (need - 1).bit_length()))
+    return tuple(w for w in sorted(widths) if w < n_flows)
 
 
 # Fleet plane (ISSUE 18): the SAME span/flush program vmapped over a

@@ -438,8 +438,16 @@ class DeviceTrafficPlane:
         self._inflight = False
         self._flush_handle = None    # in-flight packed flush (1-deep slot)
         self._flush_step = None      # backend-selected flush kernel (lazy)
+        self._compact_step = None    # ... and its compacted program
         self._ticks_synced = 0
         self._inject_buf: List[Tuple[int, int]] = []   # (circuit, cells)
+        # the live chains (an insertion-ordered set): injected, and their
+        # completion not yet folded from a flush.  No other chain holds or
+        # receives a cell, so a dispatch may step these alone (the
+        # compacted program); a chain whose second completion is never
+        # reported stays in, which is slower and never wrong
+        self._live: Dict[int, None] = {}
+        self._live_flows = 0
         self._waiters: Dict[int, Tuple[object, object]] = {}
         self._done: Dict[int, int] = {}   # circuit -> wake sim time ns
         self._woken: set = set()
@@ -467,6 +475,11 @@ class DeviceTrafficPlane:
         # which a flow moved a cell (the flush header's count)
         self.ticks_stepped = 0
         self.flow_ticks_moved = 0
+        # flow-ticks the kernel stepped (its width x ticks executed) and
+        # the dispatches that ran a compacted width
+        self.flow_ticks_stepped = 0
+        self.compact_dispatches = 0
+        self._launch_width = 0
         # pipeline introspection: actual host<->device interactions (kernel
         # dispatch + inject upload + flush read) and the wall the in-flight
         # dispatch had to compute behind host round work
@@ -658,6 +671,13 @@ class DeviceTrafficPlane:
         self.first_flow = pos_of[chain_base]
         self.last_flow = pos_of[chain_base + chain_len - 1]
         self.n_chains = len(chains)
+        # chain c's flow positions: _chain_rows[b:b + n], b = _chain_base[c]
+        # and n = _chain_len[c] (the pre-sort table is chain-contiguous)
+        self._chain_rows = pos_of
+        self._chain_base = chain_base
+        self._chain_len = chain_len
+        from ..ops.torcells_device import compact_widths
+        self._compact_widths = compact_widths(n_flows)
         # Step granulation: the kernel's loop iteration covers ``granule``
         # milliseconds.  Chosen so the arrival ring stays <= ~64 slots even
         # on multi-second-latency topologies (the reference GraphML has
@@ -908,6 +928,35 @@ class DeviceTrafficPlane:
             self._zero_inject_cached = z
         return self._zero_inject_cached
 
+    def _compact_width(self) -> int:
+        """The compacted width this dispatch runs: the smallest that holds
+        the live flows, or 0 for the full-width program (past the largest
+        width, on the numpy twin, a mesh, a fleet lane or a capped
+        flush)."""
+        if (self.mode != "device" or self._shard is not None
+                or self._lane is not None or self._flush_caps is not None):
+            return 0
+        return next((w for w in self._compact_widths
+                     if w >= self._live_flows), 0)
+
+    def _live_table(self, width: int, pairs) -> np.ndarray:
+        """The compacted program's ``live`` operand: the live chains' flow
+        positions, ascending and padded with F to ``width``, with the
+        staged ``pairs``' cells and targets at their chains' entry and exit
+        flows.  O(live flows) host work."""
+        chains = np.fromiter(self._live, dtype=np.int64, count=len(self._live))
+        n = self._chain_len[chains]
+        rows = np.repeat(self._chain_base[chains] - np.cumsum(n) + n, n) \
+            + np.arange(int(n.sum()))
+        pos = np.sort(self._chain_rows[rows])
+        live = np.zeros((3, width), dtype=np.int64)
+        live[0] = self.n_flows
+        live[0, :len(pos)] = pos
+        for circ, cells in pairs:
+            live[1, np.searchsorted(pos, self.first_flow[circ])] += cells
+            live[2, np.searchsorted(pos, self.last_flow[circ])] += cells
+        return live
+
     # -- app-facing -------------------------------------------------------
     def activate(self, client_name: str, cells: Optional[int] = None) -> int:
         """Called by the client app once its circuit is built: inject both
@@ -977,9 +1026,10 @@ class DeviceTrafficPlane:
         self._waiters[circuit] = (process, thread)
 
     def warmup(self) -> None:
-        """Pre-compile the windowed kernel for this plane's exact shapes
-        using throwaway state (XLA compiles are 20-40s on a real TPU; the
-        bench excludes them from timed walls).  No plane state is touched."""
+        """Pre-compile the windowed kernel for this plane's exact shapes,
+        and its compacted program at each compacted width, using throwaway
+        state (XLA compiles are 20-40s on a real TPU; the bench excludes
+        them from timed walls).  No plane state is touched."""
         with self._profiler.tracer.annotate("plane.warmup"):
             self._warmup()
 
@@ -994,6 +1044,8 @@ class DeviceTrafficPlane:
         import jax
         import jax.numpy as jnp
         from ..ops.torcells_device import (RING_DTYPE,
+                                           compact_flush_for_backend,
+                                           flush_halves,
                                            step_window_flush_for_backend)
         if self._flush_step is None:
             self._flush_step = step_window_flush_for_backend()
@@ -1015,18 +1067,22 @@ class DeviceTrafficPlane:
             return
         f, h = self.n_flows, self.n_nodes
         z = np.zeros(f, dtype=np.int64)
-        state = (np.int64(0), jnp.zeros(f, jnp.int64),
-                 jnp.zeros((self.ring_len, f), RING_DTYPE),
-                 jnp.asarray(self.capacity_step),
-                 jnp.zeros(f, jnp.int64), jnp.zeros(f, jnp.int64),
-                 jnp.full(f, -1, jnp.int64), jnp.zeros(h, jnp.int64))
+
+        def fresh():
+            return (np.int64(0), jnp.zeros(f, jnp.int64),
+                    jnp.zeros((self.ring_len, f), RING_DTYPE),
+                    jnp.asarray(self.capacity_step),
+                    jnp.zeros(f, jnp.int64), jnp.zeros(f, jnp.int64),
+                    jnp.full(f, -1, jnp.int64), jnp.zeros(h, jnp.int64))
+
+        state = fresh()
         out = self._flush_step(
             *state, z, z, self._pad_targets([1]), np.int64(0),
             self.flow_node, self.flow_lat_steps, self.flow_succ,
             self.seg_start, self.refill_step, self.capacity_step,
             self.last_flow, self.flow_pred, self.node_seg,
             ring_len=self.ring_len)
-        jax.block_until_ready(out)
+        jax.block_until_ready(flush_halves(out[9]))        # its readback
         if self._flush_caps is not None:
             # the tuned dispatch runs the CAPPED flush kernel — compile
             # it here too so the first timed dispatch pays no XLA wall
@@ -1039,6 +1095,19 @@ class DeviceTrafficPlane:
                 self.last_flow, self.flow_pred, self.node_seg,
                 ring_len=self.ring_len,
                 cap_chains=cc, cap_nodes=hh)
+            jax.block_until_ready(out)
+            return
+        # every compacted width a dispatch may pick (_compact_width), with
+        # a live table of padding alone
+        self._compact_step = compact_flush_for_backend()
+        for width in self._compact_widths:
+            live = np.zeros((3, width), dtype=np.int64)
+            live[0] = f
+            out = self._compact_step(
+                *fresh(), live, self._pad_targets([1]), np.int64(0),
+                self.flow_node, self.flow_lat_steps, self.flow_succ,
+                self.seg_start, self.refill_step, self.capacity_step,
+                self.last_flow, self.flow_pred, ring_len=self.ring_len)
             jax.block_until_ready(out)
 
     def _pad_targets(self, targets: List[int]) -> np.ndarray:
@@ -1190,24 +1259,34 @@ class DeviceTrafficPlane:
         the synced step); ``t0`` is advance()'s entry stamp."""
         import time as _wt
         inject_pairs = list(self._inject_buf)
-        if self._inject_buf:
+        self._inject_buf.clear()
+        for circ, cells in inject_pairs:
+            self._cells_dispatched += cells
+            if circ not in self._live:
+                self._live[circ] = None
+                self._live_flows += int(self._chain_len[circ])
+        from ..ops.torcells_device import MAX_CELLS_IN_FLIGHT
+        if (self._cells_dispatched - self._cells_delivered_seen
+                > MAX_CELLS_IN_FLIGHT):
+            # an upper bound on every node's backlog: the kernel's int32
+            # segment prefix sums are exact only below it
+            raise ValueError(
+                "device plane: more than 2**31-1 cells in flight — "
+                "the kernel's int32 segment prefix sums would not be "
+                "exact (shorten the transfers or split the run)")
+        width = self._compact_width()
+        if width:
+            # the compacted program: the live flows' positions and their
+            # injections in one [3, width] upload, nothing of length F
+            live = self._live_table(width, inject_pairs)
+            self.device_calls += 1              # live table upload
+        elif inject_pairs:
             f = self.n_flows
             inject = np.zeros(f, dtype=np.int64)
             inject_target = np.zeros(f, dtype=np.int64)
-            for circ, cells in self._inject_buf:
+            for circ, cells in inject_pairs:
                 inject[self.first_flow[circ]] += cells
                 inject_target[self.last_flow[circ]] += cells
-                self._cells_dispatched += cells
-            self._inject_buf.clear()
-            from ..ops.torcells_device import MAX_CELLS_IN_FLIGHT
-            if (self._cells_dispatched - self._cells_delivered_seen
-                    > MAX_CELLS_IN_FLIGHT):
-                # an upper bound on every node's backlog: the kernel's
-                # int32 segment prefix sums are exact only below it
-                raise ValueError(
-                    "device plane: more than 2**31-1 cells in flight — "
-                    "the kernel's int32 segment prefix sums would not be "
-                    "exact (shorten the transfers or split the run)")
             if self._shard is not None:
                 from .mesh.partition import pad_state
                 inject = pad_state(self._shard, inject)
@@ -1216,6 +1295,9 @@ class DeviceTrafficPlane:
                 self.device_calls += 1          # inject upload
         else:
             inject = inject_target = self._zero_inject()
+        self._launch_width = width or (
+            len(self._shard["src"]) if self._shard is not None
+            else self.n_flows)
         idle = self._idle_ticks_banked
         self._idle_ticks_banked = 0
         # Step continuity: the kernel's carried t equals the last dispatch's
@@ -1254,6 +1336,14 @@ class DeviceTrafficPlane:
             out = self._lane.dispatch(state, np.asarray(inject),
                                       np.asarray(inject_target), tvec,
                                       int(idle))
+        elif width:
+            if self._compact_step is None:
+                from ..ops.torcells_device import compact_flush_for_backend
+                self._compact_step = compact_flush_for_backend()
+            out = self._compact_step(*state, live, tvec, np.int64(idle),
+                                     *self._flow_args()[:8],
+                                     ring_len=self.ring_len)
+            self.compact_dispatches += 1
         elif self.mode == "device":
             if self._flush_step is None:
                 from ..ops.torcells_device import (
@@ -1331,7 +1421,7 @@ class DeviceTrafficPlane:
                 kernel_flows = len(self._shard["src"])
                 ex_us = self._meshinfo.predicted_us
             else:
-                kernel_flows = self.n_flows
+                kernel_flows = self._launch_width
                 ex_us = 0.0
             # only predict INSIDE the model's measured range (the
             # two-sided CostModel.covers guard): a table far below the
@@ -1435,6 +1525,7 @@ class DeviceTrafficPlane:
         steps_done = max(int(t_stop) - self._launch_base, 0)
         self.ticks_stepped += steps_done
         self.flow_ticks_moved += flush_moved(flush)
+        self.flow_ticks_stepped += steps_done * self._launch_width
         # launch attribution (ISSUE 15): predicted-vs-measured per-launch
         # gauges and the model-stale band check — one call per collect,
         # ~free when no model is loaded and observability is off.  Placed AFTER parse_flush
@@ -1501,6 +1592,12 @@ class DeviceTrafficPlane:
         # vectorized control-plane cut (ISSUE 7)
         if len(node_idx):
             np.add.at(self._node_pending, node_idx, node_delta)
+        # a chain whose completion came back holds no cell: it leaves the
+        # live set (a later injection brings it back)
+        for chain in done_chains.tolist():
+            if chain in self._live:
+                del self._live[chain]
+                self._live_flows -= int(self._chain_len[chain])
 
         # wake completed clients: BOTH chains (download 2c, upload 2c+1)
         # must have delivered; wake at the later completion step
@@ -1563,7 +1660,9 @@ class DeviceTrafficPlane:
         """Block until the dispatch behind ``handle`` is done, then copy
         its flush buffer to the host: (buffer, perf_counter_ns stamp
         between the two).  A handle that is already host memory (the numpy
-        twin, a fleet lane's row) has nothing to wait for."""
+        twin, a fleet lane's row) has nothing to wait for.  A single
+        device's full-length flush comes over as its int32 halves
+        (flush_halves)."""
         import time as _wt
         tracer = self._profiler.tracer
         block = getattr(handle, "block_until_ready", None)
@@ -1572,6 +1671,12 @@ class DeviceTrafficPlane:
                 block()
         t_read = _wt.perf_counter_ns()
         with tracer.annotate("plane.readback"):
+            if block is not None and self._shard is None \
+                    and self._flush_caps is None:
+                from ..ops.torcells_device import (flush_from_halves,
+                                                   flush_halves)
+                return flush_from_halves(np.asarray(flush_halves(handle))), \
+                    t_read
             return np.asarray(handle), t_read
 
     def _collect_flush(self, engine, handle) -> Tuple[np.ndarray, int]:
@@ -1964,6 +2069,10 @@ class DeviceTrafficPlane:
             # depend on how the tick is implemented
             "ticks_stepped": self.ticks_stepped,
             "flow_ticks_moved": self.flow_ticks_moved,
+            # the flow-ticks the kernel stepped to do that work (width x
+            # ticks: the compacted width when a dispatch ran one)
+            "flow_ticks_stepped": self.flow_ticks_stepped,
+            "compact_dispatches": self.compact_dispatches,
             # pipeline introspection: host<->device interactions (dispatch +
             # inject upload + flush read; <= 3 per dispatch) and the wall
             # the in-flight dispatch computed behind host round work

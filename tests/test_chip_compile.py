@@ -72,6 +72,18 @@ def test_donating_span_flush_compiles_for_v5e(one_chip, n_chains):
     assert compiled.memory_analysis().alias_size_in_bytes > 0  # donated
 
 
+@pytest.mark.parametrize("width", [4096, 65536])
+def test_donating_compact_span_flush_compiles_for_v5e(one_chip, width):
+    """The compacted span-flush at both widths of a 20,000-chain plane
+    (100,000 flows): its tick loop is the loop the v5e compiler once
+    refused for VMEM at full width."""
+    shapes = _flush_shapes(one_chip, 20_000)
+    live = jax.ShapeDtypeStruct((3, width), jnp.int64, sharding=one_chip)
+    compiled = td.torcells_step_compact_flush.lower(
+        *shapes[:8], live, *shapes[10:20], ring_len=66).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0  # donated
+
+
 def test_capped_flush_compiles_for_v5e(one_chip):
     td.torcells_step_window_flush_capped.lower(
         *_flush_shapes(one_chip, 10_000), ring_len=66, cap_chains=256,

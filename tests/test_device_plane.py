@@ -428,3 +428,68 @@ def test_collects_share_one_thread(monkeypatch):
     assert len(set(seen)) == 1
     assert seen[0].name == "device-dispatch-collect"
     assert seen[0].is_alive()
+
+
+# ---------------------------------------------------------------------------
+# the compacted span-flush: a dispatch steps its live chains alone
+# ---------------------------------------------------------------------------
+
+def _waves(mode, waves, step, stop):
+    """genscen's tor deployment at 250 hosts on one device: 223
+    process-less circuits, 2,230 flows (one compacted width, 1,024), in
+    ``waves`` waves ``step`` seconds apart from 2 s.  The 1 s heartbeat
+    runs rounds while the plane is empty, which bank idle ticks."""
+    from shadow_tpu.scale import genscen
+    cfg = genscen.tor(250, stoptime=stop, stagger_waves=waves,
+                      stagger_step_sec=step)
+    ctrl = Controller(Options(scheduler_policy="global", workers=0, seed=5,
+                              stop_time_sec=stop, log_level="warning",
+                              host_table="on", heartbeat_interval_sec=1,
+                              device_plane=mode, tpu_devices=1,
+                              device_plane_granule_ms=10), cfg)
+    assert ctrl.run() == 0
+    return ctrl
+
+
+def _same_plane_state(a, b):
+    for i, (x, y) in enumerate(zip(a.engine.device_plane._state,
+                                   b.engine.device_plane._state)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f"state {i}")
+    assert state_digest(a.engine) == state_digest(b.engine)
+
+
+def test_compacted_plane_matches_the_twin():
+    """Waves 0.5 s apart: each dispatch holds one wave's ~250 live flows,
+    so every dispatch runs the 1,024-wide program, idle ticks banked
+    between waves fold into each, and the plane ends in the twin's state
+    with every circuit done and no chain live."""
+    dev, twin = _waves("device", 10, 0.5, 10), _waves("numpy", 10, 0.5, 10)
+    _same_plane_state(dev, twin)
+    plane = dev.engine.device_plane
+    st = plane.stats()
+    assert plane._compact_widths == (1024,)
+    assert st["compact_dispatches"] == st["dispatches"] == 10
+    assert st["idle_rounds_skipped"] > 0
+    assert st["completed"] == st["circuits"] == 223
+    assert not plane._live and plane._live_flows == 0
+    assert st["flow_ticks_stepped"] == 1024 * st["ticks_stepped"]
+    assert st["flow_ticks_moved"] == twin.engine.device_plane.stats()[
+        "flow_ticks_moved"]
+    assert twin.engine.device_plane.stats()["compact_dispatches"] == 0
+
+
+def test_plane_falls_to_full_width_past_the_top_width(monkeypatch):
+    """Waves 70 ms apart overlap, so a dispatch holds one or two waves'
+    flows (190 or ~380): with a top width of 256 the plane alternates
+    between the compacted and the full-width program, and still ends in
+    the twin's state."""
+    import shadow_tpu.ops.torcells_device as td
+    monkeypatch.setattr(td, "compact_widths", lambda n_flows: (256,))
+    dev, twin = _waves("device", 12, 0.07, 4), _waves("numpy", 12, 0.07, 4)
+    _same_plane_state(dev, twin)
+    st = dev.engine.device_plane.stats()
+    assert 0 < st["compact_dispatches"] < st["dispatches"]
+    f = dev.engine.device_plane.n_flows
+    assert (256 * st["ticks_stepped"] < st["flow_ticks_stepped"]
+            < f * st["ticks_stepped"])

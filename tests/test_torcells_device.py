@@ -289,15 +289,22 @@ def test_fleet_kernel_padded_lanes_match_twin():
 
 
 def _while_body(text: str) -> str:
-    """The ``do`` region of the one ``stablehlo.while`` in ``text``."""
-    assert text.count("stablehlo.while") == 1
-    start = text.index("} do {", text.index("stablehlo.while")) + 5
-    depth = 0
-    for j in range(start, len(text)):
-        depth += {"{": 1, "}": -1}.get(text[j], 0)
-        if depth == 0:
-            return text[start:j + 1]
-    raise AssertionError("unterminated while body")
+    """The ``do`` region of the tick loop in ``text``: of its
+    ``stablehlo.while`` loops, the one whose body writes a ring row."""
+    bodies = []
+    at = text.find("stablehlo.while")
+    while at >= 0:
+        start = text.index("} do {", at) + 5
+        depth = 0
+        for j in range(start, len(text)):
+            depth += {"{": 1, "}": -1}.get(text[j], 0)
+            if depth == 0:
+                bodies.append(text[start:j + 1])
+                break
+        at = text.find("stablehlo.while", at + 1)
+    loops = [b for b in bodies if "dynamic_update_slice" in b]
+    assert len(loops) == 1, f"{len(loops)} tick loops"
+    return loops[0]
 
 
 def test_span_flush_tick_loop_has_no_scatter():
@@ -315,10 +322,213 @@ def test_span_flush_tick_loop_has_no_scatter():
     text = jax.jit(_step_span_flush_impl, static_argnames=("ring_len",)) \
         .lower(*state, z, z, _EIGHT_SPANS, np.int64(0), *tables,
                *_gather(tables), ring_len=4).as_text()
-    body = _while_body(text)
-    assert "dynamic_update_slice" in body            # the ring's row write
+    body = _while_body(text)                          # writes the ring's row
     assert "scatter" not in body
     assert "scatter" in text.replace(body, "")       # the flush pack's
+
+
+# -- the compacted span-flush: the live flows alone, bit for bit ------------
+
+def _live(fl, circuits, width, inject, inject_target):
+    """The compacted program's live table for ``circuits`` of a build_flows
+    layout: their flow positions, ascending and padded with F, with the
+    injections there (none may fall elsewhere)."""
+    f = len(fl["flow_node"])
+    on = np.isin(fl["flow_circ"], circuits)
+    assert not (np.asarray(inject)[~on].any()
+                or np.asarray(inject_target)[~on].any())
+    pos = np.flatnonzero(on)
+    live = np.zeros((3, width), np.int64)
+    live[0] = f
+    live[0, :len(pos)] = pos
+    live[1, :len(pos)] = np.asarray(inject)[pos]
+    live[2, :len(pos)] = np.asarray(inject_target)[pos]
+    return live
+
+
+def _compact_parity(fl, tables, state, inject, inject_target, targets,
+                    circuits, idle=0, width=32, ring_len=4):
+    """The compacted span-flush over ``circuits`` against the full-width
+    program and the numpy twin (_span_parity) from the same state: all
+    ten outputs equal bit for bit, the ring included.  Returns them."""
+    from shadow_tpu.ops.torcells_device import (
+        torcells_step_compact_flush_nodonate)
+    twin = _span_parity(tables, state, inject, inject_target, targets, idle,
+                        ring_len)
+    comp = torcells_step_compact_flush_nodonate(
+        *state, _live(fl, circuits, width, inject, inject_target),
+        np.asarray(targets, np.int64), np.int64(idle), *tables,
+        fl["flow_pred"], ring_len=ring_len)
+    for i in range(10):
+        np.testing.assert_array_equal(np.asarray(comp[i]),
+                                      np.asarray(twin[i]),
+                                      err_msg=f"output {i}")
+    return twin
+
+
+def _injected(fl, circuits, cells):
+    on = np.isin(fl["flow_circ"], circuits)
+    return (np.where(on & (fl["flow_stage"] == 0), cells, 0),
+            np.where(on & (fl["flow_succ"] < 0), cells, 0))
+
+
+def _after(out):
+    """The carried state a flush program's outputs leave for the next."""
+    return [np.asarray(a).copy() for a in out[:8]]
+
+
+def test_compact_parity_live_and_quiet_flows_share_segments():
+    """All six circuits run to completion, then three of them again: node
+    1 paces every circuit's first stage and node 25 every last, so live
+    and quiet flows share those segments.  Node 1's bucket, half full and
+    refilled by 3, holds 6 cells: in greedy order the first live flow
+    takes its 5, the second 1, the third none.  The quiet flows keep
+    their columns and their nodes refill as the full program's do."""
+    n = 27
+    refill, cap = np.full(n, 50), np.full(n, 100)
+    refill[1], cap[1] = 3, 7
+    fl, tables = _tor_table(_SHARED_ROUTE, n, refill, cap)
+    inject, target = _injected(fl, range(6), 4)
+    first = _span_parity(tables, _zero_state(tables), inject, target,
+                         [40, 40])
+    assert (np.asarray(first[6])[fl["flow_succ"] < 0] >= 0).all()
+    state = _after(first)
+    state[3] = state[3] // 2               # buckets below capacity
+    inject, target = _injected(fl, [1, 3, 4], 5)
+    one = _compact_parity(fl, tables, state, inject, target, [41],
+                          [1, 3, 4])
+    seg = slice(*fl["node_seg"][:, 1])
+    served = (state[1] + inject - one[1])[seg]
+    assert list(served) == [0, 5, 0, 1, 0, 0]
+    out = _compact_parity(fl, tables, state, inject, target, _EIGHT_SPANS + 40,
+                          [1, 3, 4])
+    assert int(out[8]) > 0
+
+
+@pytest.mark.parametrize("gap", [1, 2, 3, 4])
+def test_compact_parity_chain_injected_again_after_leaving(gap):
+    """The stale-ring case: circuit 2 completes, a dispatch steps the other
+    live circuit alone for ``gap`` - 1 ticks, and circuit 2 is injected
+    again ``gap`` ticks after it left, with its ring columns holding its
+    last run's sends.  Every dispatch matches the full-width program and
+    the twin, the ring included."""
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 40),
+                            np.full(n, 80))
+    inj2, tgt2 = _injected(fl, [2], 3)
+    inj5, tgt5 = _injected(fl, [5], 400)
+    out = _compact_parity(fl, tables, _zero_state(tables), inj2 + inj5,
+                          tgt2 + tgt5, np.arange(1, 9) * 3, [2, 5])
+    t = int(out[0])
+    assert np.asarray(out[6])[fl["flow_succ"] < 0].max() >= 0   # 2 is done
+    assert np.asarray(out[2]).any()                 # sends left in the ring
+    state = _after(out)
+    zero = np.zeros_like(inj2)
+    if gap > 1:
+        state = _after(_compact_parity(fl, tables, state, zero, zero,
+                                       [t + gap - 1], [5]))
+    out = _compact_parity(fl, tables, state, inj2, tgt2,
+                          np.arange(1, 9) * 3 + t + gap - 1, [2, 5])
+    assert int(out[8]) > 0
+
+
+def test_compact_parity_halts_mid_span():
+    """A K=8 span halting at the boundary after a completion: the quiet
+    nodes' buckets, a third full, refill in closed form over the ticks
+    actually run, not the plan's."""
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 4), np.full(n, 80))
+    state = _zero_state(tables)
+    state[3] = tables[5] // 3
+    inject, target = _injected(fl, [0, 4], 4)
+    out = _compact_parity(fl, tables, state, inject, target, _EIGHT_SPANS,
+                          [0, 4])
+    assert int(out[0]) in _EIGHT_SPANS[:-1]
+    assert (np.asarray(out[3]) < tables[5]).any()   # refill not yet capped
+
+
+def test_compact_parity_idle_ticks_between_dispatches():
+    """Banked idle ticks fold into every bucket and clear a ring full of
+    stale sends before the compacted loop's first tick."""
+    n = 27
+    fl, tables = _tor_table(_SHARED_ROUTE, n, np.full(n, 4), np.full(n, 9))
+    state = _zero_state(tables)
+    rng = np.random.default_rng(4)
+    state[2] = rng.integers(1, 50, size=state[2].shape).astype(
+        state[2].dtype)
+    state[3] = tables[5] // 3
+    inject, target = _injected(fl, [1, 2], 6)
+    out = _compact_parity(fl, tables, state, inject, target, _EIGHT_SPANS,
+                          [1, 2], idle=5)
+    assert int(out[8]) > 0
+
+
+def test_compact_tick_loop_holds_nothing_table_wide():
+    """The compacted program's tick loop works on the live flows and their
+    nodes alone: no operand in its body is as long as the flow table or
+    the node table, and it scatters nothing."""
+    import re
+
+    import jax
+
+    from shadow_tpu.ops.torcells_device import _compact_step_span_flush_impl
+    n = 127
+    route = np.arange(25 * 5).reshape(25, 5)
+    fl, tables = _tor_table(route, n, np.full(n, 4), np.full(n, 9))
+    f = len(tables[0])
+    state = _zero_state(tables)
+    inject, target = _injected(fl, [3], 2)
+    text = jax.jit(_compact_step_span_flush_impl,
+                   static_argnames=("ring_len",)) \
+        .lower(*state, _live(fl, [3], 16, inject, target), _EIGHT_SPANS,
+               np.int64(0), *tables, fl["flow_pred"], ring_len=4).as_text()
+    body = _while_body(text)
+    assert "tensor<16x" in body
+    wide = re.findall(r"tensor<(?:\d+x)*(?:%d|%d)x" % (f, n), body)
+    assert not wide, wide[:3]
+    assert "scatter" not in body
+    assert re.search(r"tensor<(?:\d+x)*%dx" % f, text)   # outside it
+
+
+@pytest.mark.parametrize("m", [1, 4, 66, CELL_WIRE_BYTES, 65535])
+def test_floor_div_small_is_exact_over_int64(m):
+    """The tick's 32-bit long division by a small constant equals numpy's
+    int64 floor division across the whole range, negatives included."""
+    import jax
+
+    from shadow_tpu.ops.torcells_device import _floor_div_small, _rem_small
+    rng = np.random.default_rng(m)
+    x = np.concatenate([
+        rng.integers(-2 ** 63, 2 ** 63 - 1, 20_000, dtype=np.int64),
+        rng.integers(-10 ** 6, 10 ** 6, 20_000, dtype=np.int64),
+        np.array([0, 1, -1, m - 1, m, m + 1, -m, -m - 1, 2 ** 32 - 1,
+                  2 ** 32, 2 ** 63 - 1, -2 ** 63 + 1], np.int64)])
+    q = np.asarray(jax.jit(lambda v: _floor_div_small(v, m))(x))
+    np.testing.assert_array_equal(q, x // m)
+    small = x[np.abs(x) < 2 ** 62]
+    r = np.asarray(jax.jit(lambda v: _rem_small(v, m))(small))
+    np.testing.assert_array_equal(r, small % m)
+
+
+def test_flush_halves_round_trip():
+    """The flush's int32 halves give back every int64 word on the host."""
+    from shadow_tpu.ops.torcells_device import flush_from_halves, flush_halves
+    x = np.random.default_rng(3).integers(-2 ** 63, 2 ** 63 - 1, 1_001,
+                                          dtype=np.int64)
+    halves = np.asarray(flush_halves(x))
+    assert halves.dtype == np.int32 and halves.shape == (1_001, 2)
+    np.testing.assert_array_equal(flush_from_halves(halves), x)
+
+
+@pytest.mark.parametrize("n_flows,widths", [
+    (890_000, (4096, 65536)),
+    (100_000, (1024, 8192)),
+    (2_000, (1024,)),
+    (1_024, ()),
+])
+def test_compact_widths_follow_the_table(n_flows, widths):
+    from shadow_tpu.ops.torcells_device import compact_widths
+    assert compact_widths(n_flows) == widths
 
 
 @pytest.mark.parametrize("flow_node,flow_succ,why", [
