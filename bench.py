@@ -380,12 +380,11 @@ def _run_sim(xml, policy: str, workers: int, stop: int, **opt_kw) -> dict:
         out["superwindows"] = st["superwindows"]
         # autotune columns (ISSUE 16), fail-closed: the decision source is
         # "absent" unless the plane actually published one, and the launch
-        # rate / compaction savings come from the same scrape so a run
-        # where the tuner silently failed to engage reads as exactly that
+        # rate comes from the same run so one where the tuner silently
+        # failed to engage reads as exactly that
         out["autotune_source"] = scrape.get("prof.autotune_source", "absent")
         out["launches_per_sim_sec"] = round(
             st["dispatches"] / max(stop, 1), 2)
-        out["flush_bytes_saved"] = int(st.get("flush_bytes_saved", 0))
     # mesh columns (ISSUE 9): the mesh.* registry source is present iff
     # the flow table was sharded over >1 device.  prof.* (ISSUE 15):
     # per-launch predicted-vs-measured attribution + the model-stale
@@ -1473,7 +1472,6 @@ def bench_smoke() -> int:
     out["autotune_k"] = r_tune.get("prof.autotune_k")
     out["autotune_rounds_per_launch"] = r_tune.get("rounds_per_launch")
     out["launches_per_sim_sec"] = r_tune.get("launches_per_sim_sec")
-    out["flush_bytes_saved"] = r_tune.get("flush_bytes_saved")
     if out["autotune_source"] != "model":
         failures.append(
             f"autotune_source={out['autotune_source']!r}: the synthetic "

@@ -122,9 +122,8 @@ class Options:
                                          # kernel (digest parity pinned)
     device_autotune: str = "on"          # COSTMODEL-driven dispatch tuner
                                          # (prof/autotune.py): picks the
-                                         # effective superwindow depth and
-                                         # the delta-compacted flush from
-                                         # measured per-box costs; only
+                                         # effective superwindow depth
+                                         # from measured per-box costs; only
                                          # ever chooses between digest-
                                          # identical executions. "off" =
                                          # the hand defaults, untouched
@@ -320,8 +319,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--device-autotune", choices=("on", "off"),
                    default="on", dest="device_autotune",
                    help="COSTMODEL-driven dispatch auto-tuner: pick the "
-                        "effective superwindow depth and the delta-"
-                        "compacted flush from this box's measured costs "
+                        "effective superwindow depth from this box's "
+                        "measured costs "
                         "(prof/autotune.py; engages only with a loaded, "
                         "covering model and only moves knobs still at "
                         "their hand defaults — digests never change); "

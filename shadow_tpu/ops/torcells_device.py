@@ -6,7 +6,7 @@ circuits, streams over the userspace TCP stack).  This module is the
 device-resident counterpart for the dominant traffic term — bulk cell
 delivery server→exit→middle→guard→client across circuits that CONTEND for
 shared relay bandwidth — composing the three north-star kernels in one
-``lax.while_loop`` program:
+``lax.while_loop`` tick loop (_span_loop):
 
 * per-edge latency (cells in flight live in a [L, F] ring buffer indexed
   by arrival tick — the device analog of the delivery event queue);
@@ -17,12 +17,14 @@ shared relay bandwidth — composing the three north-star kernels in one
   receiving node at build time, so the per-tick allocation is one cumsum +
   two gathers, no sorting and no data-dependent shapes.
 
-Like ops/phold_device.py and ops/saturate_device.py, the numbers this
+The tick loop runs in one family of span-flush programs: the full-width
+step, the same step over a dispatch's live flows (compacted), and the
+fleet's vmapped step, each returning the packed flush.  The numbers this
 produces are honest about what they are: a model workload (no TCP control
 loop, no cell crypto) showing the architecture's throughput when the host
 is out of the per-event path.  Correctness gates: a bit-identical numpy
-twin and cell conservation (every injected cell is delivered exactly once)
-in tests/test_torcells_device.py.
+twin (torcells_step_span_numpy) and cell conservation (every injected
+cell is delivered exactly once) in tests/test_torcells_device.py.
 
 Shapes: C circuits × 5 stages = F flows.  Stage s of circuit c is paced by
 node route[c, s] (route = [server, exit, middle, guard, client]); a cell
@@ -32,7 +34,7 @@ node_{s+1}]; leaving stage 4 means delivered.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
@@ -48,9 +50,7 @@ CELL_WIRE_BYTES = 512 + defs.CONFIG_HEADER_SIZE_TCPIPETH
 # counts (bounded by bucket capacity / cell size — a 10 Gbit/s host at a
 # 100 ms granule is ~230k cells, nowhere near 2**31).  int32 halves the
 # [ring_len, F] state bytes, which is the fixed per-dispatch copy cost on
-# backends where the carried state cannot alias (PJRT CPU).  The kernels are
-# dtype-polymorphic over the ring argument, so int64 callers (older tests,
-# external users) keep working.
+# backends where the carried state cannot alias (PJRT CPU).
 RING_DTYPE = np.int32
 
 # Bound on the cells a plane may hold at once: segment_greedy's prefix
@@ -209,196 +209,13 @@ def segment_greedy_totals(queued, node_cap, flow_node, seg_start, node_seg):
     return served, jnp.minimum(jnp.maximum(node_cap, 0), seg_total)
 
 
-@partial(jax.jit, static_argnames=("ring_len",))
-def torcells_run(queued0: jnp.ndarray,     # int64 [F] initial cells/flow
-                 flow_node: jnp.ndarray,   # int64 [F] paced node
-                 flow_lat: jnp.ndarray,    # int64 [F] onward latency ticks
-                 flow_succ: jnp.ndarray,   # int64 [F] successor flow or -1
-                 seg_start: jnp.ndarray,   # int64 [F] node-segment start
-                 refill: jnp.ndarray,      # int64 [H] bytes per tick
-                 capacity: jnp.ndarray,    # int64 [H] bucket cap bytes
-                 ring_len: int,            # static: max latency + 1
-                 max_ticks: jnp.ndarray,   # int64 scalar
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run until every cell is delivered (or max_ticks).  Returns
-    (delivered[F] on last-stage flows, ticks_run, total_forwards)."""
-    f = queued0.shape[0]
-    h = refill.shape[0]
-    size = jnp.int64(CELL_WIRE_BYTES)
-    is_last = flow_succ < 0
-
-    # successor-space arrival latency: arr_lat[j] = onward latency of j's
-    # predecessor (succ is injective over chains, so scatter-add == set).
-    # Cells in flight live in a [L, F] HISTORY of per-step successor-space
-    # send vectors, consumed by a GATHER at hist[(t - arr_lat) mod L, j] —
-    # no full-buffer scatter per step.  (The previous formulation scattered
-    # into an arrival ring at computed (slot, succ) indices; XLA:CPU
-    # materializes a copy of the whole [L, F] operand per scatter, which
-    # was ~95% of the flagship device-plane's flush wall — VERDICT r4 weak
-    # #2.  The gather form writes one row per step via dynamic-update-slice,
-    # which aliases in place on every backend.)
-    arr_lat = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-        jnp.where(is_last, jnp.int64(0), flow_lat))
-    cols = jnp.arange(f)
-
-    def body(state):
-        t, queued, hist, tokens, delivered, forwards = state
-        # arrivals: my predecessor's sends from arr_lat steps ago (columns
-        # with no predecessor are never written, so they gather zeros)
-        arr = hist[jnp.mod(t - arr_lat, ring_len), cols]
-        queued = queued + arr
-        # refill buckets
-        tokens = jnp.minimum(capacity, tokens + refill)
-        cap_cells = tokens[flow_node] // size
-        served = segment_greedy(queued, cap_cells, seg_start)
-        queued = queued - served
-        spent = jax.ops.segment_sum(served * size, flow_node,
-                                    num_segments=h)
-        tokens = tokens - spent
-        # departures: last stage delivers, others arrive at successor after
-        # their edge latency
-        delivered = delivered + jnp.where(is_last, served, 0)
-        v = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-            jnp.where(is_last, jnp.int64(0), served))
-        hist = hist.at[jnp.mod(t, ring_len)].set(v)
-        forwards = forwards + jnp.sum(served)
-        return t + 1, queued, hist, tokens, delivered, forwards
-
-    total = jnp.sum(queued0)
-
-    def cond(state):
-        t, _queued, _ring, _tok, delivered, _f = state
-        # delivered-vs-total instead of summing the [L, F] ring each tick
-        return (jnp.sum(delivered) < total) & (t < max_ticks)
-
-    ring0 = jnp.zeros((ring_len, f), dtype=jnp.int64)
-    state = (jnp.int64(0), queued0, ring0, capacity.astype(jnp.int64),
-             jnp.zeros(f, dtype=jnp.int64), jnp.int64(0))
-    t, _q, _r, _tok, delivered, forwards = jax.lax.while_loop(
-        cond, body, state)
-    return delivered, t, forwards
-
-
-def _step_window_impl(t0: jnp.ndarray,         # int64 scalar: next tick
-                      queued: jnp.ndarray,     # int64 [F]
-                      ring: jnp.ndarray,       # int64 [L, F]
-                      tokens: jnp.ndarray,     # int64 [H]
-                      delivered: jnp.ndarray,  # int64 [F]
-                      target: jnp.ndarray,     # int64 [F] (last-stage rows)
-                      done_tick: jnp.ndarray,  # int64 [F], -1 = not done
-                      node_sent: jnp.ndarray,  # int64 [H] cumulative bytes
-                      inject: jnp.ndarray,     # int64 [F] new cells @ t0
-                      inject_target: jnp.ndarray,  # int64 [F] target adds
-                      n_ticks: jnp.ndarray,    # int64 scalar (dynamic)
-                      idle_ticks: jnp.ndarray,  # int64 scalar: skipped
-                                                # empty ticks to fold in
-                      flow_node: jnp.ndarray, flow_lat: jnp.ndarray,
-                      flow_succ: jnp.ndarray, seg_start: jnp.ndarray,
-                      refill: jnp.ndarray, capacity: jnp.ndarray,
-                      ring_len: int):
-    """Advance the cell model by EXACTLY n_ticks, carrying ALL state in HBM
-    across dispatches — the execution-plane variant of torcells_run (state
-    tensors are donated, so each round's dispatch updates in place; the host
-    only uploads the tiny inject vectors and downloads the small
-    delivered/done/node_sent summaries it needs for wakeups/trackers).
-
-    Per-tick math is IDENTICAL to torcells_run's body (pinned bit-for-bit by
-    tests/test_device_plane.py's windowed-vs-run parity case), plus:
-    * per-flow completion ticks (done_tick records the first tick a
-      last-stage flow's delivered count reached its target — the engine
-      turns these into deterministic wake events);
-    * per-node cumulative sent bytes (tracker/heartbeat feed).
-
-    The caller chooses what a "tick" means: DeviceTrafficPlane passes
-    refill/capacity/latencies pre-scaled to coarse steps (its ``granule``),
-    so one loop iteration covers several milliseconds — that keeps BOTH the
-    [ring_len, F] arrival ring small on multi-second-latency topologies and
-    the sequential step count low (the per-step ring update walks the whole
-    ring buffer, so state bytes x steps is the real cost on every backend).
-
-    Returns the updated state tuple plus total forwards this window."""
-    f = queued.shape[0]
-    h = refill.shape[0]
-    size = jnp.int64(CELL_WIRE_BYTES)
-    is_last = flow_succ < 0
-    queued = queued + inject
-    target = target + inject_target
-    # fold skipped idle ticks (the plane had no cells anywhere, so the only
-    # state evolution was bucket refill — exact because refill is capped).
-    # The send history must be cleared across an idle jump: banking requires
-    # every cell delivered, so all past sends were consumed — but a jumped t
-    # would otherwise re-read stale rows on wrap (lax.cond: the zeroing pass
-    # only runs when ticks were actually banked).
-    tokens = jnp.minimum(capacity, tokens + refill * idle_ticks)
-    ring = jax.lax.cond(idle_ticks > 0,
-                        lambda hh: jnp.zeros_like(hh),
-                        lambda hh: hh, ring)
-    # successor-space arrival latency (see torcells_run): hist rows are
-    # per-step send vectors; arrivals are a gather, the only write is one
-    # row DUS — nothing scatters into the big buffer
-    arr_lat = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-        jnp.where(is_last, jnp.int64(0), flow_lat))
-    cols = jnp.arange(f)
-
-    def body(state):
-        t, queued, hist, tokens, delivered, target, done_tick, node_sent, \
-            forwards = state
-        arr = hist[jnp.mod(t - arr_lat, ring_len), cols]
-        queued = queued + arr
-        tokens = jnp.minimum(capacity, tokens + refill)
-        cap_cells = tokens[flow_node] // size
-        served = segment_greedy(queued, cap_cells, seg_start)
-        queued = queued - served
-        spent = jax.ops.segment_sum(served * size, flow_node,
-                                    num_segments=h)
-        tokens = tokens - spent
-        node_sent = node_sent + spent
-        delivered = delivered + jnp.where(is_last, served, 0)
-        newly_done = (is_last & (target > 0) & (done_tick < 0)
-                      & (delivered >= target))
-        done_tick = jnp.where(newly_done, t, done_tick)
-        v = jnp.zeros(f, jnp.int64).at[jnp.maximum(flow_succ, 0)].add(
-            jnp.where(is_last, jnp.int64(0), served))
-        # cast to the carried ring dtype: DeviceTrafficPlane keeps the ring
-        # int32 (RING_DTYPE) — per-step per-flow cell counts are bounded by
-        # bucket capacity / cell size, far below 2**31 — which halves the
-        # per-dispatch state-copy bytes, the fixed cost of every dispatch
-        hist = hist.at[jnp.mod(t, ring_len)].set(v.astype(hist.dtype))
-        forwards = forwards + jnp.sum(served)
-        return (t + 1, queued, hist, tokens, delivered, target, done_tick,
-                node_sent, forwards)
-
-    end = t0 + n_ticks
-
-    def cond(state):
-        return state[0] < end
-
-    state = (t0, queued, ring, tokens, delivered, target, done_tick,
-             node_sent, jnp.int64(0))
-    return jax.lax.while_loop(cond, body, state)
-
-
-@partial(jax.jit, static_argnames=("ring_len",),
-         donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
-def torcells_step_window(t0, queued, ring, tokens, delivered, target,
-                         done_tick, node_sent, inject, inject_target,
-                         n_ticks, idle_ticks, flow_node, flow_lat,
-                         flow_succ, seg_start, refill, capacity,
-                         ring_len: int):
-    """The jitted windowed step (see _step_window_impl for the contract)."""
-    return _step_window_impl(t0, queued, ring, tokens, delivered, target,
-                             done_tick, node_sent, inject, inject_target,
-                             n_ticks, idle_ticks, flow_node, flow_lat,
-                             flow_succ, seg_start, refill, capacity,
-                             ring_len)
-
-
 # ---------------------------------------------------------------------------
 # Packed flush buffer: the dispatch's ENTIRE host-facing summary in one
 # int64 vector, so collect is ONE device->host transfer instead of four
-# (delivered + done_tick + node_sent + forwards).  Delta-compacted with a
-# device-side cursor: only chains that completed THIS window and only nodes
-# whose sent-byte counter moved occupy slots; the header carries the counts.
+# (delivered + done_tick + node_sent + forwards).  A device-side cursor
+# packs each section to its front: only chains that completed THIS window
+# and only nodes whose sent-byte counter moved occupy slots, and the header
+# carries how many.
 #
 # Layout ([6 + 2C + 2H] int64, C = chains, H = nodes):
 #   [0] forwards this window
@@ -423,41 +240,27 @@ def torcells_step_window(t0, queued, ring, tokens, delivered, target,
 FLUSH_HEADER = 6
 
 
-def flush_len(n_chains: int, n_nodes: int,
-              cap_chains: Optional[int] = None,
-              cap_nodes: Optional[int] = None) -> int:
-    """Packed flush buffer length.  With caps (ISSUE 16 delta-compacted
-    flush) the chain/node sections carry at most ``cap_chains``/
-    ``cap_nodes`` entries — the header counts stay TRUE, so an
-    overflowing window is detectable (flush_overflowed) and re-read
-    through the full-length kernel."""
-    c = n_chains if cap_chains is None else min(cap_chains, n_chains)
-    h = n_nodes if cap_nodes is None else min(cap_nodes, n_nodes)
-    return FLUSH_HEADER + 2 * c + 2 * h
+def flush_len(n_chains: int, n_nodes: int) -> int:
+    """Packed flush buffer length."""
+    return FLUSH_HEADER + 2 * n_chains + 2 * n_nodes
 
 
 def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
-                    sent_delta, cap_chains: Optional[int] = None,
-                    cap_nodes: Optional[int] = None, moved=0):
+                    sent_delta, moved=0):
     """newly bool [C], done_last int64 [C], sent_delta int64 [H] -> packed
     buffer.  Compaction is a cumsum-cursor scatter; out-of-range slots (the
-    unselected lanes) are dropped on device.  With caps the buffer is the
-    CAPPED length and entries past a cap are dropped — the header still
-    carries the true counts, so the host can tell a capped buffer lost
-    entries and fall back to the full-length kernel (delta-compacted
-    flush, ISSUE 16: quiet lanes stop costing readback bytes)."""
+    unselected lanes) are dropped on device."""
     c = newly.shape[0]
     h = sent_delta.shape[0]
-    cc = c if cap_chains is None else min(int(cap_chains), c)
-    hh = h if cap_nodes is None else min(int(cap_nodes), h)
-    length = flush_len(c, h, cap_chains, cap_nodes)
+    length = flush_len(c, h)
     touched = sent_delta != 0
     # int32 cursors: a count of chains or nodes is far below 2**31
     pos_c = jnp.cumsum(newly.astype(jnp.int32)) - 1
     pos_h = jnp.cumsum(touched.astype(jnp.int32)) - 1
     oob = jnp.int64(length)
-    sel_c = newly & (pos_c < cc)
-    sel_h = touched & (pos_h < hh)
+    # a slot past its section's end is dropped, never written into the next
+    sel_c = newly & (pos_c < c)
+    sel_h = touched & (pos_h < h)
     buf = jnp.zeros(length, jnp.int64)
     buf = buf.at[0].set(forwards)
     buf = buf.at[1].set(delivered_sum)
@@ -468,11 +271,11 @@ def _pack_flush_jnp(forwards, delivered_sum, t_stop, newly, done_last,
     base = jnp.int64(FLUSH_HEADER)
     buf = buf.at[jnp.where(sel_c, base + pos_c, oob)].set(
         jnp.arange(c, dtype=jnp.int64), mode="drop")
-    buf = buf.at[jnp.where(sel_c, base + cc + pos_c, oob)].set(
+    buf = buf.at[jnp.where(sel_c, base + c + pos_c, oob)].set(
         done_last, mode="drop")
-    buf = buf.at[jnp.where(sel_h, base + 2 * cc + pos_h, oob)].set(
+    buf = buf.at[jnp.where(sel_h, base + 2 * c + pos_h, oob)].set(
         jnp.arange(h, dtype=jnp.int64), mode="drop")
-    buf = buf.at[jnp.where(sel_h, base + 2 * cc + hh + pos_h, oob)].set(
+    buf = buf.at[jnp.where(sel_h, base + 2 * c + h + pos_h, oob)].set(
         sent_delta, mode="drop")
     return buf
 
@@ -520,34 +323,18 @@ def flush_moved(buf: np.ndarray) -> int:
     return int(buf[5])
 
 
-def flush_overflowed(buf: np.ndarray, cap_chains: int,
-                     cap_nodes: int) -> bool:
-    """True when a CAPPED flush buffer lost entries: the header carries the
-    true per-window counts, so overflow is one comparison — the caller then
-    re-runs the same inputs through the full-length kernel (legal on the
-    non-donating CPU path, where the inputs are still alive)."""
-    return int(buf[2]) > int(cap_chains) or int(buf[3]) > int(cap_nodes)
-
-
-def parse_flush(buf: np.ndarray, n_chains: int, n_nodes: int,
-                cap_chains: Optional[int] = None,
-                cap_nodes: Optional[int] = None):
+def parse_flush(buf: np.ndarray, n_chains: int, n_nodes: int):
     """(forwards, delivered_sum, t_stop, done_chains, done_steps, node_idx,
-    node_delta) from a packed flush buffer — the ONE host-side reader.
-    Pass the caps the buffer was packed with (if any); callers must check
-    flush_overflowed FIRST — parsing an overflowed capped buffer would
-    silently drop completions/deltas."""
-    cc = n_chains if cap_chains is None else min(int(cap_chains), n_chains)
-    hh = n_nodes if cap_nodes is None else min(int(cap_nodes), n_nodes)
+    node_delta) from a packed flush buffer — the ONE host-side reader."""
+    c, h = n_chains, n_nodes
     base = FLUSH_HEADER
-    n_done = min(int(buf[2]), cc)
-    n_touch = min(int(buf[3]), hh)
+    n_done = int(buf[2])
+    n_touch = int(buf[3])
     return (int(buf[0]), int(buf[1]), int(buf[4]),
             buf[base:base + n_done],
-            buf[base + cc:base + cc + n_done],
-            buf[base + 2 * cc:base + 2 * cc + n_touch],
-            buf[base + 2 * cc + hh:
-                base + 2 * cc + hh + n_touch])
+            buf[base + c:base + c + n_done],
+            buf[base + 2 * c:base + 2 * c + n_touch],
+            buf[base + 2 * c + h:base + 2 * c + h + n_touch])
 
 
 def _span_loop(t0, targets, state, tables, ring_len: int):
@@ -644,9 +431,10 @@ def _step_span_impl(t0, queued, ring, tokens, delivered, target,
     exactly to that round — so the kernel refuses to run past it.  The
     reached boundary comes back in the flush header (t_stop), one transfer.
 
-    Per-tick math is the _step_window_impl body's (pinned by
-    tests/test_superwindow.py's span-vs-sequential-windows parity case),
-    formed without a scatter: ``flow_pred`` and ``node_seg``
+    The tick itself is _span_loop's, pinned bit for bit to the numpy
+    twin torcells_step_span_numpy (tests/test_superwindow.py's
+    span-vs-sequential-windows parity case), and formed without a
+    scatter: ``flow_pred`` and ``node_seg``
     (gather_tables) turn the successor send and the per-node byte total
     into gathers.  A scatter-add's updates run one after another on TPU
     (~114 ms a tick for 890k flows on v5e); a gather of the same length
@@ -760,9 +548,7 @@ def _compact_step_span_impl(t0, queued, ring, tokens, delivered, target,
             node_sent.at[unode].set(sent_u, mode="drop"), forwards, moved)
 
 
-def _with_flush(out, done_in_last, node_sent_in, last_flow,
-                cap_chains: Optional[int] = None,
-                cap_nodes: Optional[int] = None):
+def _with_flush(out, done_in_last, node_sent_in, last_flow):
     """A span step's 10-tuple as the flush programs return it: the 9-tuple
     with the packed flush buffer appended as [9] (its moved count rides in
     the flush header).  ``done_in_last`` and ``node_sent_in`` are the
@@ -772,7 +558,7 @@ def _with_flush(out, done_in_last, node_sent_in, last_flow,
     newly = (done_last >= 0) & (done_in_last < 0)
     flush = _pack_flush_jnp(out[8], jnp.sum(out[4][last_flow]), out[0],
                             newly, done_last, out[7] - node_sent_in,
-                            cap_chains, cap_nodes, moved=out[9])
+                            moved=out[9])
     return (*out[:9], flush)
 
 
@@ -780,22 +566,17 @@ def _step_span_flush_impl(t0, queued, ring, tokens, delivered, target,
                           done_tick, node_sent, inject, inject_target,
                           targets, idle_ticks, flow_node, flow_lat,
                           flow_succ, seg_start, refill, capacity,
-                          last_flow, flow_pred, node_seg, ring_len: int,
-                          cap_chains: Optional[int] = None,
-                          cap_nodes: Optional[int] = None):
+                          last_flow, flow_pred, node_seg, ring_len: int):
     """Superwindow step + packed flush in ONE dispatch: the 9-tuple of
     _step_span_impl with the packed flush buffer appended as [9].
     ``last_flow`` [C] maps each chain to its exit flow row;
-    ``flow_pred`` and ``node_seg`` come from gather_tables.  With caps
-    the flush is the capped (delta-compacted) buffer — see
-    _pack_flush_jnp."""
+    ``flow_pred`` and ``node_seg`` come from gather_tables."""
     out = _step_span_impl(t0, queued, ring, tokens, delivered, target,
                           done_tick, node_sent, inject, inject_target,
                           targets, idle_ticks, flow_node, flow_lat,
                           flow_succ, seg_start, refill, capacity,
                           flow_pred, node_seg, ring_len)
-    return _with_flush(out, done_tick[last_flow], node_sent, last_flow,
-                       cap_chains, cap_nodes)
+    return _with_flush(out, done_tick[last_flow], node_sent, last_flow)
 
 
 def _compact_step_span_flush_impl(t0, queued, ring, tokens, delivered,
@@ -827,17 +608,6 @@ torcells_step_window_flush = partial(
 
 torcells_step_window_flush_nodonate = partial(
     jax.jit, static_argnames=("ring_len",))(_step_span_flush_impl)
-
-# Delta-compacted flush variant (ISSUE 16): same program with the flush
-# buffer capped to the tuned lane counts.  Non-donating ONLY — overflow
-# recovery re-runs the same inputs through the full-length kernel, which
-# requires the carried state to still be alive after the launch; that is
-# exactly the property the CPU dispatch path already has (see above), and
-# device_plane only engages caps on that path.
-torcells_step_window_flush_capped = partial(
-    jax.jit, static_argnames=("ring_len", "cap_chains", "cap_nodes"))(
-        _step_span_flush_impl)
-
 
 # The compacted flush program over a dispatch's live flows, in the same
 # two jit wrappers; each width of ``live`` is a program of its own.
@@ -1017,57 +787,6 @@ def torcells_step_window_numpy_flush(t0, queued, ring, tokens, delivered,
     return (*out[:9], flush)
 
 
-def torcells_step_window_numpy(t0, queued, ring, tokens, delivered, target,
-                               done_tick, node_sent, inject, inject_target,
-                               n_ticks, idle_ticks, flow_node, flow_lat,
-                               flow_succ, seg_start, refill, capacity,
-                               ring_len: int):
-    """Bit-identical host twin of torcells_step_window (same rule, same
-    ring, same completion/byte accounting) — the parity gate's oracle and
-    the --device-plane=numpy execution mode."""
-    f = len(queued)
-    h = len(refill)
-    size = CELL_WIRE_BYTES
-    is_last = flow_succ < 0
-    queued = queued + inject
-    target = target + inject_target
-    tokens = np.minimum(capacity, tokens + refill * int(idle_ticks))
-    if int(idle_ticks) > 0:
-        ring = np.zeros_like(ring)   # idle jump: stale send history cleared
-    arr_lat = np.zeros(f, dtype=np.int64)
-    np.add.at(arr_lat, np.maximum(flow_succ, 0),
-              np.where(is_last, 0, flow_lat))
-    cols = np.arange(f)
-    forwards = 0
-    t = int(t0)
-    for _ in range(int(n_ticks)):
-        arr = ring[(t - arr_lat) % ring_len, cols]
-        queued = queued + arr
-        tokens = np.minimum(capacity, tokens + refill)
-        cap_cells = tokens[flow_node] // size
-        csum = np.cumsum(queued)
-        seg_base = np.where(seg_start > 0, csum[np.maximum(seg_start - 1, 0)],
-                            0) * (seg_start > 0)
-        before = csum - queued - seg_base
-        served = np.clip(cap_cells - before, 0, queued)
-        queued = queued - served
-        spent = np.bincount(flow_node, weights=served * size,
-                            minlength=h).astype(np.int64)
-        tokens = tokens - spent
-        node_sent = node_sent + spent
-        delivered = delivered + np.where(is_last, served, 0)
-        newly_done = (is_last & (target > 0) & (done_tick < 0)
-                      & (delivered >= target))
-        done_tick = np.where(newly_done, t, done_tick)
-        v = np.zeros(f, dtype=np.int64)
-        np.add.at(v, np.maximum(flow_succ, 0), np.where(is_last, 0, served))
-        ring[t % ring_len] = v
-        forwards += int(served.sum())
-        t += 1
-    return (np.int64(t), queued, ring, tokens, delivered, target, done_tick,
-            node_sent, np.int64(forwards))
-
-
 # ---------------------------------------------------------------------------
 # Multi-chip execution plane: the flow table sharded over a device mesh
 # lives in shadow_tpu/parallel/mesh/ (partition.py chain partitioner +
@@ -1079,45 +798,11 @@ def torcells_step_window_numpy(t0, queued, ring, tokens, delivered, target,
 # ---------------------------------------------------------------------------
 
 
-def torcells_run_numpy(queued0, flow_node, flow_lat, flow_succ, seg_start,
-                       refill, capacity, ring_len: int, max_ticks: int):
-    """Bit-identical host twin (same allocation rule, same ring)."""
-    f = len(queued0)
-    h = len(refill)
-    size = CELL_WIRE_BYTES
-    is_last = flow_succ < 0
-    queued = queued0.astype(np.int64).copy()
-    ring = np.zeros((ring_len, f), dtype=np.int64)
-    tokens = capacity.astype(np.int64).copy()
-    delivered = np.zeros(f, dtype=np.int64)
-    arr_lat = np.zeros(f, dtype=np.int64)
-    np.add.at(arr_lat, np.maximum(flow_succ, 0),
-              np.where(is_last, 0, flow_lat))
-    cols = np.arange(f)
-    forwards = 0
-    t = 0
-    total = int(queued0.sum())
-    while delivered.sum() < total and t < max_ticks:
-        arr = ring[(t - arr_lat) % ring_len, cols]
-        queued += arr
-        tokens = np.minimum(capacity, tokens + refill)
-        cap_cells = tokens[flow_node] // size
-        csum = np.cumsum(queued)
-        seg_base = np.where(seg_start > 0, csum[np.maximum(seg_start - 1, 0)],
-                            0) * (seg_start > 0)
-        before = csum - queued - seg_base
-        served = np.clip(cap_cells - before, 0, queued)
-        queued -= served
-        spent = np.bincount(flow_node, weights=served * size,
-                            minlength=h).astype(np.int64)
-        tokens -= spent
-        delivered += np.where(is_last, served, 0)
-        v = np.zeros(f, dtype=np.int64)
-        np.add.at(v, np.maximum(flow_succ, 0), np.where(is_last, 0, served))
-        ring[t % ring_len] = v
-        forwards += int(served.sum())
-        t += 1
-    return delivered, t, forwards
+# DeviceTorCells' run to completion: each dispatch covers RUN_SPANS
+# sub-windows of RUN_SPAN_TICKS ticks, and halts early at the end of one in
+# which a chain completed.
+RUN_SPAN_TICKS = 64
+RUN_SPANS = 8
 
 
 class DeviceTorCells:
@@ -1147,35 +832,64 @@ class DeviceTorCells:
         picks = rng.random((n_circuits, n_relays)).argsort(axis=1)[:, :3]
         route[:, 1:4] = n_clients + picks                         # e, m, g
         self.flows = build_flows(route, lat)
+        self.last_flow = np.flatnonzero(self.flows["flow_succ"] < 0)
         self.ring_len = int(max_latency_ms) + 2
         self.n_flows = n_circuits * 5
         self.route = route
 
-    def _args(self, cells_per_circuit: int):
+    def _run(self, step, tables, cells_per_circuit: int, max_ticks: int):
+        """Inject ``cells_per_circuit`` at every chain's entry flow, the
+        same count as its target, and dispatch ``step`` (a span-flush
+        program or its numpy twin, with ``tables`` after the per-dispatch
+        operands) over RUN_SPANS sub-windows of RUN_SPAN_TICKS ticks at a
+        time until every chain is done or ``max_ticks``.  Returns
+        (delivered [F], ticks, forwards), ``ticks`` one past the last
+        chain's completion tick, or ``max_ticks`` when one never
+        completed."""
         fl = self.flows
-        queued0 = np.where(fl["flow_stage"] == 0, cells_per_circuit, 0) \
+        f, h = self.n_flows, len(self.refill)
+        last = self.last_flow
+        inject = np.where(fl["flow_stage"] == 0, cells_per_circuit, 0) \
             .astype(np.int64)
-        return queued0, fl
+        target = np.where(fl["flow_succ"] < 0, cells_per_circuit, 0) \
+            .astype(np.int64)
+        zeros = np.zeros(f, np.int64)
+        state = (np.int64(0), zeros,
+                 np.zeros((self.ring_len, f), RING_DTYPE),
+                 self.capacity.copy(), zeros, zeros,
+                 np.full(f, -1, np.int64), np.zeros(h, np.int64))
+        spans = RUN_SPAN_TICKS * np.arange(1, RUN_SPANS + 1, dtype=np.int64)
+        t = forwards = n_done = 0
+        while n_done < len(last) and t < max_ticks:
+            out = step(*state, inject, target,
+                       np.minimum(t + spans, max_ticks), np.int64(0),
+                       *tables)
+            state = out[:8]
+            inject = target = zeros
+            fwd, _, t, _, steps, _, _ = parse_flush(np.asarray(out[9]),
+                                                    len(last), h)
+            forwards += fwd
+            n_done += len(steps)
+        ticks = max_ticks
+        if n_done == len(last):
+            ticks = int(np.asarray(state[6])[last].max()) + 1
+        return np.asarray(state[4]), ticks, forwards
+
+    def _tables(self):
+        fl = self.flows
+        return (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
+                fl["seg_start"], self.refill, self.capacity, self.last_flow)
 
     def run_device(self, cells_per_circuit: int, max_ticks: int):
-        queued0, fl = self._args(cells_per_circuit)
-        out = torcells_run(jnp.asarray(queued0),
-                           jnp.asarray(fl["flow_node"]),
-                           jnp.asarray(fl["flow_lat"]),
-                           jnp.asarray(fl["flow_succ"]),
-                           jnp.asarray(fl["seg_start"]),
-                           jnp.asarray(self.refill),
-                           jnp.asarray(self.capacity),
-                           self.ring_len, jnp.int64(max_ticks))
-        jax.block_until_ready(out)
-        delivered, ticks, forwards = (np.asarray(o) for o in out)
-        return delivered, int(ticks), int(forwards)
+        """Run to completion on the device's span-flush program."""
+        tables = tuple(jnp.asarray(a) for a in (
+            *self._tables(), self.flows["flow_pred"], self.flows["node_seg"]))
+        return self._run(partial(torcells_step_window_flush_nodonate,
+                                 ring_len=self.ring_len),
+                         tables, cells_per_circuit, max_ticks)
 
     def run_numpy(self, cells_per_circuit: int, max_ticks: int):
-        queued0, fl = self._args(cells_per_circuit)
-        d, t, fw = torcells_run_numpy(queued0, fl["flow_node"],
-                                      fl["flow_lat"], fl["flow_succ"],
-                                      fl["seg_start"], self.refill,
-                                      self.capacity, self.ring_len,
-                                      max_ticks)
-        return d, t, fw
+        """run_device on the numpy twin, bit for bit."""
+        return self._run(torcells_step_window_numpy_flush,
+                         (*self._tables(), self.ring_len),
+                         cells_per_circuit, max_ticks)

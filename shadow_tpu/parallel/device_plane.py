@@ -376,35 +376,19 @@ class DeviceTrafficPlane:
         self._build_layout(engine)
         # COSTMODEL auto-tuner (ISSUE 16, prof/autotune.py): with a
         # loaded model covering this flow table, pick the effective
-        # superwindow depth and the delta-compacted flush from measured
-        # costs.  Digest-NEUTRAL by construction: K only merges rounds
-        # the halt rule maps back exactly, and the capped flush is a
-        # transport encoding (overflow re-reads full-length).  Cadence
-        # and granule are digest-BEARING and stay at contract values.
+        # superwindow depth from measured costs.  Digest-NEUTRAL by
+        # construction: K only merges rounds the halt rule maps back
+        # exactly.  Cadence and granule are digest-BEARING and stay at
+        # contract values.
         from ..prof.autotune import plan_dispatch
         self._tune_plan = plan_dispatch(
             self._costmodel, self._costmodel_status, engine.options,
-            self.n_flows, self.n_chains, self.n_nodes)
-        self._flush_caps = None      # (cap_chains, cap_nodes) when engaged
-        self._inflight_caps = None   # caps the IN-FLIGHT dispatch packed with
-        self._inflight_args = None   # its inputs (overflow re-run, nodonate)
-        self.flush_bytes_saved = 0
-        self.flush_overflows = 0
+            self.n_flows)
         if self._tune_plan.source == "model":
             self.superwindow_rounds = self._tune_plan.superwindow_rounds
             if self.superwindow_rounds > getattr(engine, "_superwindow", 1):
                 engine._superwindow = self.superwindow_rounds
-            if self._tune_plan.flush_compact and mode == "device":
-                import jax
-                if jax.default_backend() == "cpu":
-                    # overflow recovery re-runs the SAME inputs through
-                    # the full-length kernel, which needs them alive
-                    # after the launch — exactly the non-donating CPU
-                    # dispatch path's property.  Donating backends keep
-                    # the full flush.
-                    self._flush_caps = (self._tune_plan.flush_cap_chains,
-                                        self._tune_plan.flush_cap_nodes)
-        engine.metrics.source("autotune", self._autotune_metrics)
+        engine.metrics.source("autotune", self._tune_plan.metrics)
         # quiet-tick exchange-leg fusion (ISSUE 16): set by attach_mesh —
         # per-chain leg bitmasks; dispatch picks a variant kernel with
         # the quiet legs compiled out (superset masks are bit-identical)
@@ -422,10 +406,6 @@ class DeviceTrafficPlane:
                 import jax
                 n_dev = len(jax.devices())
             if n_dev > 1:
-                # the mesh path's launch cut is the exchange-leg mask;
-                # flush compaction stays single-device (the overflow
-                # re-run would need a per-variant full kernel here)
-                self._flush_caps = None
                 self._setup_sharding(n_dev)
         self._state = None           # lazy: built at first activation
         # processless flows (scale tier): (start_ns, circuit) ascending;
@@ -554,13 +534,10 @@ class DeviceTrafficPlane:
         # parked lane at once, the lane unpads this plane's row).  The
         # lane path is synchronous (the digest-pinned --device-plane-sync
         # shape) and single-device only — sharded meshes keep their own
-        # program.  Flush caps stay off: the lane's flush section is
-        # always full-length (repacked host-side), so the capped variant
-        # would only add an overflow path the batch cannot re-run.
+        # program.
         self._lane = None
         lane = getattr(engine.options, "_fleet_lane", None)
         if lane is not None and mode == "device" and self._shard is None:
-            self._flush_caps = None
             self._lane = lane
             lane.attach_plane(self)
             from ..obs.metrics import fleet_source
@@ -931,10 +908,9 @@ class DeviceTrafficPlane:
     def _compact_width(self) -> int:
         """The compacted width this dispatch runs: the smallest that holds
         the live flows, or 0 for the full-width program (past the largest
-        width, on the numpy twin, a mesh, a fleet lane or a capped
-        flush)."""
+        width, on the numpy twin, a mesh or a fleet lane)."""
         if (self.mode != "device" or self._shard is not None
-                or self._lane is not None or self._flush_caps is not None):
+                or self._lane is not None):
             return 0
         return next((w for w in self._compact_widths
                      if w >= self._live_flows), 0)
@@ -1075,28 +1051,13 @@ class DeviceTrafficPlane:
                     jnp.zeros(f, jnp.int64), jnp.zeros(f, jnp.int64),
                     jnp.full(f, -1, jnp.int64), jnp.zeros(h, jnp.int64))
 
-        state = fresh()
         out = self._flush_step(
-            *state, z, z, self._pad_targets([1]), np.int64(0),
+            *fresh(), z, z, self._pad_targets([1]), np.int64(0),
             self.flow_node, self.flow_lat_steps, self.flow_succ,
             self.seg_start, self.refill_step, self.capacity_step,
             self.last_flow, self.flow_pred, self.node_seg,
             ring_len=self.ring_len)
         jax.block_until_ready(flush_halves(out[9]))        # its readback
-        if self._flush_caps is not None:
-            # the tuned dispatch runs the CAPPED flush kernel — compile
-            # it here too so the first timed dispatch pays no XLA wall
-            from ..ops.torcells_device import torcells_step_window_flush_capped
-            cc, hh = self._flush_caps
-            out = torcells_step_window_flush_capped(
-                *state, z, z, self._pad_targets([1]), np.int64(0),
-                self.flow_node, self.flow_lat_steps, self.flow_succ,
-                self.seg_start, self.refill_step, self.capacity_step,
-                self.last_flow, self.flow_pred, self.node_seg,
-                ring_len=self.ring_len,
-                cap_chains=cc, cap_nodes=hh)
-            jax.block_until_ready(out)
-            return
         # every compacted width a dispatch may pick (_compact_width), with
         # a live table of padding alone
         self._compact_step = compact_flush_for_backend()
@@ -1349,27 +1310,10 @@ class DeviceTrafficPlane:
                 from ..ops.torcells_device import (
                     step_window_flush_for_backend)
                 self._flush_step = step_window_flush_for_backend()
-            if self._flush_caps is not None:
-                # delta-compacted flush (tuner decision): pack only the
-                # capped lane counts; stash the inputs so an overflowing
-                # window (true counts in the header exceed the caps) can
-                # re-run full-length at consume — legal because this
-                # path is non-donating, so the inputs stay alive
-                from ..ops.torcells_device import (
-                    torcells_step_window_flush_capped)
-                cc, hh = self._flush_caps
-                out = torcells_step_window_flush_capped(
-                    *state, inject, inject_target, tvec, np.int64(idle),
-                    *self._flow_args(), ring_len=self.ring_len,
-                    cap_chains=cc, cap_nodes=hh)
-                self._inflight_caps = (cc, hh)
-                self._inflight_args = (state, inject, inject_target,
-                                       tvec, np.int64(idle))
-            else:
-                out = self._flush_step(*state, inject, inject_target,
-                                       tvec, np.int64(idle),
-                                       *self._flow_args(),
-                                       ring_len=self.ring_len)
+            out = self._flush_step(*state, inject, inject_target,
+                                   tvec, np.int64(idle),
+                                   *self._flow_args(),
+                                   ring_len=self.ring_len)
         else:
             from ..ops.torcells_device import torcells_step_window_numpy_flush
             out = torcells_step_window_numpy_flush(*state, inject,
@@ -1495,33 +1439,9 @@ class DeviceTrafficPlane:
         flows."""
         if self.mode == "device":
             self.device_calls += 1              # the flush read
-        from ..ops.torcells_device import (flush_len, flush_moved,
-                                           flush_overflowed, parse_flush)
-        caps, self._inflight_caps = self._inflight_caps, None
-        args, self._inflight_args = self._inflight_args, None
-        if caps is not None and self.mode != "device":
-            caps = None     # recovered on the twin: flush is full-length
-        if caps is not None:
-            if flush_overflowed(flush, *caps):
-                # a busy window outran the tuned caps: re-run the SAME
-                # inputs through the full-length kernel (bit-identical
-                # state math — only the flush encoding differs) and read
-                # the complete buffer.  Persistent overflow means the
-                # caps are mis-sized for this phase: stop paying the
-                # re-runs and revert to full flushes for the rest of
-                # the run.
-                flush = self._rerun_full_flush(args)
-                self.flush_overflows += 1
-                caps = None
-                if self.flush_overflows >= 8:
-                    self._flush_caps = None
-            else:
-                self.flush_bytes_saved += 8 * (
-                    flush_len(self.n_chains, self.n_nodes)
-                    - flush_len(self.n_chains, self.n_nodes, *caps))
+        from ..ops.torcells_device import flush_moved, parse_flush
         (forwards, delivered_sum, t_stop, done_chains, done_steps, node_idx,
-         node_delta) = parse_flush(flush, self.n_chains, self.n_nodes,
-                                   *(caps or (None, None)))
+         node_delta) = parse_flush(flush, self.n_chains, self.n_nodes)
         steps_done = max(int(t_stop) - self._launch_base, 0)
         self.ticks_stepped += steps_done
         self.flow_ticks_moved += flush_moved(flush)
@@ -1671,8 +1591,7 @@ class DeviceTrafficPlane:
                 block()
         t_read = _wt.perf_counter_ns()
         with tracer.annotate("plane.readback"):
-            if block is not None and self._shard is None \
-                    and self._flush_caps is None:
+            if block is not None and self._shard is None:
                 from ..ops.torcells_device import (flush_from_halves,
                                                    flush_halves)
                 return flush_from_halves(np.asarray(flush_halves(handle))), \
@@ -1731,25 +1650,6 @@ class DeviceTrafficPlane:
         engine.supervision.overhead_ns += _wt.perf_counter_ns() - t_g
         return out
 
-    def _rerun_full_flush(self, args) -> np.ndarray:
-        """Overflow recovery for the delta-compacted flush: the capped
-        buffer's TRUE header counts exceeded its caps, so some
-        completions/node deltas were dropped from the ENCODING (never from
-        the state — the capped and full kernels run byte-identical tick
-        math).  Re-run the stashed inputs through the full-length kernel
-        and read its complete flush.  Only reachable on the non-donating
-        path, where the inputs survived the capped launch."""
-        assert args is not None, "flush overflow with no stashed inputs"
-        state, inject, inject_target, tvec, idle = args
-        if self._flush_step is None:
-            from ..ops.torcells_device import step_window_flush_for_backend
-            self._flush_step = step_window_flush_for_backend()
-        out = self._flush_step(*state, inject, inject_target, tvec, idle,
-                               *self._flow_args(), ring_len=self.ring_len)
-        self.device_calls += 1          # the recovery dispatch + read
-        # simjit: disable=SIM302 -- designed collect: overflow recovery exists to READ the complete flush; the window is already lost
-        return np.asarray(out[9])
-
     def _pick_sharded_step(self):
         """The sharded kernel variant for this dispatch (quiet-tick
         exchange-leg fusion): when the active chains touch only a subset
@@ -1786,18 +1686,6 @@ class DeviceTrafficPlane:
             self._meshinfo.legs_active = bin(bits).count("1")
         return step
 
-    def _autotune_metrics(self) -> Dict[str, object]:
-        """The ``prof.autotune_*`` registry source: the tuner's decision
-        plus its runtime outcomes.  flush_compact reports the caps
-        actually ENGAGED (the plan's choice can be overridden by the
-        backend gate, the mesh path, or the persistent-overflow
-        revert)."""
-        m = self._tune_plan.metrics()
-        m["prof.autotune_flush_compact"] = int(self._flush_caps is not None)
-        m["prof.flush_bytes_saved"] = self.flush_bytes_saved
-        m["prof.flush_overflows"] = self.flush_overflows
-        return m
-
     def _recover_dispatch(self, engine, exc: BaseException,
                           injected: bool = False) -> np.ndarray:
         """Graceful device-plane degradation: the in-flight dispatch failed
@@ -1828,11 +1716,6 @@ class DeviceTrafficPlane:
         self._sharded_variants.clear()
         self._chain_leg_bits = None
         self._flush_step = None
-        # the twin packs full-length flushes only; drop the capped-path
-        # bookkeeping with the device backend
-        self._flush_caps = None
-        self._inflight_caps = None
-        self._inflight_args = None
         # predictions are calibrated for the DEVICE kernels; the numpy
         # twin must not be judged (or scheduled) by them
         self._costmodel = None
@@ -2035,12 +1918,6 @@ class DeviceTrafficPlane:
             "superwindows": self.superwindows,
             "rounds_per_launch": round(
                 self._rounds_launched / max(self.dispatches, 1), 2),
-            # delta-compacted flush outcomes (ISSUE 16): readback bytes
-            # the capped encoding saved, and windows that outran the
-            # caps (each paid one full-length re-run; persistent
-            # overflow reverts the caps entirely)
-            "flush_bytes_saved": self.flush_bytes_saved,
-            "flush_overflows": self.flush_overflows,
             "mode": self.mode,
             # dispatch-guard outcomes: >0 recoveries means a dispatch
             # failed, the window history replayed on the numpy twin, and

@@ -476,20 +476,17 @@ def make_mesh_span_raw(mesh, axis: str, ring_len: int, pad: int,
 def make_mesh_span_flush(mesh, axis: str, ring_len: int, layout: dict,
                          last_flow_pad: np.ndarray, node_src: np.ndarray,
                          n_nodes: int, mode: Optional[str] = None,
-                         leg_mask: Optional[Tuple[bool, ...]] = None,
-                         cap_chains: Optional[int] = None,
-                         cap_nodes: Optional[int] = None):
+                         leg_mask: Optional[Tuple[bool, ...]] = None):
     """Mesh superwindow step + packed flush in ONE dispatch: the engine's
     sharded kernel (DeviceTrafficPlane._sharded_step contract — same
     argument list as the PR-7 kernel, so advance()/warmup() are layout-
     agnostic).  ``mode`` picks the exchange execution strategy
     (choose_exchange_mode; None = the legacy heuristic); ``leg_mask``
-    compiles quiet exchange legs out (make_mesh_span_raw); the caps pick
-    the delta-compacted flush layout (ops/torcells_device._pack_flush_jnp).
-    The flush buffer is the standard packed layout with ONE trailing slot
-    appended: [flush_len(..., caps)] = cross-shard cells exchanged this
-    window (consume() folds it into the mesh metrics with no extra device
-    read)."""
+    compiles quiet exchange legs out (make_mesh_span_raw).  The flush
+    buffer is the standard packed layout (ops/torcells_device.
+    _pack_flush_jnp) with ONE trailing slot appended: [flush_len(...)] =
+    cross-shard cells exchanged this window (consume() folds it into the
+    mesh metrics with no extra device read)."""
     raw = make_mesh_span_raw(mesh, axis, ring_len, layout["pad"],
                              layout["exchange"], mode=mode,
                              leg_mask=leg_mask)
@@ -516,19 +513,15 @@ def make_mesh_span_flush(mesh, axis: str, ring_len: int, layout: dict,
         newly = (done_last >= 0) & (done_in_last < 0)
         flush = _pack_flush_jnp(out[8], jnp.sum(out[4][lf]), out[0], newly,
                                 done_last, global_sent(out[7]) - sent_in,
-                                cap_chains, cap_nodes, moved=out[10])
+                                moved=out[10])
         flush = jnp.concatenate([flush, out[9][None]])
         return (*out[:9], flush)
 
     return jax.jit(step_flush)
 
 
-def mesh_flush_extra(flush: np.ndarray, n_chains: int, n_nodes: int,
-                     cap_chains: Optional[int] = None,
-                     cap_nodes: Optional[int] = None) -> int:
+def mesh_flush_extra(flush: np.ndarray, n_chains: int, n_nodes: int) -> int:
     """The mesh flush buffer's trailing cross-shard cell count, or 0 for a
-    standard-length buffer (the numpy twin after a demotion).  Pass the
-    caps the buffer was packed with — the trailing slot rides at the end
-    of the CAPPED layout."""
-    base = flush_len(n_chains, n_nodes, cap_chains, cap_nodes)
+    standard-length buffer (the numpy twin after a demotion)."""
+    base = flush_len(n_chains, n_nodes)
     return int(flush[base]) if len(flush) > base else 0

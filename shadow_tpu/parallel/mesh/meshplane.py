@@ -146,16 +146,12 @@ def attach_mesh(plane, n_dev: int) -> None:
     plane._chain_leg_bits = chain_bits
     plane._full_leg_bits = -1 if sched.legs > 63 \
         else (1 << sched.legs) - 1
-    caps = getattr(plane, "_flush_caps", None)
-    cap_c, cap_h = caps if caps else (None, None)
 
-    def make_step(leg_mask=None, capped=True):
-        cc, hh = (cap_c, cap_h) if capped else (None, None)
+    def make_step(leg_mask=None):
         return make_mesh_span_flush(
             mesh, "flows", plane.ring_len, lay,
             lay["inv"][plane.last_flow], lay["node_src"], plane.n_nodes,
-            mode=ex_mode, leg_mask=leg_mask,
-            cap_chains=cc, cap_nodes=hh)
+            mode=ex_mode, leg_mask=leg_mask)
 
     plane._mesh_make_step = make_step
     plane._sharded_step = make_step()

@@ -251,10 +251,8 @@ def measure_batched_step_kernel(widths=(1, 2, 4, 8), n_circ: int = 1000,
 def measure_transfer(reps: int = 30, flows: int = 4096,
                      big_flows: int = 65536) -> Dict:
     """Fixed per-launch transfer cost: inject upload + flush readback.
-    The readback is measured at TWO buffer sizes; the slope
-    (``flush_us_per_mb``) is what prices the delta-compacted flush
-    (ISSUE 16, prof/autotune.py) — on a box where readback cost is
-    size-independent the slope is ~0 and compaction stays off."""
+    The readback is measured at TWO buffer sizes, and their slope is
+    recorded as ``flush_us_per_mb``."""
     import jax
     import jax.numpy as jnp
     import numpy as np

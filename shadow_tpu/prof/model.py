@@ -301,18 +301,6 @@ class CostModel:
         return float(tr.get("dispatch_us", 0.0)) \
             + float(tr.get("flush_us", 0.0))
 
-    def flush_us_per_mb(self) -> float:
-        """Marginal flush readback cost per MiB of buffer (the measured
-        size slope, ISSUE 16); 0.0 on a pre-16 model that only measured
-        one flush size — delta-compaction then has no measured savings
-        to justify itself and stays off."""
-        return float(self.data["transfer"].get("flush_us_per_mb", 0.0))
-
-    def flush_savings_us(self, bytes_saved: int) -> float:
-        """Predicted per-launch readback saving of shrinking the flush
-        buffer by ``bytes_saved`` bytes."""
-        return self.flush_us_per_mb() * max(int(bytes_saved), 0) / 2 ** 20
-
     # -- scheduler/attribution queries ------------------------------------
     def exchange_tick_us(self, n_dev: int, mode: str, pair_width: int,
                          leg_widths: List[int]) -> float:

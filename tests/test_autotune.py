@@ -1,16 +1,15 @@
-"""COSTMODEL-driven dispatch auto-tuner + delta-compacted flush (ISSUE 16).
+"""COSTMODEL-driven dispatch auto-tuner.
 
 1. Decision table (pure plan_dispatch units): synthetic models force each
-   regime — launch-bound => deep K, transfer-bound / no size slope =>
-   compaction off, uncalibrated or out-of-range => hand defaults,
+   regime — launch-bound => deep K, compute-bound => hand default,
+   uncalibrated or out-of-range => hand defaults,
    ``--device-autotune off`` => untouched, an explicitly-set knob is
    always honored, cadence/granule stay at contract values.
-2. Capped flush mechanics (ops level): the capped pack is bit-identical
-   to the full pack on the surviving entries, the TRUE header counts make
-   overflow detectable, and parse_flush reads the capped layout.
+2. Flush layout (ops level): the device pack is bit-identical to the
+   numpy twin, and parse_flush recovers every section.
 3. Engine integration: digest parity tuned-vs-hand-defaults,
    device-vs-numpy, explicit-K=1-vs-deep-K, and sharded-vs-serial under
-   the tuner; compaction savings accounted in the scrape; the
+   the tuner; the tuned depth engaged in the scrape; the
    prof.model_stale alarm fires when the TUNED prediction misses the
    band (the tuner's audit trail is live, not just recorded).
 
@@ -31,9 +30,7 @@ from shadow_tpu.core.options import Options
 from shadow_tpu.prof import autotune, model as prof_model
 from shadow_tpu.tools import workloads
 
-# single-device star with enough chains (48) that the capped flush
-# sections are strictly smaller than the full buffer — the compaction
-# regime is reachable; still ~seconds at the 4 ms granule
+# single-device star with 48 chains; still ~seconds at the 4 ms granule
 STAR24_XML = workloads.star_bulk(24, stoptime=120,
                                  bulk_bytes=16 * 1024 * 1024,
                                  device_data=True)
@@ -47,8 +44,7 @@ STAR6_XML = workloads.star_bulk(6, stoptime=120,
 _TD = tempfile.mkdtemp(prefix="autotune-models-")
 
 
-def _measurements(step_points, dispatch_us=400.0, flush_us=1600.0,
-                  flush_us_per_mb=0.0):
+def _measurements(step_points, dispatch_us=400.0, flush_us=1600.0):
     return {
         "collectives": {
             "ppermute": {"2x24": 300.0, "8x24": 300.0},
@@ -57,7 +53,7 @@ def _measurements(step_points, dispatch_us=400.0, flush_us=1600.0,
         },
         "step_kernel": {"points": step_points},
         "transfer": {"dispatch_us": dispatch_us, "flush_us": flush_us,
-                     "flush_us_per_mb": flush_us_per_mb},
+                     "flush_us_per_mb": 0.0},
     }
 
 
@@ -75,13 +71,11 @@ def _model_file(name, step_points, **kw):
 
 
 # a covering launch-bound model: flat cheap step cost, large fixed
-# per-launch transfer, strong flush size slope — forces deep K AND
-# compaction wherever the capped sections actually shrink the buffer
+# per-launch transfer — forces deep K
 def _launch_bound_file():
     return _model_file("launch-bound.json",
                        [{"flows": 1, "us_per_step": 30.0},
-                        {"flows": 1_000_000, "us_per_step": 30.0}],
-                       flush_us_per_mb=200_000.0)
+                        {"flows": 1_000_000, "us_per_step": 30.0}])
 
 
 class _Opts:
@@ -96,26 +90,23 @@ class _Opts:
 def test_plan_off_restores_hand_defaults():
     m = _model([{"flows": 1, "us_per_step": 30.0},
                 {"flows": 1000, "us_per_step": 30.0}])
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(autotune="off"),
-                                  500, 48, 25)
+    plan = autotune.plan_dispatch(m, "loaded", _Opts(autotune="off"), 500)
     assert plan.source == "off"
     assert plan.superwindow_rounds == autotune.DEFAULT_K
-    assert plan.flush_compact is False
 
 
 def test_plan_uncalibrated_falls_back_to_defaults():
     # no model on this box / model refused
     for model, status in ((None, "absent"), (None, "refused")):
-        plan = autotune.plan_dispatch(model, status, _Opts(), 500, 48, 25)
+        plan = autotune.plan_dispatch(model, status, _Opts(), 500)
         assert plan.source == "defaults"
         assert plan.superwindow_rounds == autotune.DEFAULT_K
-        assert plan.flush_compact is False
     # loaded but the flow table sits outside the calibrated range: the
     # no-extrapolation guard refuses to tune from it
     m = _model([{"flows": 100_000, "us_per_step": 30.0},
                 {"flows": 1_000_000, "us_per_step": 30.0}])
     assert not m.covers(500)
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500, 48, 25)
+    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500)
     assert plan.source == "defaults"
 
 
@@ -125,7 +116,7 @@ def test_plan_launch_bound_deepens_k():
     # stay at their digest-bearing contract values
     m = _model([{"flows": 1, "us_per_step": 30.0},
                 {"flows": 1_000_000, "us_per_step": 30.0}])
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500, 12, 7)
+    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500)
     assert plan.source == "model"
     assert plan.superwindow_rounds == autotune.MAX_K
     assert plan.min_dispatch_steps == autotune.DEFAULT_CADENCE
@@ -134,34 +125,15 @@ def test_plan_launch_bound_deepens_k():
     # hand default — no gratuitous deepening
     m2 = _model([{"flows": 1, "us_per_step": 5000.0},
                  {"flows": 1_000_000, "us_per_step": 5000.0}])
-    plan2 = autotune.plan_dispatch(m2, "loaded", _Opts(), 500, 12, 7)
+    plan2 = autotune.plan_dispatch(m2, "loaded", _Opts(), 500)
     assert plan2.source == "model"
     assert plan2.superwindow_rounds == autotune.DEFAULT_K
-
-
-def test_plan_compaction_needs_measured_slope_and_real_savings():
-    pts = [{"flows": 1, "us_per_step": 30.0},
-           {"flows": 1_000_000, "us_per_step": 30.0}]
-    # transfer-bound box but NO measured size slope: compaction cannot
-    # price its savings -> stays off
-    plan = autotune.plan_dispatch(_model(pts), "loaded", _Opts(),
-                                  500, 4096, 1024)
-    assert plan.source == "model" and plan.flush_compact is False
-    # slope present + big buffer: on, with the capped sections recorded
-    m = _model(pts, flush_us_per_mb=200_000.0)
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500, 4096, 1024)
-    assert plan.flush_compact is True
-    assert plan.flush_cap_chains == autotune.flush_caps(4096, 1024)[0]
-    assert plan.flush_bytes_cap_saved > 0
-    # slope present but a tiny buffer the caps cannot shrink: off
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(), 500, 12, 7)
-    assert plan.flush_compact is False
 
 
 def test_plan_honors_explicit_user_knob():
     m = _model([{"flows": 1, "us_per_step": 30.0},
                 {"flows": 1_000_000, "us_per_step": 30.0}])
-    plan = autotune.plan_dispatch(m, "loaded", _Opts(k=1), 500, 12, 7)
+    plan = autotune.plan_dispatch(m, "loaded", _Opts(k=1), 500)
     assert plan.source == "model"
     assert plan.superwindow_rounds == 1   # the user's knob, not ours
 
@@ -169,10 +141,9 @@ def test_plan_honors_explicit_user_knob():
 def test_plan_metrics_audit_trail():
     m = _model([{"flows": 1, "us_per_step": 30.0},
                 {"flows": 1_000_000, "us_per_step": 30.0}])
-    got = autotune.plan_dispatch(m, "loaded", _Opts(), 500, 12, 7).metrics()
+    got = autotune.plan_dispatch(m, "loaded", _Opts(), 500).metrics()
     for key in ("prof.autotune_source", "prof.autotune_k",
                 "prof.autotune_cadence", "prof.autotune_granule",
-                "prof.autotune_flush_compact",
                 "prof.autotune_predicted_us"):
         assert key in got, f"audit trail lost {key}"
     assert got["prof.autotune_source"] == "model"
@@ -180,12 +151,13 @@ def test_plan_metrics_audit_trail():
     assert got["prof.autotune_predicted_us"] > 0
 
 
-# -- 2. capped flush mechanics ----------------------------------------------
+# -- 2. flush layout ---------------------------------------------------------
 
 def test_capped_pack_parse_and_overflow_detection():
+    """The full-layout round trip: the device pack equals the numpy twin
+    bit for bit, and parse_flush recovers every section from it."""
     from shadow_tpu.ops.torcells_device import (
-        _pack_flush_jnp, flush_len, flush_overflowed, pack_flush_np,
-        parse_flush)
+        _pack_flush_jnp, flush_len, pack_flush_np, parse_flush)
     import jax.numpy as jnp
 
     C, H = 10, 12
@@ -194,39 +166,20 @@ def test_capped_pack_parse_and_overflow_detection():
     done_last = np.arange(C, dtype=np.int64) * 7
     sent_delta = np.zeros(H, np.int64)
     sent_delta[[0, 2, 3, 7, 8, 11]] = np.int64([5, -2, 9, 1, 4, 6])
-    args = (np.int64(123), np.int64(456), np.int64(789),
-            jnp.asarray(newly), jnp.asarray(done_last),
-            jnp.asarray(sent_delta))
-    full = np.asarray(_pack_flush_jnp(*args))
-    # full-length pack is bit-identical to the numpy twin
+    full = np.asarray(_pack_flush_jnp(
+        np.int64(123), np.int64(456), np.int64(789), jnp.asarray(newly),
+        jnp.asarray(done_last), jnp.asarray(sent_delta), moved=31))
+    assert len(full) == flush_len(C, H)
     np.testing.assert_array_equal(
         full, pack_flush_np(np.int64(123), np.int64(456), np.int64(789),
-                            newly, done_last, sent_delta))
-    ref = parse_flush(full, C, H)
-    # generous caps: same parse through the capped layout
-    capped = np.asarray(_pack_flush_jnp(*args, cap_chains=8, cap_nodes=8))
-    assert len(capped) == flush_len(C, H, 8, 8) < len(full)
-    assert not flush_overflowed(capped, 8, 8)
-    got = parse_flush(capped, C, H, 8, 8)
-    assert got[:3] == ref[:3]
-    for a, b in zip(got[3:], ref[3:]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # tight caps: entries were dropped, and the TRUE header counts say so
-    tight = np.asarray(_pack_flush_jnp(*args, cap_chains=2, cap_nodes=3))
-    assert flush_overflowed(tight, 2, 3)
-    assert int(tight[2]) == 4 and int(tight[3]) == 6
-
-
-def test_flush_caps_shape():
-    from shadow_tpu.ops.torcells_device import flush_len
-    # floors of 16 chains / 64 nodes: a tiny net's caps cover the whole
-    # buffer (flush_len clamps to the true sizes -> zero savings, and
-    # plan_dispatch keeps compaction off)
-    assert autotune.flush_caps(12, 7) == (16, 64)
-    assert flush_len(12, 7, *autotune.flush_caps(12, 7)) == flush_len(12, 7)
-    assert autotune.flush_caps(48, 25) == (16, 64)
-    cap_c, cap_h = autotune.flush_caps(4096, 1024)
-    assert cap_c == 512 and cap_h == 256
+                            newly, done_last, sent_delta, moved=31))
+    (forwards, delivered_sum, t_stop, chains, steps, nodes,
+     deltas) = parse_flush(full, C, H)
+    assert (forwards, delivered_sum, t_stop) == (123, 456, 789)
+    np.testing.assert_array_equal(chains, [1, 4, 5, 9])
+    np.testing.assert_array_equal(steps, done_last[[1, 4, 5, 9]])
+    np.testing.assert_array_equal(nodes, [0, 2, 3, 7, 8, 11])
+    np.testing.assert_array_equal(deltas, [5, -2, 9, 1, 4, 6])
 
 
 # -- 3. engine integration --------------------------------------------------
@@ -262,14 +215,8 @@ def test_tuned_run_engages_and_accounts_savings():
     scrape = ctrl.engine.metrics.scrape()
     assert scrape["prof.autotune_source"] == "model"
     assert scrape["prof.autotune_k"] == autotune.MAX_K
-    assert scrape["prof.autotune_flush_compact"] == 1
-    # the capped encoding actually ran: readback bytes saved accumulated,
-    # and any window that outran the caps was re-read full-length (the
-    # digest-parity gate below proves none of it changed results)
-    assert scrape["prof.flush_bytes_saved"] > 0
-    st = ctrl.engine.device_plane.stats()
-    assert st["flush_bytes_saved"] == scrape["prof.flush_bytes_saved"]
     # deep K engaged: launches amortize above the hand-default floor
+    st = ctrl.engine.device_plane.stats()
     assert st["rounds_per_launch"] > 1
 
 

@@ -84,12 +84,6 @@ def test_donating_compact_span_flush_compiles_for_v5e(one_chip, width):
     assert compiled.memory_analysis().alias_size_in_bytes > 0  # donated
 
 
-def test_capped_flush_compiles_for_v5e(one_chip):
-    td.torcells_step_window_flush_capped.lower(
-        *_flush_shapes(one_chip, 10_000), ring_len=66, cap_chains=256,
-        cap_nodes=1024).compile()
-
-
 def test_vmapped_fleet_flush_compiles_for_v5e(one_chip):
     """The fleet plane's vmapped span-flush: 8 lanes of 200 chains."""
     shapes = [jax.ShapeDtypeStruct((8, *a.shape), a.dtype,

@@ -1,10 +1,10 @@
 """Device-resident traffic plane (parallel/device_plane.py) gates.
 
 Three contracts:
-1. The windowed stateful kernel (torcells_step_window) advances the model
-   IDENTICALLY to the reference run-to-completion kernel (torcells_run) and
-   to its own numpy twin, bit for bit, across arbitrary window splits and
-   idle-gap folds.
+1. The span-flush program, one boundary per dispatch, advances the model
+   IDENTICALLY to the run-to-completion loop (DeviceTorCells) and to its
+   numpy twin (torcells_step_span_numpy), bit for bit, across arbitrary
+   window splits and idle-gap folds.
 2. A full engine simulation produces identical state digests whether the
    bulk flows run on the device plane or its numpy twin, and whether the
    scheduler policy is serial or tpu.
@@ -45,100 +45,97 @@ def _toy_instance():
                           relay_bw_kibps=512, max_latency_ms=20)
 
 
+def _span_tables(inst):
+    """The flow tables after the per-dispatch operands, numpy twin first,
+    then the device program's (with the gather tables)."""
+    fl = inst.flows
+    twin = (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
+            fl["seg_start"], inst.refill, inst.capacity,
+            np.flatnonzero(fl["flow_succ"] < 0))
+    return twin, tuple(np.asarray(a) for a in (*twin, fl["flow_pred"],
+                                               fl["node_seg"]))
+
+
+def _zero_state(inst):
+    from shadow_tpu.ops.torcells_device import RING_DTYPE
+    f = inst.n_flows
+    return [np.int64(0), np.zeros(f, np.int64),
+            np.zeros((inst.ring_len, f), RING_DTYPE),
+            inst.capacity.copy().astype(np.int64),
+            np.zeros(f, np.int64), np.zeros(f, np.int64),
+            np.full(f, -1, np.int64), np.zeros(len(inst.refill), np.int64)]
+
+
 def test_windowed_kernel_matches_run_to_completion():
-    """torcells_step_window with one big window == torcells_run (pins the
-    duplicated per-tick math together bit-for-bit)."""
-    import jax.numpy as jnp
-    from shadow_tpu.ops.torcells_device import (torcells_run,
-                                                torcells_step_window)
+    """The span-flush program over one single-boundary window == the
+    run-to-completion loop on the numpy twin (many dispatches, halting
+    at completions): the same cells delivered, forwards and completion
+    ticks, bit for bit."""
+    from shadow_tpu.ops.torcells_device import (
+        parse_flush, torcells_step_window_flush_nodonate)
     inst = _toy_instance()
     fl = inst.flows
     queued0 = np.where(fl["flow_stage"] == 0, 40, 0).astype(np.int64)
-    ref_del, ref_ticks, ref_fwd = inst.run_device(40, max_ticks=5000)
+    target0 = np.where(fl["flow_succ"] < 0, 40, 0).astype(np.int64)
+    ref_del, ref_ticks, ref_fwd = inst.run_numpy(40, max_ticks=5000)
+    assert ref_ticks < 5000
 
-    f = inst.n_flows
-    h = len(inst.refill)
-    state = (jnp.int64(0), jnp.zeros(f, jnp.int64),
-             jnp.zeros((inst.ring_len, f), jnp.int64),
-             jnp.asarray(inst.capacity),
-             jnp.zeros(f, jnp.int64), jnp.zeros(f, jnp.int64),
-             jnp.full(f, -1, jnp.int64), jnp.zeros(h, jnp.int64))
-    out = torcells_step_window(
-        *state, jnp.asarray(queued0), jnp.asarray(queued0),
-        np.int64(ref_ticks), np.int64(0),
-        jnp.asarray(fl["flow_node"]), jnp.asarray(fl["flow_lat"]),
-        jnp.asarray(fl["flow_succ"]), jnp.asarray(fl["seg_start"]),
-        jnp.asarray(inst.refill), jnp.asarray(inst.capacity),
+    _twin, tables = _span_tables(inst)
+    out = torcells_step_window_flush_nodonate(
+        *_zero_state(inst), queued0, target0,
+        np.array([ref_ticks], np.int64), np.int64(0), *tables,
         ring_len=inst.ring_len)
     np.testing.assert_array_equal(np.asarray(out[4]), ref_del)
     assert int(out[8]) == ref_fwd
+    fwd, _, t_stop, chains, steps, _, _ = parse_flush(
+        np.asarray(out[9]), len(tables[6]), len(inst.refill))
+    assert (fwd, t_stop) == (ref_fwd, ref_ticks)
+    assert len(chains) == len(tables[6]) and steps.max() == ref_ticks - 1
 
 
 def test_windowed_kernel_split_and_idle_invariance():
     """Many small windows + an idle-gap fold == one big window (numpy twin
-    vs device, both ways)."""
-    import jax.numpy as jnp
-    from shadow_tpu.ops.torcells_device import (torcells_step_window,
-                                                torcells_step_window_numpy)
+    vs device, both ways), through the span-flush program with one
+    boundary per dispatch."""
+    from shadow_tpu.ops.torcells_device import (
+        torcells_step_span_numpy, torcells_step_window_flush_nodonate)
     inst = _toy_instance()
     fl = inst.flows
     f = inst.n_flows
-    h = len(inst.refill)
     queued0 = np.where(fl["flow_stage"] == 0, 25, 0).astype(np.int64)
-    flow_args = (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
-                 fl["seg_start"], inst.refill, inst.capacity)
+    twin, tables = _span_tables(inst)
+    flow_args = twin[:6]
 
-    def np_state():
-        return [np.int64(0), np.zeros(f, np.int64),
-                np.zeros((inst.ring_len, f), np.int64),
-                inst.capacity.copy().astype(np.int64),
-                np.zeros(f, np.int64), np.zeros(f, np.int64),
-                np.full(f, -1, np.int64), np.zeros(h, np.int64)]
+    def np_span(state, inject, end, idle=0):
+        return torcells_step_span_numpy(
+            *state, inject, inject, np.array([end], np.int64),
+            np.int64(idle), *flow_args, inst.ring_len)
+
+    def dev_span(state, inject, end):
+        return torcells_step_window_flush_nodonate(
+            *state, inject, inject, np.array([end], np.int64), np.int64(0),
+            *tables, ring_len=inst.ring_len)
 
     zeros = np.zeros(f, np.int64)
     # one 600-tick window
-    big = torcells_step_window_numpy(*np_state(), queued0, queued0, 600, 0,
-                                     *flow_args, inst.ring_len)
+    big = np_span(_zero_state(inst), queued0, 600)
     # split: 7 + 93 + 500 with injection only in the first
-    s = np_state()
-    out = torcells_step_window_numpy(*s, queued0, queued0, 7, 0,
-                                     *flow_args, inst.ring_len)
-    out = torcells_step_window_numpy(*out[:8], zeros, zeros, 93, 0,
-                                     *flow_args, inst.ring_len)
-    out = torcells_step_window_numpy(*out[:8], zeros, zeros, 500, 0,
-                                     *flow_args, inst.ring_len)
+    out = np_span(_zero_state(inst), queued0, 7)
+    out = np_span(out[:8], zeros, 100)
+    out = np_span(out[:8], zeros, 600)
     for i in (1, 3, 4, 5, 6, 7):
         np.testing.assert_array_equal(out[i], big[i])
 
     # device twin of the split run
-    dev = tuple(jnp.asarray(a) for a in np_state())
-    dout = torcells_step_window(*dev, jnp.asarray(queued0),
-                                jnp.asarray(queued0), np.int64(7),
-                                np.int64(0),
-                                *(jnp.asarray(a) for a in flow_args),
-                                ring_len=inst.ring_len)
-    dout = torcells_step_window(*dout[:8], jnp.asarray(zeros),
-                                jnp.asarray(zeros), np.int64(93),
-                                np.int64(0),
-                                *(jnp.asarray(a) for a in flow_args),
-                                ring_len=inst.ring_len)
-    dout = torcells_step_window(*dout[:8], jnp.asarray(zeros),
-                                jnp.asarray(zeros), np.int64(500),
-                                np.int64(0),
-                                *(jnp.asarray(a) for a in flow_args),
-                                ring_len=inst.ring_len)
+    dout = dev_span(_zero_state(inst), queued0, 7)
+    dout = dev_span(dout[:8], zeros, 100)
+    dout = dev_span(dout[:8], zeros, 600)
     for i in (1, 3, 4, 5, 6, 7):
         np.testing.assert_array_equal(np.asarray(dout[i]), big[i])
 
     # idle fold: running 100 empty ticks == banking them as idle_ticks
-    idle_a = torcells_step_window_numpy(*[x.copy() if hasattr(x, "copy")
-                                          else x for x in out[:8]],
-                                        zeros, zeros, 100, 0,
-                                        *flow_args, inst.ring_len)
-    idle_b = torcells_step_window_numpy(*[x.copy() if hasattr(x, "copy")
-                                          else x for x in out[:8]],
-                                        zeros, zeros, 0, 100,
-                                        *flow_args, inst.ring_len)
+    idle_a = np_span([x.copy() for x in out[:8]], zeros, 700)
+    idle_b = np_span([x.copy() for x in out[:8]], zeros, 600, idle=100)
     np.testing.assert_array_equal(idle_a[3], idle_b[3])   # tokens
     np.testing.assert_array_equal(idle_a[4], idle_b[4])   # delivered
 

@@ -216,12 +216,9 @@ def test_boundary_hook_sees_every_boundary_and_stops_the_run():
     assert stopped.engine.end_time == upto[-1]
 
 
-@pytest.mark.parametrize("wrapper,kw", [
-    ("torcells_step_window_flush", {}),
-    ("torcells_step_window_flush_nodonate", {}),
-    ("torcells_step_window_flush_capped", {"cap_chains": 1, "cap_nodes": 1}),
-])
-def test_span_flush_module_names(wrapper, kw):
+@pytest.mark.parametrize("wrapper", ["torcells_step_window_flush",
+                                     "torcells_step_window_flush_nodonate"])
+def test_span_flush_module_names(wrapper):
     import jax.numpy as jnp
 
     from shadow_tpu.ops import torcells_device as td
@@ -235,7 +232,7 @@ def test_span_flush_module_names(wrapper, kw):
             np.array([1, 1, 0]), np.array([1, 2, -1]), np.array([0, 1, 1]),
             np.array([5, 5]), np.array([9, 9]), np.array([2]),
             np.array([-1, 0, 1]), np.array([[0, 1], [1, 3]]))
-    text = getattr(td, wrapper).lower(*args, ring_len=4, **kw).as_text()
+    text = getattr(td, wrapper).lower(*args, ring_len=4).as_text()
     module = next(ln for ln in text.splitlines() if ln.startswith("module"))
     assert "_step_span_flush_impl" in module, module
 

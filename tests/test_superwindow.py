@@ -31,8 +31,7 @@ from shadow_tpu.core.checkpoint import load_snapshot, state_digest
 from shadow_tpu.core.controller import Controller
 from shadow_tpu.core.options import Options
 from shadow_tpu.ops.torcells_device import (CELL_WIRE_BYTES,
-                                            torcells_step_span_numpy,
-                                            torcells_step_window_numpy)
+                                            torcells_step_span_numpy)
 from shadow_tpu.tools import workloads
 
 # few circuits + long transfers => the bulk phase is a host-quiet stretch
@@ -264,7 +263,7 @@ def _run_span(fx, t0, targets, inject=(0, 0), idle=0):
 
 
 def _run_sequential(fx, t0, targets, inject=(0, 0)):
-    """The K=1 oracle: one single-target window per boundary, halting
+    """The K=1 oracle: one single-boundary span per boundary, halting
     after the first window in which a chain newly completed (exactly the
     per-round engine behavior a completion wake imposes)."""
     f = fx
@@ -275,9 +274,9 @@ def _run_sequential(fx, t0, targets, inject=(0, 0)):
     forwards = 0
     for tgt in targets:
         done_before = state[6].copy()
-        out = torcells_step_window_numpy(
+        out = torcells_step_span_numpy(
             *state, inj, np.zeros(2, dtype=np.int64),
-            np.int64(int(tgt) - int(state[0])), np.int64(0),
+            np.array([tgt], dtype=np.int64), np.int64(0),
             f["flow_node"], f["flow_lat"], f["flow_succ"], f["seg_start"],
             f["refill"], f["capacity"], 6)
         inj = np.zeros(2, dtype=np.int64)   # injections fold at base only
