@@ -460,6 +460,11 @@ class DeviceTrafficPlane:
         self.flow_ticks_stepped = 0
         self.compact_dispatches = 0
         self._launch_width = 0
+        # the section capacities (chains, nodes) of the in-flight
+        # dispatch's flush: (K, K) from the compacted program at width K,
+        # (C, H) from every other; and the flush bytes copied to the host
+        self._launch_sizes = (0, 0)
+        self.flush_bytes_read = 0
         # pipeline introspection: actual host<->device interactions (kernel
         # dispatch + inject upload + flush read) and the wall the in-flight
         # dispatch had to compute behind host round work
@@ -919,15 +924,16 @@ class DeviceTrafficPlane:
         """The compacted program's ``live`` operand: the live chains' flow
         positions, ascending and padded with F to ``width``, with the
         staged ``pairs``' cells and targets at their chains' entry and exit
-        flows.  O(live flows) host work."""
+        flows, and each position's chain.  O(live flows) host work."""
         chains = np.fromiter(self._live, dtype=np.int64, count=len(self._live))
         n = self._chain_len[chains]
         rows = np.repeat(self._chain_base[chains] - np.cumsum(n) + n, n) \
             + np.arange(int(n.sum()))
         pos = np.sort(self._chain_rows[rows])
-        live = np.zeros((3, width), dtype=np.int64)
+        live = np.zeros((4, width), dtype=np.int64)
         live[0] = self.n_flows
         live[0, :len(pos)] = pos
+        live[3, :len(pos)] = self.flow_circ[pos]
         for circ, cells in pairs:
             live[1, np.searchsorted(pos, self.first_flow[circ])] += cells
             live[2, np.searchsorted(pos, self.last_flow[circ])] += cells
@@ -1059,17 +1065,18 @@ class DeviceTrafficPlane:
             ring_len=self.ring_len)
         jax.block_until_ready(flush_halves(out[9]))        # its readback
         # every compacted width a dispatch may pick (_compact_width), with
-        # a live table of padding alone
+        # a live table of padding alone, and the readback of its flush,
+        # whose length is the width's
         self._compact_step = compact_flush_for_backend()
         for width in self._compact_widths:
-            live = np.zeros((3, width), dtype=np.int64)
+            live = np.zeros((4, width), dtype=np.int64)
             live[0] = f
             out = self._compact_step(
                 *fresh(), live, self._pad_targets([1]), np.int64(0),
                 self.flow_node, self.flow_lat_steps, self.flow_succ,
                 self.seg_start, self.refill_step, self.capacity_step,
-                self.last_flow, self.flow_pred, ring_len=self.ring_len)
-            jax.block_until_ready(out)
+                self.flow_pred, ring_len=self.ring_len)
+            jax.block_until_ready((out, flush_halves(out[9])))
 
     def _pad_targets(self, targets: List[int]) -> np.ndarray:
         """Pad a superwindow's boundary list to the static kernel shape by
@@ -1259,6 +1266,8 @@ class DeviceTrafficPlane:
         self._launch_width = width or (
             len(self._shard["src"]) if self._shard is not None
             else self.n_flows)
+        self._launch_sizes = (width, width) if width \
+            else (self.n_chains, self.n_nodes)
         idle = self._idle_ticks_banked
         self._idle_ticks_banked = 0
         # Step continuity: the kernel's carried t equals the last dispatch's
@@ -1301,8 +1310,9 @@ class DeviceTrafficPlane:
             if self._compact_step is None:
                 from ..ops.torcells_device import compact_flush_for_backend
                 self._compact_step = compact_flush_for_backend()
+            tables = self._flow_args()
             out = self._compact_step(*state, live, tvec, np.int64(idle),
-                                     *self._flow_args()[:8],
+                                     *tables[:6], tables[7],
                                      ring_len=self.ring_len)
             self.compact_dispatches += 1
         elif self.mode == "device":
@@ -1401,6 +1411,7 @@ class DeviceTrafficPlane:
         # the slot is released up front so state stays consistent whether
         # the collect succeeds, raises, or is recovered
         handle, self._flush_handle = self._flush_handle, None
+        sizes = self._launch_sizes
         self._inflight = False
         t_read = None
         with self._profiler.tracer.span(
@@ -1413,9 +1424,13 @@ class DeviceTrafficPlane:
                 # --device-watchdog-sec), and the dispatch guard recovers
                 # it on the numpy twin
                 flush, t_read = self._collect_flush(engine, handle)
+                if hasattr(handle, "block_until_ready"):
+                    self.flush_bytes_read += flush.nbytes
             except Exception as e:  # noqa: BLE001 - any dispatch failure
                 flush = self._recover_dispatch(
                     engine, e, injected=isinstance(handle, _PoisonedFlush))
+                # the twin's replay packs the full-length flush
+                sizes = (self.n_chains, self.n_nodes)
         t1 = _wt.perf_counter_ns()
         # wait up to the readback's start; a recovered dispatch read
         # nothing from the device, so all of it counts as wait
@@ -1428,20 +1443,21 @@ class DeviceTrafficPlane:
                                   engine.scheduler.window_start)
         with self._profiler.tracer.annotate(
                 "plane.fold", sim_ns=engine.scheduler.window_start):
-            self._fold(engine, flush, t0, t1)
+            self._fold(engine, flush, sizes, t0, t1)
         self.fold_ns += _wt.perf_counter_ns() - t1
         self._idle_from = t1
 
-    def _fold(self, engine, flush: np.ndarray, t0: int, t1: int) -> None:
-        """consume()'s host fold of one collected flush buffer (``t0``,
-        ``t1``: the collect's start and end stamps): parse it, advance the
-        window bookkeeping, fold node byte deltas and wake completed
-        flows."""
+    def _fold(self, engine, flush: np.ndarray, sizes: Tuple[int, int],
+              t0: int, t1: int) -> None:
+        """consume()'s host fold of one collected flush buffer, packed at
+        section capacities ``sizes`` (``t0``, ``t1``: the collect's start
+        and end stamps): parse it, advance the window bookkeeping, fold
+        node byte deltas and wake completed flows."""
         if self.mode == "device":
             self.device_calls += 1              # the flush read
         from ..ops.torcells_device import flush_moved, parse_flush
         (forwards, delivered_sum, t_stop, done_chains, done_steps, node_idx,
-         node_delta) = parse_flush(flush, self.n_chains, self.n_nodes)
+         node_delta) = parse_flush(flush, *sizes)
         steps_done = max(int(t_stop) - self._launch_base, 0)
         self.ticks_stepped += steps_done
         self.flow_ticks_moved += flush_moved(flush)
@@ -1950,6 +1966,8 @@ class DeviceTrafficPlane:
             # ticks: the compacted width when a dispatch ran one)
             "flow_ticks_stepped": self.flow_ticks_stepped,
             "compact_dispatches": self.compact_dispatches,
+            # bytes of flush buffers copied from the device to the host
+            "flush_bytes_read": self.flush_bytes_read,
             # pipeline introspection: host<->device interactions (dispatch +
             # inject upload + flush read; <= 3 per dispatch) and the wall
             # the in-flight dispatch computed behind host round work

@@ -78,9 +78,10 @@ def test_donating_compact_span_flush_compiles_for_v5e(one_chip, width):
     (100,000 flows): its tick loop is the loop the v5e compiler once
     refused for VMEM at full width."""
     shapes = _flush_shapes(one_chip, 20_000)
-    live = jax.ShapeDtypeStruct((3, width), jnp.int64, sharding=one_chip)
+    live = jax.ShapeDtypeStruct((4, width), jnp.int64, sharding=one_chip)
     compiled = td.torcells_step_compact_flush.lower(
-        *shapes[:8], live, *shapes[10:20], ring_len=66).compile()
+        *shapes[:8], live, *shapes[10:18], shapes[19],
+        ring_len=66).compile()
     assert compiled.memory_analysis().alias_size_in_bytes > 0  # donated
 
 

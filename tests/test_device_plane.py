@@ -431,7 +431,7 @@ def test_collects_share_one_thread(monkeypatch):
 # the compacted span-flush: a dispatch steps its live chains alone
 # ---------------------------------------------------------------------------
 
-def _waves(mode, waves, step, stop):
+def _waves(mode, waves, step, stop, **opt_kw):
     """genscen's tor deployment at 250 hosts on one device: 223
     process-less circuits, 2,230 flows (one compacted width, 1,024), in
     ``waves`` waves ``step`` seconds apart from 2 s.  The 1 s heartbeat
@@ -443,7 +443,7 @@ def _waves(mode, waves, step, stop):
                               stop_time_sec=stop, log_level="warning",
                               host_table="on", heartbeat_interval_sec=1,
                               device_plane=mode, tpu_devices=1,
-                              device_plane_granule_ms=10), cfg)
+                              device_plane_granule_ms=10, **opt_kw), cfg)
     assert ctrl.run() == 0
     return ctrl
 
@@ -474,6 +474,42 @@ def test_compacted_plane_matches_the_twin():
     assert st["flow_ticks_moved"] == twin.engine.device_plane.stats()[
         "flow_ticks_moved"]
     assert twin.engine.device_plane.stats()["compact_dispatches"] == 0
+    # each dispatch read back a flush packed at (1,024, 1,024); the twin's
+    # flush is host memory, read from no device
+    from shadow_tpu.ops.torcells_device import flush_len
+    assert st["flush_bytes_read"] == 10 * 8 * flush_len(1024, 1024)
+    assert twin.engine.device_plane.stats()["flush_bytes_read"] == 0
+
+
+def test_recovered_compacted_dispatch_folds_the_full_length_flush(
+        monkeypatch):
+    """The third dispatch, a compacted one, fails at its collect: the numpy
+    twin replays the log and hands back a full-length flush, which the
+    fold parses at (C, H), not at the failed launch's (1,024, 1,024).  The
+    run still ends in the twin's state with every circuit done."""
+    from shadow_tpu.ops.torcells_device import flush_len
+    from shadow_tpu.parallel.device_plane import DeviceTrafficPlane
+    folds = []
+    real = DeviceTrafficPlane._fold
+
+    def fold(self, engine, flush, sizes, t0, t1):
+        folds.append((len(flush), sizes, self.mode))
+        return real(self, engine, flush, sizes, t0, t1)
+
+    monkeypatch.setattr(DeviceTrafficPlane, "_fold", fold)
+    dev = _waves("device", 10, 0.5, 10, fault_inject="device-dispatch:3")
+    monkeypatch.setattr(DeviceTrafficPlane, "_fold", real)
+    twin = _waves("numpy", 10, 0.5, 10)
+    _same_plane_state(dev, twin)
+    plane = dev.engine.device_plane
+    st = plane.stats()
+    assert plane.recoveries == 1 and plane.demoted
+    assert st["completed"] == st["circuits"] == 223
+    c, h = plane.n_chains, plane.n_nodes
+    assert folds[:2] == [(flush_len(1024, 1024), (1024, 1024),
+                          "device")] * 2
+    assert folds[2] == (flush_len(c, h), (c, h), "numpy")
+    assert st["flush_bytes_read"] == 2 * 8 * flush_len(1024, 1024)
 
 
 def test_plane_falls_to_full_width_past_the_top_width(monkeypatch):

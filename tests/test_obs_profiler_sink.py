@@ -246,15 +246,14 @@ def test_compact_span_flush_module_names(wrapper):
 
     from shadow_tpu.ops import torcells_device as td
     f, h = 3, 2
-    live = np.array([[0, 1, 2, 3], [0] * 4, [0] * 4])
+    live = np.array([[0, 1, 2, 3], [0] * 4, [0] * 4, [0] * 4])
     args = (np.int64(0), jnp.zeros(f, jnp.int64),
             jnp.zeros((4, f), jnp.int32), jnp.zeros(h, jnp.int64),
             jnp.zeros(f, jnp.int64), jnp.zeros(f, jnp.int64),
             jnp.full(f, -1, jnp.int64), jnp.zeros(h, jnp.int64), live,
             np.array([2, 2]), np.int64(0), np.array([0, 1, 1]),
             np.array([1, 1, 0]), np.array([1, 2, -1]), np.array([0, 1, 1]),
-            np.array([5, 5]), np.array([9, 9]), np.array([2]),
-            np.array([-1, 0, 1]))
+            np.array([5, 5]), np.array([9, 9]), np.array([-1, 0, 1]))
     text = getattr(td, wrapper).lower(*args, ring_len=4).as_text()
     module = next(ln for ln in text.splitlines() if ln.startswith("module"))
     assert "_step_span_flush_impl" in module, module

@@ -134,7 +134,7 @@ def _tor_table(route, n_nodes, refill_cells, cap_cells, lat=2):
     route = np.asarray(route, np.int64)
     fl = build_flows(route, np.full((n_nodes, n_nodes), lat, np.int64))
     last_flow = np.array([np.flatnonzero((fl["flow_circ"] == c)
-                                         & (fl["flow_stage"] == 4))[0]
+                                         & (fl["flow_succ"] < 0))[0]
                           for c in range(len(route))], np.int64)
     c = CELL_WIRE_BYTES
     tables = (fl["flow_node"], fl["flow_lat"], fl["flow_succ"],
@@ -329,40 +329,62 @@ def test_span_flush_tick_loop_has_no_scatter():
 
 # -- the compacted span-flush: the live flows alone, bit for bit ------------
 
-def _live(fl, circuits, width, inject, inject_target):
+def _live(fl, tables, circuits, width, inject, inject_target):
     """The compacted program's live table for ``circuits`` of a build_flows
     layout: their flow positions, ascending and padded with F, with the
-    injections there (none may fall elsewhere)."""
+    injections there (none may fall elsewhere), and each flow's chain (the
+    circuit whose exit flow is ``last_flow[chain]``)."""
     f = len(fl["flow_node"])
+    last_flow = tables[6]
     on = np.isin(fl["flow_circ"], circuits)
     assert not (np.asarray(inject)[~on].any()
                 or np.asarray(inject_target)[~on].any())
     pos = np.flatnonzero(on)
-    live = np.zeros((3, width), np.int64)
+    chain_of_circ = np.empty(len(last_flow), np.int64)
+    chain_of_circ[fl["flow_circ"][last_flow]] = np.arange(len(last_flow))
+    live = np.zeros((4, width), np.int64)
     live[0] = f
     live[0, :len(pos)] = pos
     live[1, :len(pos)] = np.asarray(inject)[pos]
     live[2, :len(pos)] = np.asarray(inject_target)[pos]
+    live[3, :len(pos)] = chain_of_circ[fl["flow_circ"][pos]]
     return live
+
+
+def _same_flush(got, want, got_sizes, want_sizes):
+    """Every field parse_flush reads, in order: the header values, the
+    chain ids and done steps, the node ids and deltas."""
+    from shadow_tpu.ops.torcells_device import flush_moved, parse_flush
+    a = parse_flush(np.asarray(got), *got_sizes)
+    b = parse_flush(np.asarray(want), *want_sizes)
+    assert a[:3] == b[:3]
+    assert flush_moved(np.asarray(got)) == flush_moved(np.asarray(want))
+    for i in range(3, 7):
+        np.testing.assert_array_equal(a[i], b[i], err_msg=f"field {i}")
 
 
 def _compact_parity(fl, tables, state, inject, inject_target, targets,
                     circuits, idle=0, width=32, ring_len=4):
     """The compacted span-flush over ``circuits`` against the full-width
-    program and the numpy twin (_span_parity) from the same state: all
-    ten outputs equal bit for bit, the ring included.  Returns them."""
+    program and the numpy twin (_span_parity) from the same state: the
+    nine state outputs equal bit for bit, the ring included, and the
+    flush, packed at (width, width), reads as the full one at (C, H).
+    Returns the twin's outputs."""
     from shadow_tpu.ops.torcells_device import (
-        torcells_step_compact_flush_nodonate)
+        flush_len, torcells_step_compact_flush_nodonate)
     twin = _span_parity(tables, state, inject, inject_target, targets, idle,
                         ring_len)
     comp = torcells_step_compact_flush_nodonate(
-        *state, _live(fl, circuits, width, inject, inject_target),
-        np.asarray(targets, np.int64), np.int64(idle), *tables,
+        *state, _live(fl, tables, circuits, width, inject, inject_target),
+        np.asarray(targets, np.int64), np.int64(idle), *tables[:6],
         fl["flow_pred"], ring_len=ring_len)
-    for i in range(10):
+    for i in range(9):
         np.testing.assert_array_equal(np.asarray(comp[i]),
                                       np.asarray(twin[i]),
                                       err_msg=f"output {i}")
+    assert np.asarray(comp[9]).shape == (flush_len(width, width),)
+    _same_flush(comp[9], twin[9], (width, width),
+                (len(tables[6]), len(tables[4])))
     return twin
 
 
@@ -463,6 +485,55 @@ def test_compact_parity_idle_ticks_between_dispatches():
     assert int(out[8]) > 0
 
 
+def test_compact_parity_fills_both_flush_sections():
+    """Twelve one-hop chains on nodes 12..1, each its own node: eight are
+    live in a width-8 dispatch, so every live slot is a real flow, all
+    eight complete and all eight nodes send, filling both sections of the
+    (8, 8) flush.  The live flows sit in node order, the reverse of chain
+    order, so the chain section is put back in ascending chain id."""
+    n = 14
+    route = np.arange(12, 0, -1).reshape(12, 1)
+    fl, tables = _tor_table(route, n, np.full(n, 10), np.full(n, 20))
+    live = [0, 2, 3, 5, 6, 8, 9, 11]
+    inject, target = _injected(fl, live, 3)
+    out = _compact_parity(fl, tables, _zero_state(tables), inject, target,
+                          [1], live, width=8)
+    from shadow_tpu.ops.torcells_device import parse_flush
+    _, _, _, chains, steps, nodes, deltas = parse_flush(out[9], 12, n)
+    assert list(chains) == live and list(steps) == [0] * 8
+    assert list(nodes) == sorted(12 - c for c in live)
+    assert list(deltas) == [3 * CELL_WIRE_BYTES] * 8
+    assert int(out[8]) == 24
+
+
+def test_compact_flush_is_packed_from_live_sizes_without_scatter():
+    """The compacted program's flush is flush_len(K, K) long, and nothing
+    it reads comes from a scatter: the program cut down to its flush
+    output lowers with no scatter, while the whole program keeps the
+    K-long write-back scatters of its state."""
+    import jax
+
+    from shadow_tpu.ops.torcells_device import (_compact_step_span_flush_impl,
+                                                flush_len)
+    n = 127
+    route = np.arange(25 * 5).reshape(25, 5)
+    fl, tables = _tor_table(route, n, np.full(n, 4), np.full(n, 9))
+    inject, target = _injected(fl, [3, 7], 2)
+    args = (*_zero_state(tables), _live(fl, tables, [3, 7], 16, inject,
+                                        target),
+            _EIGHT_SPANS, np.int64(0), *tables[:6], fl["flow_pred"])
+
+    def step(*a):
+        return _compact_step_span_flush_impl(*a, ring_len=4)
+
+    assert jax.eval_shape(step, *args)[9].shape == (flush_len(16, 16),)
+    whole = jax.jit(step).lower(*args).as_text()
+    flush_only = jax.jit(lambda *a: step(*a)[9]).lower(*args).as_text()
+    assert "scatter" in whole
+    assert "scatter" not in flush_only
+    assert "stablehlo.sort" in flush_only
+
+
 def test_compact_tick_loop_holds_nothing_table_wide():
     """The compacted program's tick loop works on the live flows and their
     nodes alone: no operand in its body is as long as the flow table or
@@ -480,8 +551,9 @@ def test_compact_tick_loop_holds_nothing_table_wide():
     inject, target = _injected(fl, [3], 2)
     text = jax.jit(_compact_step_span_flush_impl,
                    static_argnames=("ring_len",)) \
-        .lower(*state, _live(fl, [3], 16, inject, target), _EIGHT_SPANS,
-               np.int64(0), *tables, fl["flow_pred"], ring_len=4).as_text()
+        .lower(*state, _live(fl, tables, [3], 16, inject, target),
+               _EIGHT_SPANS, np.int64(0), *tables[:6], fl["flow_pred"],
+               ring_len=4).as_text()
     body = _while_body(text)
     assert "tensor<16x" in body
     wide = re.findall(r"tensor<(?:\d+x)*(?:%d|%d)x" % (f, n), body)
