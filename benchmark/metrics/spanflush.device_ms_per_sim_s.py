@@ -1,8 +1,10 @@
 """Device time of the span-flush kernel per simulated second: the summed
 duration of every HLO module whose name contains ``_step_span_flush_impl``
-in the traced window (all of its donating, non-donating and capped jit
-variants wrap that function, ops/torcells_device.py), in ms, over the
-window's simulated seconds (the trace spans the whole window)."""
+in the traced window (the full-width program's donating and
+non-donating jit wrappers, and the compacted program's, whose function
+``_compact_step_span_flush_impl`` holds that name too;
+ops/torcells_device.py), in ms, over the window's simulated seconds (the
+trace spans the whole window)."""
 
 KERNELS = {"spanflush": "_step_span_flush_impl"}
 

@@ -29,10 +29,11 @@ from ``--seed``, build it through the program's normal path
 generated ``Configuration``; then ``Controller``) with
 ``--scheduler-policy=tpu --tpu-devices <chips> --device-plane device``,
 warm every kernel shape the scenario can use, run to ``warm_sim_s``,
-then measure the window (``lib/window.py``).  ``--trace 1`` records a
-``jax.profiler`` trace of the window (``lib/trace.py``).  After the
-window: the configuration's comparison with its plain reference, then
-the result, the last line of standard output.
+then measure the window (``lib/window.py``: ``--seconds`` of wall, or
+less where the traffic's last arrival comes first).  ``--trace 1``
+records a ``jax.profiler`` trace of the window (``lib/trace.py``).
+After the window: the configuration's comparison with its plain
+reference, then the result, the last line of standard output.
 """
 
 from __future__ import annotations
@@ -216,6 +217,8 @@ class Run:
         self.wall_s = 0.0
         self.setup_s = 0.0
         self.rss_peak_mb = 0.0
+        self.longest_round_ms = 0.0
+        self.gc_s = 0.0
         self.trace: dict = {}
 
     def delta(self, key: str):
@@ -270,13 +273,16 @@ def measure(cell: Cell, args, scenario: dict, clock: CompileClock,
             prof.stop_trace()
 
     win = Window(engine, int(cell.traffic["warm_sim_s"] * 1e9),
-                 args.seconds, on_open=opened, on_close=closed,
-                 first_boundary=warm)
-    rc = ctrl.run()
+                 args.seconds, round(scenario["last_arrival_s"] * 1e9),
+                 on_open=opened, on_close=closed, first_boundary=warm)
+    try:
+        rc = ctrl.run()
+    finally:
+        win.gc.stop()
     if not win.closed:
         raise RunFailed(
             f"the simulation ended by itself at sim "
-            f"{(win.ended_by_itself_at or 0) / 1e9:.3f} s (rc {rc}) before "
+            f"{(win.last_boundary or 0) / 1e9:.3f} s (rc {rc}) before "
             f"the window closed: the stop time or the offered traffic ran "
             "out inside the window, so there is no rate")
     if rc != 0:
@@ -288,12 +294,18 @@ def measure(cell: Cell, args, scenario: dict, clock: CompileClock,
             "the traffic is no longer stationary there")
     run.sim_s, run.wall_s = win.sim_s, win.wall_s
     run.setup_s = (win.t0_ns / 1e9) - T_START
+    run.longest_round_ms = win.longest_round_ns / 1e6
+    run.gc_s = win.gc.ns / 1e9
     facts = {
         "window_sim_s": [win.sim0_ns / 1e9, win.sim1_ns / 1e9],
         "window_wall_s": win.wall_s,
+        "closed_by": win.closed_by,
         "compiles_in_window": state["compiles1"] - state["compiles0"],
         "rounds": run.delta("engine.rounds"),
         "dispatches": run.delta("plane.dispatches"),
+        "longest_round_ms": run.longest_round_ms,
+        "gc_share": run.gc_s / run.wall_s,
+        "gc_collections": win.gc.collections,
     }
     import jax
     facts["memory_peak_bytes"] = max(

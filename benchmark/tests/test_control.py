@@ -23,66 +23,84 @@ def _args(cell, seed=4294967311, seconds=1.0):
             str(seconds), "--trace", "0"]
 
 
-def _once(real, alter):
-    done = []
-
-    def call(*a, **kw):
-        if not done and a[8].any():
-            done.append(1)
-            return alter(a, real, kw)
-        return real(*a, **kw)
-    return call
-
-
-def _plane(engine):
-    plane = engine.device_plane
+def _plant_once(plane, full, compact):
+    """The plane's next dispatch that injects cells goes through ``full``
+    (the full-width program, ``plane._flush_step``, whose argument 8 is
+    the inject vector) or ``compact`` (the compacted program,
+    ``plane._compact_step``, whose argument 8 is the ``live`` table with
+    the injected cells in row 1), whichever runs it; every other
+    dispatch runs as it is.  Each alteration is ``alter(a, real, kw)``."""
     if plane._flush_step is None:
         from shadow_tpu.ops.torcells_device import \
             step_window_flush_for_backend
         plane._flush_step = step_window_flush_for_backend()
-    return plane
+    if plane._compact_step is None:
+        from shadow_tpu.ops.torcells_device import compact_flush_for_backend
+        plane._compact_step = compact_flush_for_backend()
+    done = []
+
+    def once(real, alter, injects):
+        def call(*a, **kw):
+            if not done and injects(a[8]):
+                done.append(1)
+                return alter(a, real, kw)
+            return real(*a, **kw)
+        return call
+    plane._flush_step = once(plane._flush_step, full,
+                             lambda inject: inject.any())
+    plane._compact_step = once(plane._compact_step, compact,
+                               lambda live: live[1].any())
+
+
+def _state_unchanged(a, real, kw):
+    out = real(*a, **kw)
+    return (*a[:8], *out[8:])
 
 
 def plane_state_unchanged(engine):
     """A dispatch that injects cells hands back the state it was given."""
-    plane = _plane(engine)
-
-    def alter(a, real, kw):
-        out = real(*a, **kw)
-        return (*a[:8], *out[8:])
-    plane._flush_step = _once(plane._flush_step, alter)
+    _plant_once(engine.device_plane, _state_unchanged, _state_unchanged)
 
 
 def plane_half_injections_dropped(engine):
-    """A dispatch takes in only every other circuit it was handed."""
-    plane = _plane(engine)
+    """A dispatch takes in only about half of the chains it was handed:
+    those whose entry (and exit) flow index is even on the full-width
+    program, the even chains on the compacted one."""
+    import numpy as np
 
-    def alter(a, real, kw):
-        import numpy as np
+    def full(a, real, kw):
         a = list(a)
         keep = np.arange(len(a[8])) % 2 == 0
         a[8] = np.where(keep, np.asarray(a[8]), 0)
         a[9] = np.where(keep, np.asarray(a[9]), 0)
         return real(*a, **kw)
-    plane._flush_step = _once(plane._flush_step, alter)
+
+    def compact(a, real, kw):
+        a = list(a)
+        live = np.array(a[8])
+        drop = live[3] % 2 == 1
+        live[1:3, drop] = 0
+        a[8] = live
+        return real(*a, **kw)
+    _plant_once(engine.device_plane, full, compact)
 
 
 def plane_cell_altered(engine):
     """A dispatch reports one more cell delivered on one flow."""
-    plane = _plane(engine)
+    plane = engine.device_plane
 
     def alter(a, real, kw):
         out = list(real(*a, **kw))
         out[4] = out[4].at[int(plane.last_flow[0])].add(1)
         return tuple(out)
-    plane._flush_step = _once(plane._flush_step, alter)
+    _plant_once(plane, alter, alter)
 
 
 def _plant(monkeypatch, fault):
     real_hook = Window._hook
 
-    def hook(self, lookahead):
-        more = real_hook(self, lookahead)
+    def hook(self, boundary):
+        more = real_hook(self, boundary)
         if self.t0_ns is not None and not getattr(self, "_planted", False):
             self._planted = True
             fault(self.engine)
